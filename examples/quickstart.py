@@ -13,12 +13,12 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# Force CPU when no accelerator is reachable (the framework itself is
-# backend-agnostic; on a TPU host just drop these two lines).
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-import jax
+# Runs on whatever device JAX finds (JAX_PLATFORMS=cpu picks the CPU).
+from hyperspace_tpu.util.compile_cache import enable_compile_cache
 
-jax.config.update("jax_platforms", "cpu")
+enable_compile_cache(
+    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+)
 
 from hyperspace_tpu import IndexConfig, IndexConstants
 from hyperspace_tpu.engine import HyperspaceSession, col

@@ -1,7 +1,7 @@
 """Host→device transfer cache.
 
 A query executes many device ops over the same cached host columns; re-uploading
-them per query would dominate on a real TPU (HBM transfers over PCIe/tunnel). Device
+them per query would dominate on a real TPU (host→HBM transfers over PCIe). Device
 arrays are cached per host-array identity (weakref-keyed, so entries die with their
 host arrays — which are themselves owned by the scan cache).
 

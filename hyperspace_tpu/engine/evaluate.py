@@ -628,7 +628,7 @@ def _compiled_eval(expr: Expr, table: Table, mode: str):
     except Exception as e:
         # Fall back to the eager path for THIS call; permanently blacklist the
         # (mode, expression) shape only for trace-time failures (host access
-        # during trace: TracerError/concretization). A transient device/relay
+        # during trace: TracerError/concretization). A transient device
         # error must not disable compilation for the shape forever.
         import jax
 

@@ -2781,9 +2781,8 @@ class SortMergeJoinExec(PhysicalNode):
         """Inner-join row count for the GENERAL (non-bucketed) path without
         pulling pairs to the host: the global sort+probe (`_merge_phase_a`)
         already runs on device; candidate enumeration + exact verification
-        reuse the bucketed machinery as its one-bucket special case. On the
-        relay the old path pulled ~16 bytes per candidate pair to the host —
-        this keeps the NON-indexed baseline count on-device too, so the bench
+        reuse the bucketed machinery as its one-bucket special case. The old
+        path pulled ~16 bytes per candidate pair to the host — this keeps the NON-indexed baseline count on-device too, so the bench
         compares two equally-tuned paths — the value-direct branch has a host
         twin for the CPU backend under the same principle. `pre` carries the
         already-executed children (shared with the `_compute_pairs`

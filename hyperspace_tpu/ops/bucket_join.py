@@ -352,8 +352,7 @@ def pad_buckets_by_hash(key64_arr, starts_np: np.ndarray) -> PaddedBuckets:
     """Hash-key padded matrices (argsort within bucket) for the general case:
     multi-column or string keys, nullable keys, or unsorted buckets. Within
     its VMEM shape budget the in-bucket sort dispatches to the Pallas
-    single-pass bitonic kernel (`ops.pallas_sort`), guarded like the probe —
-    any lowering failure falls back to the XLA argsort permanently."""
+    single-pass bitonic kernel (`ops.pallas_sort`)."""
     from .backend import pallas_maybe_wanted
 
     B = len(starts_np) - 1
@@ -361,24 +360,13 @@ def pad_buckets_by_hash(key64_arr, starts_np: np.ndarray) -> PaddedBuckets:
     cap = _cap_pow2(int(lens.max())) if B else 1
     keys_nudged = jnp.minimum(jnp.asarray(key64_arr), _PAD - 1)
     if pallas_maybe_wanted("HYPERSPACE_PALLAS_SORT"):
-        from .pallas_sort import (
-            pallas_sort_wanted,
-            record_sort_failure,
-            sort_padded_with_order,
-        )
+        from .pallas_sort import pallas_sort_wanted, sort_padded_with_order
 
         if pallas_sort_wanted(B, cap):
-            try:
-                padded, lengths = _pad_scatter(
-                    keys_nudged, jnp.asarray(starts_np), B, cap
-                )
-                keys, order = sort_padded_with_order(padded)
-                _record_bucket_pad(int(starts_np[-1]), B, cap, 8)
-                return PaddedBuckets(
-                    keys, lengths, np.asarray(order), starts_np, "hash"
-                )
-            except Exception as e:  # Mosaic lowering/runtime problems
-                record_sort_failure(e)
+            padded, lengths = _pad_scatter(keys_nudged, jnp.asarray(starts_np), B, cap)
+            keys, order = sort_padded_with_order(padded)
+            _record_bucket_pad(int(starts_np[-1]), B, cap, 8)
+            return PaddedBuckets(keys, lengths, np.asarray(order), starts_np, "hash")
     keys, order, lengths = _pad_and_sort(keys_nudged, jnp.asarray(starts_np), B, cap)
     _record_bucket_pad(int(starts_np[-1]), B, cap, 8)
     return PaddedBuckets(keys, lengths, np.asarray(order), starts_np, "hash")
@@ -410,8 +398,7 @@ def probe_ranges(ls, rs, l_len, r_len):
     """Probe dispatcher: the Pallas tiled-compare kernel when wanted (on-TPU
     within its capacity budget, or HYPERSPACE_PALLAS_PROBE=1), else the XLA
     vmap'd-searchsorted probe; the CPU backend probes on host (numpy
-    searchsorted, ~4x the XLA-CPU probe). Any Pallas failure is recorded once
-    and falls back permanently — an index problem must never break a query."""
+    searchsorted, ~4x the XLA-CPU probe)."""
     from .backend import pallas_maybe_wanted, use_device_path
 
     # Cheap pre-gate before touching pallas at all: importing
@@ -419,21 +406,12 @@ def probe_ranges(ls, rs, l_len, r_len):
     # backend the kernel is never wanted — the import would be pure cold-path
     # tax (measured as the dominant cost of the first 8M indexed count).
     if pallas_maybe_wanted("HYPERSPACE_PALLAS_PROBE"):
-        from .pallas_probe import (
-            pallas_probe_wanted,
-            probe_pallas,
-            record_pallas_failure,
-        )
+        from .pallas_probe import pallas_probe_wanted, probe_pallas
 
-        if pallas_probe_wanted(
-            int(ls.shape[1]), int(rs.shape[1]), int(ls.shape[0]), ls.dtype
-        ):
+        if pallas_probe_wanted(int(ls.shape[1]), int(rs.shape[1]), int(ls.shape[0])):
             # Checked FIRST: HYPERSPACE_PALLAS_PROBE=1 forces the kernel even
             # on the CPU backend (interpret-mode validation rides this).
-            try:
-                return probe_pallas(ls, rs, l_len, r_len)
-            except Exception as e:  # Mosaic lowering/runtime problems
-                record_pallas_failure(e, ls.dtype)
+            return probe_pallas(ls, rs, l_len, r_len)
     if not use_device_path():
         return _probe_host(
             np.asarray(ls), np.asarray(rs), np.asarray(l_len), np.asarray(r_len)
@@ -496,7 +474,7 @@ def _pad_scatter_codes(codes, starts, num_buckets: int, cap: int, bits: int):
 
 @_observed_jit(label="bucket_join.pad_and_sort_codes", static_argnums=(2, 3, 4))
 def _pad_and_sort_codes(codes, starts, num_buckets: int, cap: int, bits: int):
-    """XLA fallback twin of `pallas_sort.sort_codes_packed`: scatter + stable
+    """XLA twin of `pallas_sort.sort_code_rows`: scatter + stable
     argsort on the flat biased matrix. Same (sorted, order, lengths) contract."""
     padded, lengths = _pad_scatter_codes(codes, starts, num_buckets, cap, bits)
     order = jnp.argsort(padded, axis=1)
@@ -546,21 +524,11 @@ def pad_buckets_by_codes(
     starts = jnp.asarray(starts_np)
     sorted_codes = order = lengths = None
     if pallas_maybe_wanted("HYPERSPACE_PALLAS_SORT"):
-        from .pallas_sort import (
-            pallas_packed_sort_wanted,
-            record_sort_failure,
-            sort_codes_packed,
-        )
+        from .pallas_sort import pallas_packed_sort_wanted, sort_code_rows
 
         if pallas_packed_sort_wanted(B, cap, bits):
-            try:
-                padded, lengths = _pad_scatter_codes(codes, starts, B, cap, bits)
-                sorted_codes, order = sort_codes_packed(
-                    _pack_code_rows(padded, bits), bits
-                )
-            except Exception as e:  # Mosaic lowering/runtime problems
-                record_sort_failure(e)
-                sorted_codes = None
+            padded, lengths = _pad_scatter_codes(codes, starts, B, cap, bits)
+            sorted_codes, order = sort_code_rows(padded)
     if sorted_codes is None:
         sorted_codes, order, lengths = _pad_and_sort_codes(
             codes, starts, B, cap, bits
@@ -577,7 +545,7 @@ def pad_buckets_by_codes(
 
 def probe_code_ranges(l: PackedCodeBuckets, r: PackedCodeBuckets):
     """Probe dispatcher for packed code reps: the Pallas packed-word kernel
-    when wanted (own "packed" latch), else widen-then-probe — one device
+    when wanted, else widen-then-probe — one device
     unpack to flat int32 matrices feeding the generic probe (`_probe`, or the
     host searchsorted off the device path). Biased codes compare consistently
     on both sides, so ranges are identical across all three paths."""
@@ -587,19 +555,10 @@ def probe_code_ranges(l: PackedCodeBuckets, r: PackedCodeBuckets):
         raise ValueError(f"packed rep bits mismatch: {l.bits} != {r.bits}")
     B = l.words.shape[0]
     if pallas_maybe_wanted("HYPERSPACE_PALLAS_PROBE"):
-        from .pallas_probe import (
-            pallas_packed_probe_wanted,
-            probe_packed_pallas,
-            record_pallas_failure,
-        )
+        from .pallas_probe import pallas_packed_probe_wanted, probe_packed_pallas
 
         if pallas_packed_probe_wanted(l.cap, r.cap, B, l.bits):
-            try:
-                return probe_packed_pallas(
-                    l.words, r.words, l.bits, l.lengths, r.lengths
-                )
-            except Exception as e:  # Mosaic lowering/runtime problems
-                record_pallas_failure(e, kind="packed")
+            return probe_packed_pallas(l.words, r.words, l.bits, l.lengths, r.lengths)
     ls = _unpack_code_rows(l.words, l.bits)
     rs = _unpack_code_rows(r.words, r.bits)
     if not use_device_path():

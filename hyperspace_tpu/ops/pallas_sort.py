@@ -1,21 +1,25 @@
-"""Pallas TPU kernel for the in-bucket sort (hash-mode padded reps).
+"""Pallas TPU kernels for the in-bucket sort (hash-mode padded reps).
 
 `bucket_join._pad_and_sort` sorts each padded bucket row with `jnp.argsort`
-(XLA variadic sort): at round-4 bench shapes that was the DOMINANT device
-kernel (pad+sort 5.49 s vs the probe's 1.15 s at 8M rows) — a bitonic network
-whose every stage round-trips HBM. This kernel keeps a whole [TB, cap] bucket
-group resident in VMEM and runs the complete bitonic network in one
-`pallas_call` — a single HBM read + write per element regardless of the
-network's O(log² cap) stages. That trade only exists while the block fits
-VMEM, so the dispatcher gates on cap (pow2 by construction — `_cap_pow2`).
+(XLA variadic sort), a bitonic network whose every stage round-trips HBM.
+These kernels keep a whole [TB, cap] bucket group resident in VMEM and run
+the complete bitonic network in one `pallas_call` — a single HBM read +
+write per element regardless of the network's O(log² cap) stages. That trade
+only exists while the block fits VMEM, so the dispatcher gates on cap (pow2
+by construction — `_cap_pow2`).
 
-Formulation: compare-exchange at stride j is a reshape to [TB, m, 2, j] —
-lane-local slicing, no gathers (partner i^j sits at [..., 1, :] of the pair
-axis). Keys are 64-bit, pre-split OUTSIDE the kernel into the same
-lexicographic (hi, lo) int32 pair the probe kernel uses (no 64-bit values on
-the VPU; no 64-bit bitcasts for the relay's X64-elimination to reject). The
-row-index payload rides the exchanges, so the kernel returns both sorted keys
-and the argsort permutation in one pass.
+Formulation: the compare-exchange at stride j pairs lane i with lane i ^ j.
+The partner row is two lane rotations (by j and by cap - j) and an iota
+select, so the network needs no reshape (Mosaic refuses the [TB, m, 2, j]
+shape cast). The rotation direction is read off a rotated iota rather than
+assumed. Stages run as `fori_loop`s with a dynamic rotate amount, so the
+kernel body is one stage whatever the cap (an unrolled network takes minutes
+to compile at cap 4096). The state lives in the output refs between stages.
+
+Keys are 64-bit, pre-split OUTSIDE the kernel into the same lexicographic
+(hi, lo) int32 pair the probe kernel uses (no 64-bit values on the VPU). The
+row-index payload rides the exchanges, so the kernel returns both sorted
+keys and the argsort permutation in one pass.
 
 Bitonic networks are NOT stable; equal keys land in arbitrary order. That is
 sound here by the same argument as hash collisions: the probe emits the whole
@@ -23,8 +27,8 @@ equal-key RANGE and verification compares actual values, so any permutation
 within an equal run yields the identical pair set.
 
 Equivalence with `jnp.argsort` is pinned by tests/test_pallas_sort.py
-(interpret mode off-TPU); the guarded dispatcher falls back to the XLA path
-on any lowering failure, scoped with the same latch discipline as the probe.
+(interpret mode off-TPU); tests/test_tpu_compile.py compiles the kernels for
+a described v5e. A wanted kernel that fails raises.
 """
 
 from __future__ import annotations
@@ -35,80 +39,72 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _ENV_KEY = "HYPERSPACE_PALLAS_SORT"
-# VMEM budget: 3 int32 payloads (hi, lo, idx) x in/out + temps. TB=8 rows of
-# cap=32768 is ~6 MB — comfortable; 65536 doubles it and starts crowding
-# double-buffering, so the gate stops at 32768.
+# VMEM budget: 3 int32 payloads (hi, lo, idx) x in/out, double-buffered, plus
+# stage temps. TB=8 rows of cap=32768 fits the default 16 MiB scoped VMEM of
+# a v5e (tests/test_tpu_compile.py); 65536 would not, so the gate stops here.
 _MAX_CAP = 32768
 _MIN_CAP = 256  # below this the dispatch overhead beats any fusion win
-_sort_broken: dict = {}  # scoped latch (single kind: "sort")
-_fallback_counts: dict = {}  # diverted-dispatch counter after a latch
 
-from ..telemetry import metrics as _metrics
 from ..telemetry.compile_log import observed_jit as _observed_jit
 
-# Bound once: incremented on every diverted dispatch after a latch.
-_FALLBACK_METRIC = _metrics.counter("pallas.sort.fallbacks")
+
+def _bitonic_refs(refs, less):
+    """In-place bitonic network over the LAST axis of the [TB, n] VMEM refs.
+    `less(a, b)` orders tuples of loaded rows (keys first; trailing rows are
+    payload that rides the exchanges)."""
+    tb, n = refs[0].shape
+    log_n = n.bit_length() - 1
+    pos = jax.lax.broadcasted_iota(jnp.int32, (tb, n), 1)
+
+    def stage(k, j):
+        xs = [r[...] for r in refs]
+        # Which of the two rotations holds lane i ^ j, decided by rotating
+        # the iota itself (independent of the rotate's sign convention).
+        from_a = pltpu.roll(pos, j, 1) == (pos ^ j)
+        ps = [
+            jnp.where(from_a, pltpu.roll(x, j, 1), pltpu.roll(x, n - j, 1))
+            for x in xs
+        ]
+        # Lane i keeps the min of its pair when it is the pair's lower lane
+        # of an ascending run, or the upper lane of a descending run.
+        want_min = ((pos & j) == 0) == ((pos & k) == 0)
+        take = (want_min & less(ps, xs)) | (~want_min & less(xs, ps))
+        for r, x, p in zip(refs, xs, ps):
+            r[...] = jnp.where(take, p, x)
+
+    # int32 loop bounds: under jax_enable_x64 Python-int bounds give int64
+    # induction variables, which Mosaic cannot lower.
+    one = jnp.int32(1)
+
+    def merge(kk, carry):
+        k = jnp.left_shift(one, kk)
+
+        def step(t, c):
+            stage(k, jnp.right_shift(k, t + one))
+            return c
+
+        return jax.lax.fori_loop(jnp.int32(0), kk, step, carry)
+
+    jax.lax.fori_loop(one, jnp.int32(log_n + 1), merge, jnp.int32(0))
 
 
-def pallas_fallback_stats() -> dict:
-    """Session counters of sort-kernel fallbacks (see the probe twin): how
-    many sorts were diverted after a failure latched, and the first error.
-    Empty when the kernel never failed."""
-    if not _sort_broken and not _fallback_counts:
-        return {}
-    return {
-        "failures": dict(_fallback_counts),
-        "errors": dict(_sort_broken),
-    }
+def _pairs_lt(a, b):
+    """64-bit (hi, lo) lexicographic signed compare on row tuples: a < b."""
+    return (a[0] < b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
 
 
-def _pairs_gt(ah, al, bh, bl):
-    """64-bit (hi, lo) lexicographic signed compare: a > b."""
-    return (ah > bh) | ((ah == bh) & (al > bl))
-
-
-def _bitonic_body(h, l, idx):
-    """The full bitonic network over the LAST axis of [TB, cap] arrays,
-    python-unrolled (cap is static): O(log² cap) reshape/where stages."""
-    tb, n = h.shape
-    k = 2
-    while k <= n:
-        j = k // 2
-        while j >= 1:
-            m = n // (2 * j)
-            h4 = h.reshape(tb, m, 2, j)
-            l4 = l.reshape(tb, m, 2, j)
-            i4 = idx.reshape(tb, m, 2, j)
-            ah, bh = h4[:, :, 0, :], h4[:, :, 1, :]
-            al, bl = l4[:, :, 0, :], l4[:, :, 1, :]
-            ai, bi = i4[:, :, 0, :], i4[:, :, 1, :]
-            # Direction of the pair's bitonic run: bit log2(k) of the lower
-            # element's global position g*2j (t < j never reaches that bit).
-            g = jax.lax.broadcasted_iota(jnp.int32, (tb, m, 1, j), 1)
-            desc = ((g * (2 * j)) & k) > 0
-            desc = desc[:, :, 0, :]
-            swap = _pairs_gt(ah, al, bh, bl) != desc
-            nah = jnp.where(swap, bh, ah)
-            nbh = jnp.where(swap, ah, bh)
-            nal = jnp.where(swap, bl, al)
-            nbl = jnp.where(swap, al, bl)
-            nai = jnp.where(swap, bi, ai)
-            nbi = jnp.where(swap, ai, bi)
-            h = jnp.stack([nah, nbh], axis=2).reshape(tb, n)
-            l = jnp.stack([nal, nbl], axis=2).reshape(tb, n)
-            idx = jnp.stack([nai, nbi], axis=2).reshape(tb, n)
-            j //= 2
-        k *= 2
-    return h, l, idx
+def _single_lt(a, b):
+    return a[0] < b[0]
 
 
 def _sort_kernel(h_ref, l_ref, i_ref, ho_ref, lo_ref, io_ref):
-    h, l, idx = _bitonic_body(h_ref[...], l_ref[...], i_ref[...])
-    ho_ref[...] = h
-    lo_ref[...] = l
-    io_ref[...] = idx
+    ho_ref[...] = h_ref[...]
+    lo_ref[...] = l_ref[...]
+    io_ref[...] = i_ref[...]
+    _bitonic_refs((ho_ref, lo_ref, io_ref), _pairs_lt)
 
 
 def _bucket_tile(B: int) -> int:
@@ -133,7 +129,7 @@ def _sort_pallas_call(hi, lo, idx, interpret: bool):
     B, cap = hi.shape
     TB = _bucket_tile(B)
     grid = (B // TB,)
-    spec = pl.BlockSpec((TB, cap), lambda b: (b, 0))
+    spec = pl.BlockSpec((TB, cap), lambda b: (b, jnp.int32(0)))
     return pl.pallas_call(
         _sort_kernel,
         grid=grid,
@@ -172,99 +168,23 @@ def sort_padded_with_order(keys_i64) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return _recombine(sh, sl), order
 
 
-# --- sort on PACKED sub-byte code words --------------------------------------
+# --- sort on sub-byte code lanes ----------------------------------------------
 #
 # Sub-byte dictionary codes (`engine/packed_codes.py`) don't need the 64-bit
 # (hi, lo, idx) triple: a biased code (< 16) and its slot index (< cap <=
 # 32768) TOGETHER fit one int32 composite, comp = (code << log2 cap) | slot.
 # Comps are UNIQUE (slot bits), so the unstable bitonic reproduces the STABLE
 # argsort of the code matrix exactly — and the network moves one int32 lane
-# instead of three, a third of the VMEM traffic of `_sort_kernel`. The kernel
-# reads the packed WORD matrix from HBM (bits-per-code traffic) and unpacks
-# in VMEM.
+# instead of three, a third of the VMEM traffic of `_sort_kernel`. The words
+# are unpacked by XLA before the kernel: an in-kernel unpack materialises a
+# [TB, words, lanes-per-word] intermediate whose minor axis pads to 128
+# lanes, which overflows VMEM at cap 32768 with 4-bit codes.
 
 
-def _bitonic_body_single(v):
-    """`_bitonic_body` specialised to ONE int32 lane (the composite): same
-    reshape/where network, a third of the exchanged state."""
-    tb, n = v.shape
-    k = 2
-    while k <= n:
-        j = k // 2
-        while j >= 1:
-            m = n // (2 * j)
-            v4 = v.reshape(tb, m, 2, j)
-            a, b = v4[:, :, 0, :], v4[:, :, 1, :]
-            g = jax.lax.broadcasted_iota(jnp.int32, (tb, m, 1, j), 1)
-            desc = ((g * (2 * j)) & k) > 0
-            desc = desc[:, :, 0, :]
-            swap = (a > b) != desc
-            na = jnp.where(swap, b, a)
-            nb = jnp.where(swap, a, b)
-            v = jnp.stack([na, nb], axis=2).reshape(tb, n)
-            j //= 2
-        k *= 2
-    return v
-
-
-def _sort_packed_kernel(w_ref, o_ref, *, bits, log2cap):
-    from .pallas_probe import _unpack_words_block
-
-    lanes = _unpack_words_block(w_ref[...], bits)  # [TB, cap] biased int32
-    slot = jax.lax.broadcasted_iota(jnp.int32, lanes.shape, 1)
-    comp = (lanes << log2cap) | slot
-    o_ref[...] = _bitonic_body_single(comp)
-
-
-@_observed_jit(label="pallas.sort_packed", static_argnums=(1, 2))
-def _sort_packed_call(words, bits: int, interpret: bool):
-    import functools
-
-    B, n_words = words.shape
-    lpw = 32 // bits
-    cap = n_words * lpw
-    assert cap & (cap - 1) == 0, cap
-    TB = _bucket_tile(B)
-    in_spec = pl.BlockSpec((TB, n_words), lambda b: (b, 0))
-    out_spec = pl.BlockSpec((TB, cap), lambda b: (b, 0))
-    kern = functools.partial(
-        _sort_packed_kernel, bits=bits, log2cap=cap.bit_length() - 1
-    )
-    return pl.pallas_call(
-        kern,
-        grid=(B // TB,),
-        in_specs=[in_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((B, cap), jnp.int32),
-        interpret=interpret,
-    )(words)
-
-
-def sort_codes_packed(words, bits: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sort each padded bucket row of a packed BIASED-code word matrix:
-    (sorted_biased_codes int32 [B, cap], order int32 [B, cap]) with
-    `sorted[b, s] == codes[b, order[b, s]]`. Requires pad slots packed as the
-    top lane value (2**bits - 1 — `probe_bits_for_cardinality` keeps it above
-    every real biased code), so pads sort last like the int64 path's pad key.
-    Matches `jnp.argsort` EXACTLY including ties (comp uniqueness => stable)."""
-    words = jnp.asarray(words)
-    cap = words.shape[1] * (32 // bits)
-    comp = _sort_packed_call(words, bits, jax.default_backend() != "tpu")
-    return comp >> (cap.bit_length() - 1), comp & (cap - 1)
-
-
-def _sort_comp_kernel(v_ref, o_ref):
-    o_ref[...] = _bitonic_body_single(v_ref[...])
-
-
-@_observed_jit(label="pallas.sort_comp", static_argnums=(1,))
-def sort_comp_padded(v, interpret: bool):
-    """Single-lane int32 bitonic over [B, cap] composite rows (build-side
-    bucket|code|row composites — `partition.pallas_packed_build_sort`). The
-    caller owns the composite encoding; this just sorts rows ascending."""
+def _comp_pallas(v, interpret: bool):
     B, cap = v.shape
     TB = _bucket_tile(B)
-    spec = pl.BlockSpec((TB, cap), lambda b: (b, 0))
+    spec = pl.BlockSpec((TB, cap), lambda b: (b, jnp.int32(0)))
     return pl.pallas_call(
         _sort_comp_kernel,
         grid=(B // TB,),
@@ -275,10 +195,48 @@ def sort_comp_padded(v, interpret: bool):
     )(v)
 
 
+def _sort_comp_kernel(v_ref, o_ref):
+    o_ref[...] = v_ref[...]
+    _bitonic_refs((o_ref,), _single_lt)
+
+
+@_observed_jit(label="pallas.sort_comp", static_argnums=(1,))
+def sort_comp_padded(v, interpret: bool):
+    """Single-lane int32 bitonic over [B, cap] composite rows (build-side
+    bucket|code|row composites — `partition.pallas_packed_build_sort`). The
+    caller owns the composite encoding; this just sorts rows ascending."""
+    return _comp_pallas(v, interpret)
+
+
+@_observed_jit(label="pallas.sort_codes", static_argnums=(1,))
+def _sort_codes_call(lanes, interpret: bool):
+    B, cap = lanes.shape
+    log2cap = cap.bit_length() - 1
+    slot = jax.lax.broadcasted_iota(jnp.int32, (B, cap), 1)
+    comp = _comp_pallas((lanes.astype(jnp.int32) << log2cap) | slot, interpret)
+    return comp >> log2cap, comp & (cap - 1)
+
+
+def sort_code_rows(lanes) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sort each padded bucket row of a BIASED-code int32 matrix [B, cap]:
+    (sorted_biased_codes int32 [B, cap], order int32 [B, cap]) with
+    `sorted[b, s] == codes[b, order[b, s]]`. Pad slots must hold the top lane
+    value (2**bits - 1 — `probe_bits_for_cardinality` keeps it above every
+    real biased code), so pads sort last like the int64 path's pad key.
+    Matches `jnp.argsort` EXACTLY including ties (comp uniqueness => stable)."""
+    return _sort_codes_call(jnp.asarray(lanes), jax.default_backend() != "tpu")
+
+
+def sort_codes_packed(words, bits: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`sort_code_rows` over a packed biased-code WORD matrix [B, cap/lpw]."""
+    from ..engine.packed_codes import unpack_rows_traced
+
+    return sort_code_rows(unpack_rows_traced(jnp.asarray(words), bits))
+
+
 def pallas_packed_sort_wanted(B: int, cap: int, bits: int) -> bool:
-    """Gate for the packed-word sort: the ordinary sort gate plus whole-word
-    rows. Shares the single "sort" latch — both variants lower the same
-    bitonic network, so a Mosaic failure in one predicts the other."""
+    """Gate for the code-lane sort: the ordinary sort gate plus whole-word
+    rows."""
     if cap % (32 // bits):
         return False
     return pallas_sort_wanted(B, cap)
@@ -286,12 +244,7 @@ def pallas_packed_sort_wanted(B: int, cap: int, bits: int) -> bool:
 
 def pallas_sort_wanted(B: int, cap: int) -> bool:
     """Dispatch decision: forced by env (1/0), else auto on TPU within the
-    VMEM shape budget. Any lowering failure latches a permanent fallback
-    (scoped to the sort; the validated probe kernel is unaffected)."""
-    if "sort" in _sort_broken:
-        _fallback_counts["sort"] = _fallback_counts.get("sort", 0) + 1
-        _FALLBACK_METRIC.inc()
-        return False
+    VMEM shape budget."""
     mode = os.environ.get(_ENV_KEY, "auto")
     if mode == "0":
         return False
@@ -300,15 +253,3 @@ def pallas_sort_wanted(B: int, cap: int) -> bool:
     if mode == "1":
         return True
     return jax.default_backend() == "tpu"
-
-
-def record_sort_failure(exc: BaseException) -> None:
-    import logging
-
-    _sort_broken["sort"] = f"{type(exc).__name__}: {exc}"
-    _fallback_counts["sort"] = _fallback_counts.get("sort", 0) + 1
-    _FALLBACK_METRIC.inc()
-    logging.getLogger("hyperspace_tpu.ops").warning(
-        "pallas sort failed; falling back to the XLA sort permanently: %s",
-        _sort_broken["sort"],
-    )

@@ -8,7 +8,6 @@ from .mesh import (  # noqa: F401
     replicated,
     row_sharding,
 )
-from .shim import pjit, require_shard_map, shard_map  # noqa: F401
 from .distributed import (  # noqa: F401
     distributed_bucketed_join_counts,
     distributed_bucketize,

@@ -42,7 +42,6 @@ from ..telemetry import metrics as _metrics
 from ..telemetry import stage_ledger as _stage_ledger
 from ..telemetry.compile_log import observed_jit as _observed_jit
 from .mesh import BUCKET_AXIS, quantize_cap
-from .shim import shard_map
 
 _PAD_SLOT = -1
 #: Packed wire lanes ride uint32 words (`engine/packed_codes.py` layout).
@@ -78,7 +77,7 @@ def _counts_program(mesh: Mesh, num_buckets: int):
         return jnp.sum(one_hot, axis=0, keepdims=True)  # [1, n_dev]
 
     return _observed_jit(
-        shard_map(count_fn, mesh=mesh, in_specs=P(BUCKET_AXIS), out_specs=P(BUCKET_AXIS)),
+        jax.shard_map(count_fn, mesh=mesh, in_specs=P(BUCKET_AXIS), out_specs=P(BUCKET_AXIS)),
         label="parallel.exchange_counts",
     )
 
@@ -134,7 +133,7 @@ def _exchange_program(mesh: Mesh, num_buckets: int, cap: int):
         return out_bucket, out_valid, out_payload
 
     return _observed_jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS)),
@@ -260,7 +259,7 @@ def _counts_coded_program(mesh: Mesh, num_buckets: int):
         return jnp.sum(one_hot, axis=0, keepdims=True)  # [1, n_dev]
 
     return _observed_jit(
-        shard_map(count_fn, mesh=mesh, in_specs=P(BUCKET_AXIS), out_specs=P(BUCKET_AXIS)),
+        jax.shard_map(count_fn, mesh=mesh, in_specs=P(BUCKET_AXIS), out_specs=P(BUCKET_AXIS)),
         label="parallel.exchange_counts",
     )
 
@@ -371,7 +370,7 @@ def _exchange_coded_program(
         return out_bucket, out_valid, out_payload
 
     return _observed_jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS)),
@@ -434,7 +433,7 @@ def _join_counts_program(mesh: Mesh):
         return jnp.sum(jnp.where(valid, hi - lo, 0), axis=1)
 
     return _observed_jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS)),

@@ -43,7 +43,6 @@ from ..ops.hashing import _SEED1, combined_hash_u32, key64
 from ..telemetry.compile_log import observed_jit as _observed_jit
 from .distributed import distributed_bucketize, distributed_bucketize_coded
 from .mesh import BUCKET_AXIS, quantize_cap, quantized_rows, row_sharding
-from .shim import shard_map
 
 _PAD = np.iinfo(np.int64).max
 
@@ -418,7 +417,7 @@ def _probe_program(mesh: Mesh, buckets_local: int, cap_l: int, cap_r: int):
         return lo, counts, l_order, r_order
 
     return _observed_jit(
-        shard_map(
+        jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS)),

@@ -7,7 +7,7 @@ per node with what actually happened: measured wall seconds, rows out,
 cache/memo hit-or-miss, and the pipelined executors' stage spans
 (probe/verify/gather/…) nested where they ran. Sections below the tree report
 the optimizer-rule decisions (which index rule rewrote the plan, and why the
-others sat out), Pallas kernel fallbacks, and the per-query cache/metric
+others sat out), and the per-query cache/metric
 counter deltas.
 
 Nodes with no span did not execute — the engine served them another way (a
@@ -136,9 +136,6 @@ def _stage_lines(span, children_of, indent: int) -> List[str]:
                     if v is not None:
                         cells.append(f"{k}={v}")
                 out.append(pad + "  " + " ".join(cells))
-        fallbacks = child.attrs.get("pallas_fallbacks")
-        if fallbacks:
-            out.append(pad + f"pallas fallbacks: {fallbacks}")
     return out
 
 

@@ -206,20 +206,16 @@ def _on_event(event: str, **_kw) -> None:
 
 
 def compile_cache_summary() -> dict:
-    """Persistent-compilation-cache traffic: {"dir": configured cache dir or
-    None, "events": {event leaf: count}}. A SECOND process (or a post-
-    `jax.clear_caches()` re-dispatch) against a warm
-    ``HYPERSPACE_COMPILE_CACHE_DIR`` shows `cache_hits` > 0 here — the
-    observable proof it paid zero backend compiles. Consumed by the exporter
-    frames and `bench_detail.mesh`."""
-    import os as _os
+    """Persistent-compilation-cache traffic: {"dir": the cache directory JAX
+    uses or None, "events": {event leaf: count}}. A SECOND process (or a
+    post-`jax.clear_caches()` re-dispatch) against a warm cache shows
+    `cache_hits` > 0 here — the observable proof it paid zero backend
+    compiles. Consumed by the exporter frames and `chip_smoke.py`."""
+    import jax
 
     with _lock:
         events = dict(_cache_events)
-    return {
-        "dir": _os.environ.get("HYPERSPACE_COMPILE_CACHE_DIR") or None,
-        "events": events,
-    }
+    return {"dir": jax.config.jax_compilation_cache_dir or None, "events": events}
 
 
 def install() -> bool:

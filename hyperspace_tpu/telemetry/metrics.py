@@ -4,7 +4,7 @@ The engine's caches and memos each kept private hit/miss integers that only
 bench.py knew how to scrape, and only for the caches it knew about. This
 registry is the one place every component reports to — `scan_cache`,
 `device_cache`, the device memos in `engine/physical`, the decode pool in
-`engine/io`, the optimizer rules, and the Pallas kernel fallbacks — so a
+`engine/io`, and the optimizer rules — so a
 query's cache behavior is answerable from one `snapshot()` (consumed by
 `bench_detail.metrics_snapshot` and `explain(analyze=True)`).
 

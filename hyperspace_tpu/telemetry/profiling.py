@@ -44,11 +44,6 @@ class StageTimings:
         self.mode = mode
         self._t0 = time.monotonic()
         self._wall: Optional[float] = None
-        # Pallas fallback counters at operation START: summary() attaches the
-        # DELTA, so a fallback shows up in the summary of the operation where
-        # it actually happened — not in every later operation's (the counters
-        # themselves are session-cumulative).
-        self._fallbacks0 = pallas_fallback_summary()
 
     def add(self, stage: str, seconds: float) -> None:
         with self._lock:
@@ -83,9 +78,6 @@ class StageTimings:
             out["overlap_ratio"] = round(busy / wall, 3) if wall > 0 else None
             out["mode"] = self.mode
             out["stage_counts"] = dict(sorted(self._counts.items()))
-        delta = _fallback_delta(self._fallbacks0, pallas_fallback_summary())
-        if delta:
-            out["pallas_fallbacks"] = delta
         return out
 
 
@@ -96,27 +88,6 @@ _BUILD_STAGES: "deque[dict]" = deque(maxlen=16)
 _QUERY_STAGES: "deque[dict]" = deque(maxlen=16)
 _JOIN_STAGES: "deque[dict]" = deque(maxlen=16)
 _build_stages_lock = threading.Lock()
-
-
-def _fallback_delta(before: dict, after: dict) -> dict:
-    """Per-operation Pallas fallback delta between two `pallas_fallback_
-    summary()` snapshots: only kinds whose diverted-dispatch count GREW, with
-    the latched error strings carried along. Empty when nothing new fell
-    back during the operation."""
-    out: dict = {}
-    for mod_key, a in after.items():
-        bf = before.get(mod_key, {}).get("failures", {})
-        grown = {
-            k: v - bf.get(k, 0)
-            for k, v in a.get("failures", {}).items()
-            if v - bf.get(k, 0) > 0
-        }
-        if grown:
-            ent = {"failures": grown}
-            if a.get("errors"):
-                ent["errors"] = dict(a["errors"])
-            out[mod_key] = ent
-    return out
 
 
 def _observe_stage_histograms(kind: str, summary: dict) -> None:
@@ -137,11 +108,7 @@ def _observe_stage_histograms(kind: str, summary: dict) -> None:
 
 
 def record_build_stages(summary: dict) -> None:
-    """Record one build's stage summary. Summaries come from `StageTimings.
-    summary()`, which attaches the operation-scoped `pallas_fallbacks` DELTA
-    — a silent host fallback during a build or a streaming scan is visible
-    in THAT operation's summary, and only that one (it previously rode
-    `record_join_stages` alone, as session-cumulative counters)."""
+    """Record one build's stage summary (from `StageTimings.summary()`)."""
     d = dict(summary)
     with _build_stages_lock:
         _BUILD_STAGES.append(d)
@@ -165,8 +132,7 @@ def record_query_stages(summary: dict) -> None:
     """Per-stage timings of one streaming query execution (decode/filter/
     partial/merge busy time + wall + overlap ratio) — the read-side twin of
     `record_build_stages`, surfaced through bench.py's
-    ``bench_detail.query_stages``. Pallas fallback deltas ride the summary
-    (see `record_build_stages`)."""
+    ``bench_detail.query_stages``."""
     d = dict(summary)
     with _build_stages_lock:
         _QUERY_STAGES.append(d)
@@ -190,8 +156,7 @@ def record_join_stages(summary: dict) -> None:
     """Per-stage timings of one streamed join→aggregate execution (pad/probe/
     expand/verify/gather/eval/partial busy time + wall + overlap ratio, plus
     class/outlier counts) — surfaced through bench.py's
-    ``bench_detail.join_stages``. Pallas fallback deltas ride the summary so
-    a silent host fallback is visible next to the timings it explains."""
+    ``bench_detail.join_stages``."""
     d = dict(summary)
     with _build_stages_lock:
         _JOIN_STAGES.append(d)
@@ -209,27 +174,6 @@ def join_stages_history() -> list:
     """Stage summaries of the last few streamed joins, oldest first."""
     with _build_stages_lock:
         return [dict(d) for d in _JOIN_STAGES]
-
-
-def pallas_fallback_summary() -> dict:
-    """Session-level Pallas fallback counters (probe + sort kernels), empty
-    when nothing fell back. Reads through sys.modules so it NEVER triggers
-    the ~1 s `jax.experimental.pallas` import on paths that never wanted a
-    kernel — a module that was never imported cannot have failed."""
-    import sys
-
-    out: dict = {}
-    for name, key in (
-        ("hyperspace_tpu.ops.pallas_probe", "probe"),
-        ("hyperspace_tpu.ops.pallas_sort", "sort"),
-    ):
-        mod = sys.modules.get(name)
-        stats = getattr(mod, "pallas_fallback_stats", None) if mod else None
-        if stats is not None:
-            s = stats()
-            if s:
-                out[key] = s
-    return out
 
 
 def io_pruning_summary() -> dict:

@@ -214,26 +214,38 @@ class TestDistributedFlagContract:
 
 
 class TestPersistentCompileCache:
-    def test_session_knob_configures_jax_and_hits_surface(self, tmp_path, monkeypatch):
+    @pytest.mark.parametrize("env_set", [True, False])
+    def test_entries_land_where_jax_env_says_else_default(
+        self, tmp_path, monkeypatch, env_set
+    ):
+        """`JAX_COMPILATION_CACHE_DIR` stands when set; otherwise the entry
+        point's fixed default is used. Entries land there and nowhere else,
+        and a re-dispatch after dropping in-memory executables hits."""
         import jax
         import jax.numpy as jnp
 
-        from hyperspace_tpu.engine import session as session_mod
+        from hyperspace_tpu.util.compile_cache import enable_compile_cache
 
-        cache_dir = str(tmp_path / "xla_cache")
-        monkeypatch.setenv("HYPERSPACE_COMPILE_CACHE_DIR", cache_dir)
-        monkeypatch.setattr(session_mod, "_compile_cache_done", False)
+        env_dir = str(tmp_path / "from_env")
+        default_dir = str(tmp_path / "default")
+        want = env_dir if env_set else default_dir
+        if env_set:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         try:
-            _session(tmp_path)  # session init applies the knob
-            assert jax.config.jax_compilation_cache_dir == cache_dir
+            assert enable_compile_cache(default_dir) == want
+            assert jax.config.jax_compilation_cache_dir == want
             f = compile_log.observed_jit(lambda x: x * 5 + 2, label="test.pcache")
-            f(jnp.ones(333))
-            assert os.listdir(cache_dir), "no persistent cache entries written"
+            f(jnp.ones(333 + env_set))
+            assert os.listdir(want), "no persistent cache entries written"
+            other = default_dir if env_set else env_dir
+            assert not os.path.exists(other) or not os.listdir(other)
             hits0 = compile_log.compile_cache_summary()["events"].get("cache_hits", 0)
             jax.clear_caches()  # drop in-memory executables: next dispatch
-            f(jnp.ones(333))  # must come from the PERSISTENT cache
+            f(jnp.ones(333 + env_set))  # must come from the PERSISTENT cache
             summary = compile_log.compile_cache_summary()
-            assert summary["dir"] == cache_dir
+            assert summary["dir"] == want
             assert summary["events"].get("cache_hits", 0) > hits0
         finally:
             jax.config.update("jax_compilation_cache_dir", None)
@@ -241,12 +253,19 @@ class TestPersistentCompileCache:
     def test_exporter_frame_carries_compile_cache(self, tmp_path, monkeypatch):
         import json
 
-        from hyperspace_tpu.telemetry.exporter import MetricsExporter
+        import jax
 
-        monkeypatch.setenv("HYPERSPACE_COMPILE_CACHE_DIR", str(tmp_path / "c"))
-        path = str(tmp_path / "frames.jsonl")
-        ex = MetricsExporter(path, interval_s=0.05).start()
-        ex.stop()
+        from hyperspace_tpu.telemetry.exporter import MetricsExporter
+        from hyperspace_tpu.util.compile_cache import enable_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+        try:
+            enable_compile_cache(str(tmp_path / "unused"))
+            path = str(tmp_path / "frames.jsonl")
+            ex = MetricsExporter(path, interval_s=0.05).start()
+            ex.stop()
+        finally:
+            jax.config.update("jax_compilation_cache_dir", None)
         frames = [json.loads(l) for l in open(path)]
         assert frames and frames[-1].get("final") is True
         assert frames[-1].get("compile_cache", {}).get("dir") == str(tmp_path / "c")
@@ -302,20 +321,20 @@ def test_skewed_layout_stays_on_classed_executor(tmp_path, monkeypatch):
     assert len(got) > 0 and got == expected
 
 
-def test_shim_pjit_compiles_sharded_program(tmp_path):
-    """The shim's pjit entry (jax.jit on this build) accepts sharding
-    annotations and runs a mesh-sharded program — the seam new sharded
-    programs should be declared through."""
+def test_jit_compiles_program_sharded_on_the_bucket_mesh(tmp_path):
+    """`jax.jit` with sharding annotations runs a program sharded over the
+    mesh `make_mesh` builds — the seam new sharded programs are declared
+    through."""
+    import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from hyperspace_tpu.parallel import make_mesh, pjit
+    from hyperspace_tpu.parallel import make_mesh
     from hyperspace_tpu.parallel.mesh import BUCKET_AXIS
 
     mesh = make_mesh(8)
     sh = NamedSharding(mesh, P(BUCKET_AXIS))
-    f = pjit(lambda x: x * 2 + 1, in_shardings=(sh,), out_shardings=sh)
-    import jax
+    f = jax.jit(lambda x: x * 2 + 1, in_shardings=(sh,), out_shardings=sh)
 
     x = jax.device_put(jnp.arange(64, dtype=jnp.int64), sh)
     out = f(x)

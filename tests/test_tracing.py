@@ -291,29 +291,32 @@ def test_stage_spans_ride_streaming_scan_aggregate(session, tmp_path, monkeypatc
     assert any(n.startswith("query:") and n != "query:stages" for n in names)
 
 
-def test_pallas_fallbacks_attach_to_build_and_query_summaries(monkeypatch):
-    """A Pallas fallback during an operation is visible on that operation's
-    build/query stage summary — as a PER-OPERATION delta, not the session
-    counters (a fallback in query 1 must not reappear on every later clean
-    operation's summary). Previously only record_join_stages carried any
-    fallback info at all."""
+def test_failing_pallas_probe_raises_out_of_traced_query(session, tmp_path, monkeypatch):
+    """A wanted Pallas probe that fails inside an indexed join surfaces as the
+    query's error (no silent XLA/host fallback), and stage summaries carry no
+    fallback key."""
+    from hyperspace_tpu.engine import physical
     from hyperspace_tpu.ops import pallas_probe
     from hyperspace_tpu.telemetry import profiling
 
-    counts = {"int": 3}
-    monkeypatch.setattr(pallas_probe, "_fallback_counts", counts)
-    st = profiling.StageTimings(mode="t")  # snapshots the baseline ({int: 3})
-    st.add("decode", 0.1)
-    counts["int"] = 5  # two fallbacks happen DURING the operation
-    profiling.record_build_stages(st.summary())
-    got = profiling.last_build_stages()
-    assert got["pallas_fallbacks"]["probe"]["failures"] == {"int": 2}
+    q = _indexed_join_agg(session, tmp_path)
+    expected = q().sorted_rows()
 
-    # A clean operation after the fallback latched: no fallback key at all.
-    clean = profiling.StageTimings(mode="t")
-    clean.add("eval", 0.1)
-    profiling.record_query_stages(clean.summary())
-    assert "pallas_fallbacks" not in profiling.last_query_stages()
+    def boom(*a, **k):
+        raise RuntimeError("mosaic lowering failed")
+
+    monkeypatch.setenv("HYPERSPACE_PALLAS_PROBE", "1")
+    monkeypatch.setattr(pallas_probe, "probe_pallas", boom)
+    physical.clear_device_memos()
+    with tracing.capture():
+        with pytest.raises(RuntimeError, match="mosaic lowering failed"):
+            q().collect()
+    for summary in (profiling.last_build_stages(), profiling.last_join_stages()):
+        assert summary is None or "pallas_fallbacks" not in summary
+
+    monkeypatch.undo()
+    physical.clear_device_memos()
+    assert q().sorted_rows() == expected
 
 
 def test_event_logger_bad_path_falls_back_to_noop():

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Compare two bench JSON artifacts and gate on timing regressions.
 
-The BENCH_r01…r05 trajectory has been eyeballed PR over PR; this tool makes
-the comparison mechanical: flatten both files to dotted numeric leaves,
+This tool makes the comparison of two bench artifacts mechanical: flatten both files to dotted numeric leaves,
 report per-metric deltas, and exit non-zero when any TIMING metric regressed
 past a configurable threshold.
 
@@ -147,7 +146,7 @@ def _history_rows(
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument(
-        "baseline", nargs="?", help="baseline bench JSON (e.g. BENCH_r04.json)"
+        "baseline", nargs="?", help="baseline bench JSON (e.g. BENCH_r01.json)"
     )
     ap.add_argument(
         "candidate", nargs="?", help="candidate bench JSON (e.g. BENCH_r05.json)"
