@@ -3,7 +3,9 @@
 Parity: reference `actions/Action.scala:34-104` — `run()` = validate → begin → op → end,
 where `begin()` writes log id base+1 with the transient state and `end()` writes id
 base+2 with the final state, then deletes and recreates the `latestStable` pointer.
-Telemetry events wrap the whole run; failures are logged and rethrown.
+Telemetry events wrap the whole run; failures are logged and rethrown. Under a
+traced action the two log writes are the spans `log:begin` and `log:commit`
+and the body is `action:op`.
 """
 
 from __future__ import annotations
@@ -15,6 +17,7 @@ from ..exceptions import ConcurrentWriteError, HyperspaceException, LogCommitErr
 from ..index.log_entry import LogEntry
 from ..index.log_manager import IndexLogManager
 from ..telemetry import metrics as _metrics
+from ..telemetry import tracing
 from ..telemetry.event_logging import EventLogger, NoOpEventLogger
 from ..telemetry.events import HyperspaceEvent
 from . import states
@@ -145,9 +148,12 @@ class Action:
         self._event_logger.log_event(self.event("Operation Started."))
         try:
             self.validate()
-            self.begin()
-            self.op()
-            self.end()
+            with tracing.span("log:begin"):
+                self.begin()
+            with tracing.span("action:op"):
+                self.op()
+            with tracing.span("log:commit"):
+                self.end()
             self._event_logger.log_event(self.event("Operation Succeeded."))
         except Exception as e:  # log + rethrow (reference behavior)
             self._event_logger.log_event(self.event(f"Operation Failed: {e}"))

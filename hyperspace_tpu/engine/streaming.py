@@ -189,41 +189,35 @@ def stream_aggregate(agg_exec, ctx) -> Optional[Table]:
 
     import numpy as np
 
-    stages = StageTimings(
-        mode="stream-device" if use_device_path() else "stream-cpu"
-    )
-    agg = StreamAggregator(agg_exec.group_keys, agg_exec.aggs, stages=stages)
-    # 0-row schema template accumulated across ALL chunks: the empty-input
-    # result must carry the same concat-PROMOTED dtypes (mixed-width files,
-    # union dictionaries) the materialized path would produce.
-    template: Optional[Table] = None
-    none_idx = np.empty(0, np.int64)
-    n_chunks = 0
-    from .. import resilience
+    with StageTimings(
+        "query", "stream-device" if use_device_path() else "stream-cpu"
+    ) as stages:
+        agg = StreamAggregator(agg_exec.group_keys, agg_exec.aggs, stages=stages)
+        # 0-row schema template accumulated across ALL chunks: the empty-input
+        # result must carry the same concat-PROMOTED dtypes (mixed-width files,
+        # union dictionaries) the materialized path would produce.
+        template: Optional[Table] = None
+        none_idx = np.empty(0, np.int64)
+        n_chunks = 0
+        from .. import resilience
 
-    for chunk in agg_exec.child.execute_stream(ctx, stages):
-        # Chunk-boundary cancellation: a deadlined query stops between
-        # chunks; nothing partial was cached (only-cache-on-success).
-        resilience.check_deadline("query.stream")
-        zero = chunk.take(none_idx)
-        template = zero if template is None else Table.concat([template, zero])
-        n_chunks += 1
-        agg.add_chunk(chunk)
-    out = agg.finalize()
-    if out is None:
-        if template is None:
-            return None  # nothing streamed: caller falls back
-        out = _empty_result(template, agg_exec.group_keys, agg_exec.aggs)
-    summary = stages.summary()
-    summary.update(
-        {
-            "chunks": n_chunks,
-            "rows": agg.rows,
-            "groups": out.num_rows,
-        }
-    )
-    record_query_stages(summary)
-    return out
+        for chunk in agg_exec.child.execute_stream(ctx, stages):
+            # Chunk-boundary cancellation: a deadlined query stops between
+            # chunks; nothing partial was cached (only-cache-on-success).
+            resilience.check_deadline("query.stream")
+            zero = chunk.take(none_idx)
+            template = zero if template is None else Table.concat([template, zero])
+            n_chunks += 1
+            agg.add_chunk(chunk)
+        out = agg.finalize()
+        if out is None:
+            if template is None:
+                return None  # nothing streamed: caller falls back
+            out = _empty_result(template, agg_exec.group_keys, agg_exec.aggs)
+        record_query_stages(
+            stages.summary(chunks=n_chunks, rows=agg.rows, groups=out.num_rows)
+        )
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -361,165 +355,164 @@ def stream_join_aggregate(agg_exec, join_exec, chain, ctx) -> Optional[Table]:
         # materialized (always byte-identical).
         return None
 
-    stages = StageTimings(mode="join-stream")
-    subkey = phys._pair_subkey(
-        join_exec.left_keys,
-        join_exec.right_keys,
-        join_exec.left,
-        join_exec.right,
-        left,
-        right,
-    )
-    rows_key = phys._pair_rows_key(join_exec.left, join_exec.right, ctx)
-
-    # Warm path: an earlier query (count/collect/materialized aggregate) on
-    # these rows already cached the VERIFIED pairs — start at the gathers.
-    verified, cached = phys._peek_two_table("pairs", left, right, subkey, rows_key)
-    from ..telemetry import tracing
-
-    tracing.set_attr("pairs_memo", "hit" if verified else "miss")
-    plan = ranges = None
-    ranges_hit = False
-    if verified:
-        li_all, ri_all = cached
-    else:
-        with stages.timed("pad"):
-            plan = phys._classed_plan_cached(
-                join_exec, left, right, l_starts, r_starts, subkey, rows_key
-            )
-        ranges_hit, ranges = phys._peek_two_table(
-            "pairs", left, right, ("cprobe", plan.mode) + subkey, rows_key
+    with StageTimings("join", "join-stream") as stages:
+        subkey = phys._pair_subkey(
+            join_exec.left_keys,
+            join_exec.right_keys,
+            join_exec.left,
+            join_exec.right,
+            left,
+            right,
         )
-        if not ranges_hit:
-            with stages.timed("probe"):
-                ranges = bj.probe_classed(plan)
-        with stages.timed("expand"):
-            li_all, ri_all = bj.classed_pairs(plan, ranges)
+        rows_key = phys._pair_rows_key(join_exec.left, join_exec.right, ctx)
 
-    agg = StreamAggregator(group_keys, agg_exec.aggs, stages=stages, direct_hint=hint)
+        # Warm path: an earlier query (count/collect/materialized aggregate) on
+        # these rows already cached the VERIFIED pairs — start at the gathers.
+        verified, cached = phys._peek_two_table("pairs", left, right, subkey, rows_key)
+        from ..telemetry import tracing
 
-    n = int(len(li_all))
-    chunk_rows = join_chunk_rows()
-    slices = [
-        (lo, min(lo + chunk_rows, n)) for lo in range(0, n, chunk_rows)
-    ] or [(0, 0)]
-    lkeys, rkeys = join_exec.left_keys, join_exec.right_keys
-    verified_parts: List[tuple] = []
-    template: Optional[Table] = None
-
-    def build_chunk(lo: int, hi: int):
-        from .physical import WithColumnExec, _assemble_join, _verify_pairs
-
-        li_c, ri_c = li_all[lo:hi], ri_all[lo:hi]
-        if not verified:
-            with stages.timed("verify"):
-                li_c, ri_c = _verify_pairs(left, right, lkeys, rkeys, li_c, ri_c)
-        with stages.timed("gather"):
-            t = _assemble_join(left, right, li_c, ri_c, "inner")
-        with stages.timed("eval"):
-            for op in reversed(chain):  # innermost (closest to the join) first
-                t = (
-                    op._apply(t)
-                    if isinstance(op, WithColumnExec)
-                    else t.select(op.column_names)
-                )
-        return li_c, ri_c, t
-
-    none_idx = np.empty(0, np.int64)
-
-    def consume(res) -> None:
-        nonlocal template
-        li_c, ri_c, t = res
-        if not verified:
-            verified_parts.append((li_c, ri_c))
-        if template is None:
-            template = t.take(none_idx)
-        agg.add_chunk(t)
-
-    from .. import resilience
-
-    workers = min(2, engine_io.decode_pool_size(len(slices)))
-    if workers <= 1 or len(slices) == 1:
-        for lo, hi in slices:
-            # Pair-chunk-boundary cancellation: a mid-stream deadline (like a
-            # mid-stream fault) propagates cleanly — the memos below are
-            # populated only after EVERY chunk streamed successfully.
-            resilience.check_deadline("query.join_stream")
-            consume(build_chunk(lo, hi))
-    else:
-        from collections import deque
-        from concurrent.futures import ThreadPoolExecutor
-
-        from ..telemetry import accounting as _accounting
-
-        # Workers adopt the submitting query's ledger and deadline scope
-        # (the io.py pool contract): without this, chunk work on pool
-        # threads — including any XLA compiles its device programs trigger —
-        # billed to NOTHING instead of the query that caused it.
-        led = _accounting.current_ledger()
-        sc = resilience.current_scope()
-
-        def build_chunk_adopted(lo: int, hi: int):
-            with _accounting.use_ledger(led), resilience.use_scope(sc):
-                return build_chunk(lo, hi)
-
-        pool = ThreadPoolExecutor(max_workers=workers)
-        try:
-            pending: "deque" = deque()
-            i = 0
-            while i < len(slices) or pending:
-                resilience.check_deadline("query.join_stream")
-                # Depth-bounded: at most workers+1 chunks in flight keeps
-                # resident chunk memory bounded while the NEXT chunk's
-                # verify/gather overlaps this one's aggregator fold.
-                while i < len(slices) and len(pending) < workers + 1:
-                    pending.append(pool.submit(build_chunk_adopted, *slices[i]))
-                    i += 1
-                consume(pending.popleft().result())
-        finally:
-            pool.shutdown(wait=False, cancel_futures=True)
-
-    # EVERY chunk streamed successfully: NOW (and only now) populate the
-    # memos, so warm queries — streamed or materialized, counts included —
-    # start from the verified pairs exactly as after a materialized run.
-    if not verified:
-        if verified_parts:
-            li_v = np.concatenate([p[0] for p in verified_parts])
-            ri_v = np.concatenate([p[1] for p in verified_parts])
+        tracing.set_attr("pairs_memo", "hit" if verified else "miss")
+        plan = ranges = None
+        ranges_hit = False
+        if verified:
+            li_all, ri_all = cached
         else:
-            li_v = ri_v = np.empty(0, np.int64)
-        phys._cached_two_table(
-            "pairs", left, right, subkey, lambda: (li_v, ri_v), rows_key=rows_key
-        )
-        if plan is not None and ranges is not None and not ranges_hit:
-            phys._cached_two_table(
-                "pairs",
-                left,
-                right,
-                ("cprobe", plan.mode) + subkey,
-                lambda: ranges,
-                rows_key=rows_key,
+            with stages.timed("pad"):
+                plan = phys._classed_plan_cached(
+                    join_exec, left, right, l_starts, r_starts, subkey, rows_key
+                )
+            ranges_hit, ranges = phys._peek_two_table(
+                "pairs", left, right, ("cprobe", plan.mode) + subkey, rows_key
             )
+            if not ranges_hit:
+                with stages.timed("probe"):
+                    ranges = bj.probe_classed(plan)
+            with stages.timed("expand"):
+                li_all, ri_all = bj.classed_pairs(plan, ranges)
 
-    out = agg.finalize()
-    if out is None:
-        if template is None:
-            return None
-        out = _empty_result(template, group_keys, agg_exec.aggs)
-    summary = stages.summary()
-    summary.update(
-        {
-            "chunks": agg.chunks,
-            "pairs": n,
-            "groups": out.num_rows,
-            "direct_cells": hint is not None,
-            "classes": None if plan is None else len(plan.segments),
-            "outliers": None if plan is None else int(len(plan.outlier_ids)),
-            "join_mode": None if plan is None else plan.mode,
-        }
-    )
-    record_join_stages(summary)
-    return out
+        agg = StreamAggregator(
+            group_keys, agg_exec.aggs, stages=stages, direct_hint=hint
+        )
+
+        n = int(len(li_all))
+        chunk_rows = join_chunk_rows()
+        slices = [
+            (lo, min(lo + chunk_rows, n)) for lo in range(0, n, chunk_rows)
+        ] or [(0, 0)]
+        lkeys, rkeys = join_exec.left_keys, join_exec.right_keys
+        verified_parts: List[tuple] = []
+        template: Optional[Table] = None
+
+        def build_chunk(lo: int, hi: int):
+            from .physical import WithColumnExec, _assemble_join, _verify_pairs
+
+            li_c, ri_c = li_all[lo:hi], ri_all[lo:hi]
+            if not verified:
+                with stages.timed("verify"):
+                    li_c, ri_c = _verify_pairs(left, right, lkeys, rkeys, li_c, ri_c)
+            with stages.timed("gather"):
+                t = _assemble_join(left, right, li_c, ri_c, "inner")
+            with stages.timed("eval"):
+                for op in reversed(chain):  # innermost (closest to the join) first
+                    t = (
+                        op._apply(t)
+                        if isinstance(op, WithColumnExec)
+                        else t.select(op.column_names)
+                    )
+            return li_c, ri_c, t
+
+        none_idx = np.empty(0, np.int64)
+
+        def consume(res) -> None:
+            nonlocal template
+            li_c, ri_c, t = res
+            if not verified:
+                verified_parts.append((li_c, ri_c))
+            if template is None:
+                template = t.take(none_idx)
+            agg.add_chunk(t)
+
+        from .. import resilience
+
+        workers = min(2, engine_io.decode_pool_size(len(slices)))
+        if workers <= 1 or len(slices) == 1:
+            for lo, hi in slices:
+                # Pair-chunk-boundary cancellation: a mid-stream deadline (like a
+                # mid-stream fault) propagates cleanly — the memos below are
+                # populated only after EVERY chunk streamed successfully.
+                resilience.check_deadline("query.join_stream")
+                consume(build_chunk(lo, hi))
+        else:
+            from collections import deque
+            from concurrent.futures import ThreadPoolExecutor
+
+            from ..telemetry import accounting as _accounting
+
+            # Workers adopt the submitting query's ledger and deadline scope
+            # (the io.py pool contract): without this, chunk work on pool
+            # threads — including any XLA compiles its device programs trigger —
+            # billed to NOTHING instead of the query that caused it.
+            led = _accounting.current_ledger()
+            sc = resilience.current_scope()
+
+            def build_chunk_adopted(lo: int, hi: int):
+                with _accounting.use_ledger(led), resilience.use_scope(sc):
+                    return build_chunk(lo, hi)
+
+            pool = ThreadPoolExecutor(max_workers=workers)
+            try:
+                pending: "deque" = deque()
+                i = 0
+                while i < len(slices) or pending:
+                    resilience.check_deadline("query.join_stream")
+                    # Depth-bounded: at most workers+1 chunks in flight keeps
+                    # resident chunk memory bounded while the NEXT chunk's
+                    # verify/gather overlaps this one's aggregator fold.
+                    while i < len(slices) and len(pending) < workers + 1:
+                        pending.append(pool.submit(build_chunk_adopted, *slices[i]))
+                        i += 1
+                    consume(pending.popleft().result())
+            finally:
+                pool.shutdown(wait=False, cancel_futures=True)
+
+        # EVERY chunk streamed successfully: NOW (and only now) populate the
+        # memos, so warm queries — streamed or materialized, counts included —
+        # start from the verified pairs exactly as after a materialized run.
+        if not verified:
+            if verified_parts:
+                li_v = np.concatenate([p[0] for p in verified_parts])
+                ri_v = np.concatenate([p[1] for p in verified_parts])
+            else:
+                li_v = ri_v = np.empty(0, np.int64)
+            phys._cached_two_table(
+                "pairs", left, right, subkey, lambda: (li_v, ri_v), rows_key=rows_key
+            )
+            if plan is not None and ranges is not None and not ranges_hit:
+                phys._cached_two_table(
+                    "pairs",
+                    left,
+                    right,
+                    ("cprobe", plan.mode) + subkey,
+                    lambda: ranges,
+                    rows_key=rows_key,
+                )
+
+        out = agg.finalize()
+        if out is None:
+            if template is None:
+                return None
+            out = _empty_result(template, group_keys, agg_exec.aggs)
+        summary = stages.summary(
+            chunks=agg.chunks,
+            pairs=n,
+            groups=out.num_rows,
+            direct_cells=hint is not None,
+            classes=None if plan is None else len(plan.segments),
+            outliers=None if plan is None else int(len(plan.outlier_ids)),
+            join_mode=None if plan is None else plan.mode,
+        )
+        record_join_stages(summary)
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -617,215 +610,212 @@ def stream_star_aggregate(agg_exec, star_exec, chain, ctx) -> Optional[Table]:
         # would reorder — those shapes stay on the cascade (byte-identical).
         return None
 
-    stages = StageTimings(mode="star-stream")
-    n_fact = fact.num_rows
-    per_dim = []  # (dim_table, counts, match_starts, ri_fact_major) per dim
-    dim_stats: List[dict] = []
-    memo_todo: List[tuple] = []
+    with StageTimings("join", "star-stream") as stages:
+        n_fact = fact.num_rows
+        per_dim = []  # (dim_table, counts, match_starts, ri_fact_major) per dim
+        dim_stats: List[dict] = []
+        memo_todo: List[tuple] = []
 
-    for dim_exec, fkeys, dkeys, index_name, num_buckets, dt, d_starts in dim_sides:
-        t0 = time.monotonic()
-        subkey = ("star",) + phys._pair_subkey(
-            list(fkeys), list(dkeys), star_exec.fact, dim_exec, fact, dt
-        )
-        rows_key = phys._pair_rows_key(star_exec.fact, dim_exec, ctx)
-        hit, cached = phys._peek_two_table("pairs", fact, dt, subkey, rows_key)
-        if hit:
-            li, ri = cached
-            stat = {
-                "index": index_name,
-                "buckets": int(num_buckets),
-                "pairs": int(len(li)),
-                "memo": "hit",
-            }
-        else:
-            with stages.timed("pad"):
-                # The fact was never bucket-partitioned on THIS dimension's
-                # keys: hash its FK columns into the dimension's bucket
-                # space (the exact build-time hash — narrow string codes
-                # hash via dh_table[codes], so values agree) and lay it out
-                # in bucket order on the fly.
-                fk_cols = [fact.column(k) for k in fkeys]
-                bid = np.asarray(
-                    bucket_id(
-                        fk_cols,
-                        [stage_codes(c, "star_probe") for c in fk_cols],
-                        num_buckets,
+        for dim_exec, fkeys, dkeys, index_name, num_buckets, dt, d_starts in dim_sides:
+            t0 = time.monotonic()
+            subkey = ("star",) + phys._pair_subkey(
+                list(fkeys), list(dkeys), star_exec.fact, dim_exec, fact, dt
+            )
+            rows_key = phys._pair_rows_key(star_exec.fact, dim_exec, ctx)
+            hit, cached = phys._peek_two_table("pairs", fact, dt, subkey, rows_key)
+            if hit:
+                li, ri = cached
+                stat = {
+                    "index": index_name,
+                    "buckets": int(num_buckets),
+                    "pairs": int(len(li)),
+                    "memo": "hit",
+                }
+            else:
+                with stages.timed("pad"):
+                    # The fact was never bucket-partitioned on THIS dimension's
+                    # keys: hash its FK columns into the dimension's bucket
+                    # space (the exact build-time hash — narrow string codes
+                    # hash via dh_table[codes], so values agree) and lay it out
+                    # in bucket order on the fly.
+                    fk_cols = [fact.column(k) for k in fkeys]
+                    bid = np.asarray(
+                        bucket_id(
+                            fk_cols,
+                            [stage_codes(c, "star_probe") for c in fk_cols],
+                            num_buckets,
+                        )
+                    )
+                    perm, f_starts = bj.fact_bucket_layout(bid, num_buckets)
+                    l_flags, r_flags = phys._joint_float_flags(
+                        fact, dt, list(fkeys), list(dkeys)
+                    )
+                    l_vals = np.asarray(
+                        phys._table_key64(fact, list(fkeys), l_flags)
+                    )[perm]
+                    r_vals = np.asarray(phys._table_key64(dt, list(dkeys), r_flags))
+                    plan = bj.build_classed_plan(
+                        l_vals,
+                        r_vals,
+                        f_starts,
+                        np.asarray(d_starts, np.int64),
+                        "hash",
+                        device=use_device_path(),
+                    )
+                pad_s = time.monotonic() - t0
+                t1 = time.monotonic()
+                with stages.timed("probe"):
+                    ranges = bj.probe_classed(plan)
+                with stages.timed("expand"):
+                    pli, ri = bj.classed_pairs(plan, ranges)
+                li = perm[pli]  # bucket-layout positions → original fact rows
+                probe_s = time.monotonic() - t1
+                t2 = time.monotonic()
+                with stages.timed("verify"):
+                    li, ri = phys._verify_pairs(
+                        fact, dt, list(fkeys), list(dkeys), li, ri
+                    )
+                # Fact-major order (stable: within one fact row, matches keep
+                # the deterministic bucket-major probe order) — the layout the
+                # per-chunk odometer composes from.
+                order = np.argsort(li, kind="stable")
+                li, ri = li[order], ri[order]
+                verify_s = time.monotonic() - t2
+                memo_todo.append((dt, subkey, rows_key, li, ri))
+                stat = {
+                    "index": index_name,
+                    "buckets": int(num_buckets),
+                    "pairs": int(len(li)),
+                    "memo": "miss",
+                    "pad_s": round(pad_s, 5),
+                    "probe_s": round(probe_s, 5),
+                    "verify_s": round(verify_s, 5),
+                }
+            counts = np.bincount(li, minlength=n_fact).astype(np.int64)
+            mstarts = np.zeros(n_fact + 1, np.int64)
+            np.cumsum(counts, out=mstarts[1:])
+            per_dim.append((dt, counts, mstarts, ri))
+            dim_stats.append(stat)
+
+        ndims = len(per_dim)
+        # Output rows per fact row = the product of its per-dimension match
+        # counts (the star's survivor composition); chunk boundaries slice FACT
+        # rows so each chunk's output stays near the join chunk budget.
+        K = per_dim[0][1].copy()
+        for _dt, counts, _ms, _ri in per_dim[1:]:
+            K = K * counts
+        out_starts = np.zeros(n_fact + 1, np.int64)
+        np.cumsum(K, out=out_starts[1:])
+        total_pairs = int(out_starts[-1])
+        chunk_rows = join_chunk_rows()
+        bounds = [0]
+        while bounds[-1] < n_fact:
+            lo = bounds[-1]
+            hi = (
+                int(
+                    np.searchsorted(
+                        out_starts, out_starts[lo] + chunk_rows, side="right"
                     )
                 )
-                perm, f_starts = bj.fact_bucket_layout(bid, num_buckets)
-                l_flags, r_flags = phys._joint_float_flags(
-                    fact, dt, list(fkeys), list(dkeys)
-                )
-                l_vals = np.asarray(
-                    phys._table_key64(fact, list(fkeys), l_flags)
-                )[perm]
-                r_vals = np.asarray(phys._table_key64(dt, list(dkeys), r_flags))
-                plan = bj.build_classed_plan(
-                    l_vals,
-                    r_vals,
-                    f_starts,
-                    np.asarray(d_starts, np.int64),
-                    "hash",
-                    device=use_device_path(),
-                )
-            pad_s = time.monotonic() - t0
-            t1 = time.monotonic()
-            with stages.timed("probe"):
-                ranges = bj.probe_classed(plan)
-            with stages.timed("expand"):
-                pli, ri = bj.classed_pairs(plan, ranges)
-            li = perm[pli]  # bucket-layout positions → original fact rows
-            probe_s = time.monotonic() - t1
-            t2 = time.monotonic()
-            with stages.timed("verify"):
-                li, ri = phys._verify_pairs(
-                    fact, dt, list(fkeys), list(dkeys), li, ri
-                )
-            # Fact-major order (stable: within one fact row, matches keep
-            # the deterministic bucket-major probe order) — the layout the
-            # per-chunk odometer composes from.
-            order = np.argsort(li, kind="stable")
-            li, ri = li[order], ri[order]
-            verify_s = time.monotonic() - t2
-            memo_todo.append((dt, subkey, rows_key, li, ri))
-            stat = {
-                "index": index_name,
-                "buckets": int(num_buckets),
-                "pairs": int(len(li)),
-                "memo": "miss",
-                "pad_s": round(pad_s, 5),
-                "probe_s": round(probe_s, 5),
-                "verify_s": round(verify_s, 5),
-            }
-        counts = np.bincount(li, minlength=n_fact).astype(np.int64)
-        mstarts = np.zeros(n_fact + 1, np.int64)
-        np.cumsum(counts, out=mstarts[1:])
-        per_dim.append((dt, counts, mstarts, ri))
-        dim_stats.append(stat)
-
-    ndims = len(per_dim)
-    # Output rows per fact row = the product of its per-dimension match
-    # counts (the star's survivor composition); chunk boundaries slice FACT
-    # rows so each chunk's output stays near the join chunk budget.
-    K = per_dim[0][1].copy()
-    for _dt, counts, _ms, _ri in per_dim[1:]:
-        K = K * counts
-    out_starts = np.zeros(n_fact + 1, np.int64)
-    np.cumsum(K, out=out_starts[1:])
-    total_pairs = int(out_starts[-1])
-    chunk_rows = join_chunk_rows()
-    bounds = [0]
-    while bounds[-1] < n_fact:
-        lo = bounds[-1]
-        hi = (
-            int(
-                np.searchsorted(
-                    out_starts, out_starts[lo] + chunk_rows, side="right"
-                )
+                - 1
             )
-            - 1
+            bounds.append(min(max(hi, lo + 1), n_fact))
+
+        agg = StreamAggregator(
+            group_keys, agg_exec.aggs, stages=stages, direct_hint=hint
         )
-        bounds.append(min(max(hi, lo + 1), n_fact))
 
-    agg = StreamAggregator(
-        group_keys, agg_exec.aggs, stages=stages, direct_hint=hint
-    )
+        def build_chunk(lo: int, hi: int) -> Table:
+            from .physical import WithColumnExec
 
-    def build_chunk(lo: int, hi: int) -> Table:
-        from .physical import WithColumnExec
-
-        Kc = K[lo:hi]
-        nz = np.nonzero(Kc)[0]
-        rows = nz + lo
-        Kr = Kc[nz]
-        tot = int(Kr.sum())
-        with stages.timed("gather"):
-            if tot == 0:
-                fact_idx = np.empty(0, np.int64)
-                sels = [np.empty(0, np.int64)] * ndims
-            else:
-                fact_idx = np.repeat(rows, Kr)
-                ends = np.cumsum(Kr)
-                off = np.arange(tot, dtype=np.int64) - np.repeat(ends - Kr, Kr)
-                # Per-row odometer over the dimensions (last dim varies
-                # fastest): composition j of fact row i selects match
-                # (j // stride_d) % count_d from each dimension's list.
-                strides: List = [None] * ndims
-                stride = np.ones(len(rows), np.int64)
-                for d in range(ndims - 1, -1, -1):
-                    strides[d] = stride
-                    stride = stride * per_dim[d][1][rows]
-                sels = []
-                for d in range(ndims):
-                    st_e = np.repeat(strides[d], Kr)
-                    cnt_e = np.repeat(per_dim[d][1][rows], Kr)
-                    sels.append((off // st_e) % cnt_e)
-            parts = [fact.take(fact_idx)]
-            for d in range(ndims):
-                dt_d, _counts, mstarts_d, ri_d = per_dim[d]
+            Kc = K[lo:hi]
+            nz = np.nonzero(Kc)[0]
+            rows = nz + lo
+            Kr = Kc[nz]
+            tot = int(Kr.sum())
+            with stages.timed("gather"):
                 if tot == 0:
-                    dim_idx = np.empty(0, np.int64)
+                    fact_idx = np.empty(0, np.int64)
+                    sels = [np.empty(0, np.int64)] * ndims
                 else:
-                    dim_idx = ri_d[np.repeat(mstarts_d[rows], Kr) + sels[d]]
-                parts.append(dt_d.take(dim_idx))
-        with stages.timed("eval"):
-            cols = {}
-            for p in parts:
-                for n, c in p.columns.items():
-                    cols[n if n not in cols else f"{n}_r"] = c
-            t = Table(cols)
-            for op in reversed(chain):  # innermost (closest to the join) first
-                t = (
-                    op._apply(t)
-                    if isinstance(op, WithColumnExec)
-                    else t.select(op.column_names)
-                )
-        return t
+                    fact_idx = np.repeat(rows, Kr)
+                    ends = np.cumsum(Kr)
+                    off = np.arange(tot, dtype=np.int64) - np.repeat(ends - Kr, Kr)
+                    # Per-row odometer over the dimensions (last dim varies
+                    # fastest): composition j of fact row i selects match
+                    # (j // stride_d) % count_d from each dimension's list.
+                    strides: List = [None] * ndims
+                    stride = np.ones(len(rows), np.int64)
+                    for d in range(ndims - 1, -1, -1):
+                        strides[d] = stride
+                        stride = stride * per_dim[d][1][rows]
+                    sels = []
+                    for d in range(ndims):
+                        st_e = np.repeat(strides[d], Kr)
+                        cnt_e = np.repeat(per_dim[d][1][rows], Kr)
+                        sels.append((off // st_e) % cnt_e)
+                parts = [fact.take(fact_idx)]
+                for d in range(ndims):
+                    dt_d, _counts, mstarts_d, ri_d = per_dim[d]
+                    if tot == 0:
+                        dim_idx = np.empty(0, np.int64)
+                    else:
+                        dim_idx = ri_d[np.repeat(mstarts_d[rows], Kr) + sels[d]]
+                    parts.append(dt_d.take(dim_idx))
+            with stages.timed("eval"):
+                cols = {}
+                for p in parts:
+                    for n, c in p.columns.items():
+                        cols[n if n not in cols else f"{n}_r"] = c
+                t = Table(cols)
+                for op in reversed(chain):  # innermost (closest to the join) first
+                    t = (
+                        op._apply(t)
+                        if isinstance(op, WithColumnExec)
+                        else t.select(op.column_names)
+                    )
+            return t
 
-    from .. import resilience
+        from .. import resilience
 
-    template: Optional[Table] = None
-    none_idx = np.empty(0, np.int64)
-    n_chunks = 0
-    for lo, hi in zip(bounds, bounds[1:]):
-        # Chunk-boundary cancellation: a mid-stream deadline (like a
-        # mid-stream fault) propagates cleanly — the memos below are
-        # populated only after EVERY chunk streamed successfully.
-        resilience.check_deadline("query.star_stream")
-        t = build_chunk(lo, hi)
-        if template is None:
-            template = t.take(none_idx)
-        n_chunks += 1
-        agg.add_chunk(t)
+        template: Optional[Table] = None
+        none_idx = np.empty(0, np.int64)
+        n_chunks = 0
+        for lo, hi in zip(bounds, bounds[1:]):
+            # Chunk-boundary cancellation: a mid-stream deadline (like a
+            # mid-stream fault) propagates cleanly — the memos below are
+            # populated only after EVERY chunk streamed successfully.
+            resilience.check_deadline("query.star_stream")
+            t = build_chunk(lo, hi)
+            if template is None:
+                template = t.take(none_idx)
+            n_chunks += 1
+            agg.add_chunk(t)
 
-    # EVERY chunk streamed successfully: NOW (and only now) populate the
-    # per-dimension pair memos, so warm star queries start at composition.
-    for dt_m, subkey, rows_key, li_v, ri_v in memo_todo:
-        phys._cached_two_table(
-            "pairs",
-            fact,
-            dt_m,
-            subkey,
-            lambda li_v=li_v, ri_v=ri_v: (li_v, ri_v),
-            rows_key=rows_key,
+        # EVERY chunk streamed successfully: NOW (and only now) populate the
+        # per-dimension pair memos, so warm star queries start at composition.
+        for dt_m, subkey, rows_key, li_v, ri_v in memo_todo:
+            phys._cached_two_table(
+                "pairs",
+                fact,
+                dt_m,
+                subkey,
+                lambda li_v=li_v, ri_v=ri_v: (li_v, ri_v),
+                rows_key=rows_key,
+            )
+
+        out = agg.finalize()
+        if out is None:
+            if template is None:
+                return None
+            out = _empty_result(template, group_keys, agg_exec.aggs)
+        summary = stages.summary(
+            chunks=n_chunks,
+            pairs=total_pairs,
+            groups=out.num_rows,
+            direct_cells=hint is not None,
+            join_mode="star",
+            star_dims=dim_stats,
         )
-
-    out = agg.finalize()
-    if out is None:
-        if template is None:
-            return None
-        out = _empty_result(template, group_keys, agg_exec.aggs)
-    summary = stages.summary()
-    summary.update(
-        {
-            "chunks": n_chunks,
-            "pairs": total_pairs,
-            "groups": out.num_rows,
-            "direct_cells": hint is not None,
-            "join_mode": "star",
-            "star_dims": dim_stats,
-        }
-    )
-    record_join_stages(summary)
-    return out
+        record_join_stages(summary)
+        return out

@@ -52,13 +52,23 @@ class Hyperspace:
                 self._manager.create(df, index_config)
 
     def delete_index(self, index_name: str) -> None:
-        self._manager.delete(index_name)
+        from . import resilience
+        from .telemetry import tracing
+
+        with resilience.query_scope("build:delete_index"):
+            with tracing.query_span("build:delete_index", index_name=index_name):
+                self._manager.delete(index_name)
 
     def restore_index(self, index_name: str) -> None:
         self._manager.restore(index_name)
 
     def vacuum_index(self, index_name: str) -> None:
-        self._manager.vacuum(index_name)
+        from . import resilience
+        from .telemetry import tracing
+
+        with resilience.query_scope("build:vacuum_index"):
+            with tracing.query_span("build:vacuum_index", index_name=index_name):
+                self._manager.vacuum(index_name)
 
     def refresh_index(self, index_name: str, mode: Optional[str] = None) -> None:
         """mode="full": rebuild from scratch (reference behavior).

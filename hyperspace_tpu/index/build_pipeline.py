@@ -48,8 +48,15 @@ index files, pinned by `tests/test_build_pipeline.py` and
 `tests/test_mesh_compile.py` respectively. Any change to the sort tie order
 here breaks BOTH contracts at once.
 
-Stage timings (decode/hash/h2d/sort/write, wall, overlap ratio) are recorded
-via `telemetry.profiling.record_build_stages` and surfaced in `bench.py`'s
+Every stage runs inside `StageTimings.timed`, the one bracket behind the
+busy-time sums, the stage ledger and the spans: under ``build:stages`` (the
+pipeline's wall) each file decoded is a ``build:decode`` span, each chunk's
+key staging ``build:h2d`` (``build:hash`` on the CPU), then ``build:sort``,
+the writer's ``build:concat``, one ``build:take`` per column and one
+``build:write`` per non-empty bucket — each on the thread that did the work,
+with its ``rows``/``bytes``/``file``/``bucket`` counts as attributes. The
+summary (busy seconds per stage, wall, overlap ratio) is recorded via
+`telemetry.profiling.record_build_stages` and surfaced in `bench.py`'s
 ``bench_detail``.
 """
 
@@ -66,6 +73,7 @@ import numpy as np
 
 from ..config import IndexConstants
 from ..engine import io as engine_io
+from ..engine.encoding import column_nbytes
 from ..engine.schema import STRING
 from ..engine.table import Column, Table
 from ..exceptions import HyperspaceException
@@ -205,7 +213,7 @@ def _sort_pipeline(
 
     n = sum(t.num_rows for t in chunks)
     if not use_device_path():
-        with stages.timed("sort"):
+        with stages.timed("sort", rows=n):
             b_host = (
                 np.concatenate(chunk_bucket_ids)
                 if chunk_bucket_ids
@@ -218,7 +226,7 @@ def _sort_pipeline(
         # Numeric keys, staged while decoding: hash+concat+sort in ONE
         # donated-buffer program (or the Pallas composite sort when the whole
         # build fits VMEM).
-        with stages.timed("sort"):
+        with stages.timed("sort", rows=n):
             valid_lens = [t.num_rows for t in chunks]
             perm = sorted_b = None
             if len(key_names) == 1 and len(staged_device[0]) >= 1:
@@ -249,9 +257,9 @@ def _sort_pipeline(
 
         with stages.timed("concat"):
             key_cols = _concat_key_columns(chunks, key_names)
-        with stages.timed("h2d"):
+        with stages.timed("h2d", bytes=sum(c.data.nbytes for c in key_cols)):
             arrs = [jnp.asarray(c.data) for c in key_cols]
-        with stages.timed("sort"):
+        with stages.timed("sort", rows=n):
             b = bucket_id(key_cols, arrs, num_buckets)
             perm_d, sorted_b_d = _sort_perm(
                 b, tuple(_sortable(a) for a in arrs), n
@@ -354,11 +362,14 @@ class _BucketWriter:
             codes = np.asarray(sl)
         return _encoding.dictionary_arrow_array(codes, self.dicts[n], mask)
 
-    def write_bucket(self, b: int, lo: int, hi: int) -> None:
+    def write_bucket(self, b: int, lo: int, hi: int) -> Optional[str]:
+        """Write bucket `b` (rows [lo, hi) of the sorted order); returns the
+        file's path, None for an empty bucket."""
         if hi <= lo:
-            return  # empty bucket: no file (same contract as the serial path)
+            return None  # empty bucket: no file (same contract as the serial path)
         import pyarrow as pa
 
+        path = os.path.join(self.index_data_path, f"part-{b:05d}.parquet")
         out = pa.table({n: self._bucket_array(n, lo, hi) for n in self.names})
         # Bounded row groups over the key-sorted bucket rows: the footer zone
         # maps then resolve point/range filters INSIDE the bucket file (scan
@@ -366,10 +377,9 @@ class _BucketWriter:
         # contract between the two paths includes the row-group layout, and
         # both paths now write through ONE `storage.write` fault/retry site.
         engine_io.checked_write_table(
-            out,
-            os.path.join(self.index_data_path, f"part-{b:05d}.parquet"),
-            row_group_rows=engine_io.index_row_group_rows(),
+            out, path, row_group_rows=engine_io.index_row_group_rows()
         )
+        return path
 
     def run(self, perm: np.ndarray, starts: np.ndarray, pool_size: int) -> None:
         import pyarrow as pa
@@ -396,18 +406,24 @@ class _BucketWriter:
             bfuts = [
                 pool.submit(self._timed_bucket, b, int(starts[b]), int(starts[b + 1]))
                 for b in range(num_buckets)
+                if starts[b + 1] > starts[b]  # an empty bucket has no file
             ]
             done, _ = wait(bfuts, return_when=FIRST_EXCEPTION)
             for f in done:
                 f.result()  # re-raise the first worker failure
 
     def _timed_take(self, pc, arr, idx):
-        with self.stages.timed("take"):
+        with self.stages.timed("take", rows=len(idx)):
             return pc.take(arr, idx)
 
     def _timed_bucket(self, b: int, lo: int, hi: int) -> None:
-        with self.stages.timed("write"):
-            self.write_bucket(b, lo, hi)
+        with self.stages.timed("write", bucket=b, rows=hi - lo) as sp:
+            path = self.write_bucket(b, lo, hi)
+        # The file's size is read after the bracket, so the span times the
+        # write alone; an ended span still takes attributes until its root
+        # ends.
+        if sp.recording and path is not None:
+            sp.set_attr("bytes", os.path.getsize(path))
 
 
 def pipelined_write(
@@ -427,41 +443,42 @@ def pipelined_write(
         raise HyperspaceException("No data files to read.")
     from ..ops.backend import use_device_path
 
-    stages = StageTimings(mode="pipelined-device" if use_device_path() else "pipelined-cpu")
-    n_files = len(files_in_order)
+    mode = "pipelined-device" if use_device_path() else "pipelined-cpu"
+    with StageTimings("build", mode) as stages:
+        n_files = len(files_in_order)
 
-    # Warm-source shortcut: when the exact concat this build would assemble is
-    # already cached (a prior query or build read the same files + columns),
-    # the whole decode stage collapses to reusing it — the "reuse scan_cache
-    # entries when warm" contract, one level up.
-    if not lineage:
-        _, cached_concat = engine_io.concat_cache_probe(
-            files_in_order, file_format, wanted, partitions
-        )
-        if cached_concat is not None:
-            stages.add("decode", 0.0)
-            return _finish_from_chunks(
-                _split_chunks(cached_concat, _effective_chunk_rows(cfg)),
-                key_names,
-                num_buckets,
-                index_data_path,
-                cfg,
-                stages,
-                n_files,
+        # Warm-source shortcut: when the exact concat this build would assemble
+        # is already cached (a prior query or build read the same files +
+        # columns), the whole decode stage collapses to reusing it — the
+        # "reuse scan_cache entries when warm" contract, one level up.
+        if not lineage:
+            _, cached_concat = engine_io.concat_cache_probe(
+                files_in_order, file_format, wanted, partitions
             )
+            if cached_concat is not None:
+                stages.add("decode", 0.0)
+                return _finish_from_chunks(
+                    _split_chunks(cached_concat, _effective_chunk_rows(cfg)),
+                    key_names,
+                    num_buckets,
+                    index_data_path,
+                    cfg,
+                    stages,
+                    n_files,
+                )
 
-    return _decode_and_finish(
-        files_in_order,
-        file_format,
-        wanted,
-        partitions,
-        lineage,
-        key_names,
-        num_buckets,
-        index_data_path,
-        cfg,
-        stages,
-    )
+        return _decode_and_finish(
+            files_in_order,
+            file_format,
+            wanted,
+            partitions,
+            lineage,
+            key_names,
+            num_buckets,
+            index_data_path,
+            cfg,
+            stages,
+        )
 
 
 def _stage_chunk_device(key_cols: List[Column], stages: StageTimings) -> List["object"]:
@@ -471,14 +488,17 @@ def _stage_chunk_device(key_cols: List[Column], stages: StageTimings) -> List["o
     variety; the buffers are later DONATED to the sort program."""
     import jax
 
-    with stages.timed("h2d"):
+    with stages.timed("h2d") as sp:
         bufs = []
+        staged = 0
         for c in key_cols:
             pad_n = _pow2_ceil(len(c.data))
             host = c.data
             if pad_n != len(host):
                 host = np.concatenate([host, np.zeros(pad_n - len(host), host.dtype)])
             bufs.append(jax.device_put(host))
+            staged += host.nbytes
+        sp.set_attr("bytes", staged)
         return bufs
 
 
@@ -489,7 +509,7 @@ def _hash_chunk(key_cols: List[Column], num_buckets: int, stages: StageTimings) 
 
     from ..ops.hashing import bucket_id
 
-    with stages.timed("hash"):
+    with stages.timed("hash", rows=len(key_cols[0].data)):
         arrs = [jnp.asarray(c.data) for c in key_cols]
         return np.asarray(bucket_id(key_cols, arrs, num_buckets))
 
@@ -579,10 +599,16 @@ def _decode_and_finish(
     def decode_one(i: int) -> None:
         with _accounting.use_ledger(led), _resilience.use_scope(sc):
             _faults.check("pool.worker")
-            with stages.timed("decode"):
-                file_tables[i] = _decode_file(
+            with stages.timed("decode", file=i) as sp:
+                t = _decode_file(
                     files_in_order[i], file_format, wanted, partitions, lineage
                 )
+                file_tables[i] = t
+                if sp.recording:
+                    sp.add_attrs(
+                        rows=t.num_rows,
+                        bytes=sum(column_nbytes(c) for c in t.columns.values()),
+                    )
         hash_q.put(i)
 
     device = use_device_path()
@@ -700,15 +726,12 @@ def _sort_write_summarize(
 
     writer.run(perm, starts, cfg.writers)
 
-    summary = stages.summary()
-    summary.update(
-        {
-            "rows": int(perm.shape[0]),
-            "files": n_files,
-            "chunks": len(chunks),
-            "decode_threads": cfg.decode_threads,
-            "writers": cfg.writers,
-        }
+    summary = stages.summary(
+        rows=int(perm.shape[0]),
+        files=n_files,
+        chunks=len(chunks),
+        decode_threads=cfg.decode_threads,
+        writers=cfg.writers,
     )
     record_build_stages(summary)
     return summary

@@ -171,61 +171,62 @@ class CoveringIndexBuilder(IndexerBuilder):
 
         from ..telemetry.profiling import StageTimings, record_build_stages
 
-        stages = StageTimings(mode="serial")
-        with stages.timed("decode"):
-            table = self._prepare_index_table(df, index_config)
-        mesh = self._session.mesh_for(table.num_rows)
-        if mesh is not None:
-            stages.mode = "mesh"
-        with stages.timed("sort"):
+        with StageTimings("build", "serial") as stages:
+            with stages.timed("decode"):
+                table = self._prepare_index_table(df, index_config)
+            mesh = self._session.mesh_for(table.num_rows)
             if mesh is not None:
-                # Cluster-wide build (the reference's repartition+bucketed-write
-                # runs on the whole Spark cluster, `CreateActionBase.scala:119-140`):
-                # rows ride an all_to_all over the mesh; identical hash →
-                # identical index files.
-                from ..parallel.table_ops import distributed_bucketize_table
+                stages.mode = "mesh"
+            with stages.timed("sort", rows=table.num_rows):
+                if mesh is not None:
+                    # Cluster-wide build (the reference's repartition+bucketed-
+                    # write runs on the whole Spark cluster,
+                    # `CreateActionBase.scala:119-140`): rows ride an
+                    # all_to_all over the mesh; identical hash → identical
+                    # index files.
+                    from ..parallel.table_ops import distributed_bucketize_table
 
-                sorted_table, starts = distributed_bucketize_table(
-                    mesh, table, indexed, num_buckets
+                    sorted_table, starts = distributed_bucketize_table(
+                        mesh, table, indexed, num_buckets
+                    )
+                else:
+                    sorted_table, starts = bucketize_table(table, indexed, num_buckets)
+            os.makedirs(index_data_path, exist_ok=True)
+            import numpy as np
+            from concurrent.futures import ThreadPoolExecutor
+
+            def write_bucket(b: int) -> None:
+                lo, hi = int(starts[b]), int(starts[b + 1])
+                if hi <= lo:
+                    return  # empty bucket: no file
+                bucket_table = sorted_table.take(np.arange(lo, hi))
+                # Bounded, key-sorted row groups (same bound as the pipelined
+                # writer — the byte-identity contract includes the layout):
+                # scan pushdown prunes inside bucket files through the footer
+                # stats.
+                engine_io.write_parquet(
+                    bucket_table,
+                    os.path.join(index_data_path, f"part-{b:05d}.parquet"),
+                    row_group_rows=engine_io.index_row_group_rows(),
                 )
-            else:
-                sorted_table, starts = bucketize_table(table, indexed, num_buckets)
-        os.makedirs(index_data_path, exist_ok=True)
-        import numpy as np
-        from concurrent.futures import ThreadPoolExecutor
 
-        def write_bucket(b: int) -> None:
-            lo, hi = int(starts[b]), int(starts[b + 1])
-            if hi <= lo:
-                return  # empty bucket: no file
-            bucket_table = sorted_table.take(np.arange(lo, hi))
-            # Bounded, key-sorted row groups (same bound as the pipelined
-            # writer — the byte-identity contract includes the layout): scan
-            # pushdown prunes inside bucket files through the footer stats.
-            engine_io.write_parquet(
-                bucket_table,
-                os.path.join(index_data_path, f"part-{b:05d}.parquet"),
-                row_group_rows=engine_io.index_row_group_rows(),
-            )
-
-        # Parquet encode is pyarrow C++ work that releases the GIL: writing the
-        # bucket files concurrently keeps the build from serializing on host I/O
-        # (SURVEY §7 — the executors of the reference's bucketed write ran
-        # cluster-wide for the same reason).
-        # Per-bucket tasks for BOTH paths: the pool load-balances small
-        # parquet encodes regardless of which device owned a bucket. The mesh
-        # layout's per-shard file ownership (device d's exchange block IS its
-        # contiguous bucket range [d·B/n, (d+1)·B/n)) matters on a MULTI-HOST
-        # mesh, where each host would map only its own devices' bucket range
-        # here — on one host, coarser shard-sized tasks would only serialize
-        # a skewed shard's writes behind one worker. File names and bytes are
-        # identical across the mesh and single-device paths either way.
-        with stages.timed("write"):
-            with ThreadPoolExecutor(max_workers=cfg.writers) as pool:
-                list(pool.map(write_bucket, range(num_buckets)))
-        summary = stages.summary()
-        summary["rows"] = table.num_rows
-        record_build_stages(summary)
+            # Parquet encode is pyarrow C++ work that releases the GIL: writing
+            # the bucket files concurrently keeps the build from serializing on
+            # host I/O (SURVEY §7 — the executors of the reference's bucketed
+            # write ran cluster-wide for the same reason).
+            # Per-bucket tasks for BOTH paths: the pool load-balances small
+            # parquet encodes regardless of which device owned a bucket. The
+            # mesh layout's per-shard file ownership (device d's exchange block
+            # IS its contiguous bucket range [d·B/n, (d+1)·B/n)) matters on a
+            # MULTI-HOST mesh, where each host would map only its own devices'
+            # bucket range here — on one host, coarser shard-sized tasks would
+            # only serialize a skewed shard's writes behind one worker. File
+            # names and bytes are identical across the mesh and single-device
+            # paths either way.
+            with stages.timed("write"):
+                with ThreadPoolExecutor(max_workers=cfg.writers) as pool:
+                    list(pool.map(write_bucket, range(num_buckets)))
+            record_build_stages(stages.summary(rows=table.num_rows))
 
     def _mesh_may_apply(self, rel: SourceRelation) -> bool:
         """Whether the distributed mesh build could claim this source — decided

@@ -36,6 +36,7 @@ from typing import Iterator, List, Optional, Tuple
 
 from ..exceptions import ConcurrentWriteError
 from ..telemetry import metrics as _metrics
+from ..telemetry import tracing
 
 #: Dot prefix: `util.path_utils.is_data_path` treats '.'-prefixed names as
 #: metadata UNCONDITIONALLY (unlike '_'-prefixed, where '=' re-admits hive
@@ -94,8 +95,9 @@ def _pid_alive(pid: int) -> bool:
 @contextlib.contextmanager
 def stage_commit(final_path: str) -> Iterator[str]:
     """Yield a staging directory for the build of `final_path`; commit it by
-    rename on clean exit, delete it on failure. Raises `ConcurrentWriteError`
-    (after cleaning up) when `final_path` appeared concurrently."""
+    rename on clean exit (the span `build:commit`), delete it on failure.
+    Raises `ConcurrentWriteError` (after cleaning up) when `final_path`
+    appeared concurrently."""
     final_path = final_path.rstrip(os.sep)
     parent = os.path.dirname(final_path) or "."
     os.makedirs(parent, exist_ok=True)
@@ -111,17 +113,18 @@ def stage_commit(final_path: str) -> Iterator[str]:
         # to commit, and `Content.from_directory` of a missing final dir is
         # already the empty inventory.
         return
-    try:
-        os.rename(stage, final_path)
-    except OSError as e:
-        _ABORTS.inc()
-        shutil.rmtree(stage, ignore_errors=True)
-        if os.path.exists(final_path):
-            raise ConcurrentWriteError(
-                f"Another writer committed {final_path} first; this build was "
-                "aborted cleanly. Please retry."
-            ) from e
-        raise
+    with tracing.span("build:commit"):
+        try:
+            os.rename(stage, final_path)
+        except OSError as e:
+            _ABORTS.inc()
+            shutil.rmtree(stage, ignore_errors=True)
+            if os.path.exists(final_path):
+                raise ConcurrentWriteError(
+                    f"Another writer committed {final_path} first; this build "
+                    "was aborted cleanly. Please retry."
+                ) from e
+            raise
     _COMMITS.inc()
 
 

@@ -92,18 +92,21 @@ def _node_annotation(span, children_of, rendered_ids) -> str:
 
 
 def _stage_lines(span, children_of, indent: int) -> List[str]:
-    """The synthesized `<kind>:stages` children of one operator span, each
-    rendered as one busy-time breakdown line (stages overlap; their busy sum
-    over wall is the overlap ratio — see `StageTimings`)."""
+    """The `<kind>:stages` children of one operator span, each rendered as
+    one busy-time breakdown line from the summary the span carries (its
+    ``<stage>_s`` attributes: stages overlap; their busy sum over wall is
+    the overlap ratio — see `StageTimings`). A stages span that closed
+    without a summary (a fallback or a failure) renders no line."""
     out: List[str] = []
     pad = "  " * indent + "     "
     for child in children_of.get(span.span_id, ()):
-        if not child.name.endswith(":stages"):
+        if not child.name.endswith(":stages") or "stage_counts" not in child.attrs:
             continue
         kind = child.name.split(":", 1)[0]
-        stages = []
-        for st in children_of.get(child.span_id, ()):
-            stages.append(f"{st.name.split(':', 1)[1]}={_fmt_seconds(st.duration_s)}")
+        stages = [
+            f"{st}={_fmt_seconds(child.attrs.get(f'{st}_s'))}"
+            for st in child.attrs.get("stage_counts") or {}
+        ]
         wall = child.attrs.get("wall_s")
         overlap = child.attrs.get("overlap_ratio")
         extra = []

@@ -15,7 +15,9 @@ remain the machine-readable summary (device_time_s / utilization).
 `StageTimings` + `record_build_stages` are the HOST-side counterpart for the
 pipelined index build: per-stage busy time (decode/hash/h2d/sort/take/write),
 wall-clock, and the overlap ratio of each build, surfaced through bench.py's
-`bench_detail.build_stages` (see docs/build-pipeline.md)."""
+`bench_detail.build_stages` (see docs/build-pipeline.md). Its stage bracket
+is also the build's span tree: one ``<kind>:stages`` span over the
+operation's wall and one ``<kind>:<stage>`` span per bracket."""
 
 from __future__ import annotations
 
@@ -30,20 +32,38 @@ from . import tracing
 
 
 class StageTimings:
-    """Thread-safe per-stage wall-clock accumulator for a pipelined operation.
+    """Thread-safe per-stage wall-clock accumulator for a pipelined operation
+    of one `kind` (``build``, ``query`` or ``join``).
 
     Stages run CONCURRENTLY (that is the point of the pipeline), so per-stage
     sums measure busy time across workers, not a wall-clock partition:
     `overlap_ratio = sum(stage_s) / wall_s` > 1 means stages genuinely ran on
-    top of each other, ~1 means the pipeline degenerated to a serial chain."""
+    top of each other, ~1 means the pipeline degenerated to a serial chain.
 
-    def __init__(self, mode: str = ""):
+    While tracing records, construction opens the ``<kind>:stages`` span under
+    the ambient span; `summary()` closes it and hangs the summary on it, and
+    leaving the ``with`` block by an exception closes it with
+    ``status="error"``, and leaving it without a summary (the operation
+    returned before it ran, and its caller falls back to another path) with
+    ``status="fallback"``. Use it as a context manager around the
+    operation."""
+
+    def __init__(self, kind: str, mode: str = ""):
         self._lock = threading.Lock()
         self._stages: Dict[str, float] = {}
         self._counts: Dict[str, int] = {}
+        self.kind = kind
         self.mode = mode
         self._t0 = time.monotonic()
         self._wall: Optional[float] = None
+        self.span = tracing.start_span(f"{kind}:stages")
+
+    def __enter__(self) -> "StageTimings":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        # A no-op after summary(): the first end wins.
+        self.span.end(status="fallback", error=exc)
 
     def add(self, stage: str, seconds: float) -> None:
         with self._lock:
@@ -51,16 +71,20 @@ class StageTimings:
             self._counts[stage] = self._counts.get(stage, 0) + 1
 
     @contextlib.contextmanager
-    def timed(self, stage: str) -> Iterator[None]:
-        # Every pipelined stage bracket doubles as a stage-attribution scope
-        # (telemetry/stage_ledger.py): counters ticked inside bill this stage
-        # and the busy wall banks on the ambient QueryScope. One env read
-        # when HYPERSPACE_STAGE_ATTRIBUTION=0; StageTimings' own sums are
-        # untouched either way.
+    def timed(self, stage: str, **attrs) -> Iterator:
+        """THE stage bracket: the body's seconds add to the stage's busy sum,
+        it is a stage-attribution scope (telemetry/stage_ledger.py: counters
+        ticked inside bill this stage and the busy wall banks on the ambient
+        QueryScope), and it is one ``<kind>:<stage>`` span under the stages
+        span — on whichever thread runs it, hence the explicit parent. Yields
+        that span (the shared no-op span while tracing is off) so the site
+        can record the bracket's counts on it; `attrs` start it."""
         t0 = time.monotonic()
         try:
-            with stage_ledger.stage_scope(stage):
-                yield
+            with tracing.span(
+                f"{self.kind}:{stage}", parent=self.span, **attrs
+            ) as sp, stage_ledger.stage_scope(stage):
+                yield sp
         finally:
             self.add(stage, time.monotonic() - t0)
 
@@ -68,7 +92,9 @@ class StageTimings:
         if self._wall is None:
             self._wall = time.monotonic() - self._t0
 
-    def summary(self) -> dict:
+    def summary(self, **extra) -> dict:
+        """The operation's summary, plus the caller's `extra` counts; closes
+        the stages span, which carries the summary as its attributes."""
         self.finish()
         with self._lock:
             wall = self._wall or 0.0
@@ -78,6 +104,9 @@ class StageTimings:
             out["overlap_ratio"] = round(busy / wall, 3) if wall > 0 else None
             out["mode"] = self.mode
             out["stage_counts"] = dict(sorted(self._counts.items()))
+        out.update(extra)
+        self.span.add_attrs(**out)
+        self.span.end()
         return out
 
 
@@ -113,7 +142,6 @@ def record_build_stages(summary: dict) -> None:
     with _build_stages_lock:
         _BUILD_STAGES.append(d)
     _observe_stage_histograms("build", d)
-    tracing.record_stage_spans("build", d)
 
 
 def last_build_stages() -> Optional[dict]:
@@ -137,7 +165,6 @@ def record_query_stages(summary: dict) -> None:
     with _build_stages_lock:
         _QUERY_STAGES.append(d)
     _observe_stage_histograms("query", d)
-    tracing.record_stage_spans("query", d)
 
 
 def last_query_stages() -> Optional[dict]:
@@ -161,7 +188,6 @@ def record_join_stages(summary: dict) -> None:
     with _build_stages_lock:
         _JOIN_STAGES.append(d)
     _observe_stage_histograms("join", d)
-    tracing.record_stage_spans("join", d)
 
 
 def last_join_stages() -> Optional[dict]:

@@ -254,17 +254,17 @@ def close_stages(led) -> Optional[Dict[str, dict]]:
 
 ENV_TIMELINE_DIR = "HYPERSPACE_TIMELINE_DIR"
 
-#: Span-name prefixes whose spans are stage lanes: `record_*_stages`
-#: synthesizes ``<kind>:<stage>`` children under each ``<kind>:stages``
-#: summary span.
+#: Span-name prefixes whose spans are stage lanes: every
+#: `StageTimings.timed` bracket is one ``<kind>:<stage>`` span under its
+#: ``<kind>:stages`` span.
 _STAGE_KINDS = ("build", "query", "join")
 
 
 def _lane_of(span: dict) -> str:
     """Timeline lane for one exported span dict: the root query gets its own
-    lane, synthesized stage spans get one lane PER STAGE (the causal
-    timeline the issue asks for), operator spans share an ``ops`` lane, pool
-    worker spans a ``workers`` lane, everything else groups by name family."""
+    lane, stage spans get one lane PER STAGE (the causal timeline), operator
+    spans share an ``ops`` lane, pool worker spans a ``workers`` lane,
+    everything else groups by name family."""
     name = str(span.get("name", ""))
     if span.get("parent_id") is None:
         return "query"
@@ -287,28 +287,37 @@ def chrome_trace(spans: List[dict]) -> dict:
     query_id/span_id/parent_id/name/start_s/duration_s/status/attrs) into
     Chrome-trace JSON (``chrome://tracing`` / Perfetto's legacy importer):
     one complete-event (``ph:"X"``) per span, one lane (tid) per stage /
-    worker family / op class, thread-name metadata naming the lanes."""
+    worker family / op class, thread-name metadata naming the lanes. Spans
+    of one stage that overlap in time (eight files decoding at once) go to
+    numbered sub-lanes of their stage's lane (``stage:decode#2``, ...), so no
+    lane holds two events that partly overlap."""
     spans = [s for s in spans if isinstance(s, dict)]
-    starts = [
-        float(s["start_s"])
-        for s in spans
-        if isinstance(s.get("start_s"), (int, float))
-    ]
-    t0 = min(starts) if starts else 0.0
+    timed = sorted(
+        (s for s in spans if isinstance(s.get("start_s"), (int, float))),
+        key=lambda s: float(s["start_s"]),
+    )
+    t0 = float(timed[0]["start_s"]) if timed else 0.0
     lanes: Dict[str, int] = {}
     events: List[dict] = []
-    for s in spans:
-        start = s.get("start_s")
-        if not isinstance(start, (int, float)):
-            continue
+    stage_ends: Dict[str, List[float]] = {}  # stage lane -> each sub-lane's end
+    for s in timed:
+        start = float(s["start_s"])
         dur = s.get("duration_s")
         dur = float(dur) if isinstance(dur, (int, float)) else 0.0
         lane = _lane_of(s)
+        if lane.startswith("stage:"):
+            ends = stage_ends.setdefault(lane, [])
+            free = next((i for i, e in enumerate(ends) if e <= start), len(ends))
+            if free == len(ends):
+                ends.append(0.0)
+            ends[free] = start + max(0.0, dur)
+            if free:
+                lane = f"{lane}#{free + 1}"
         tid = lanes.setdefault(lane, len(lanes) + 1)
         ev = {
             "name": str(s.get("name", "?")),
             "ph": "X",
-            "ts": round((float(start) - t0) * 1e6, 1),
+            "ts": round((start - t0) * 1e6, 1),
             "dur": round(max(0.0, dur) * 1e6, 1),
             "pid": 1,
             "tid": tid,

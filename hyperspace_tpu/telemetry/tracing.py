@@ -1,13 +1,12 @@
 """Unified query-lifecycle tracing: a hierarchical span tree per query.
 
-PRs 1–3 bolted three parallel ad-hoc stage recorders onto the engine
-(`record_build_stages` / `record_query_stages` / `record_join_stages`) that
-bench.py scrapes but no query can correlate end-to-end. This module is the
-correlation layer: every user-facing action (`DataFrame.collect/count`,
-`create_index`, `explain(analyze=True)`) opens a ROOT span carrying a stable
-`query_id`; physical operators, the planner, the optimizer rules, and the
-stage summaries of the pipelined executors attach child spans under it — one
-tree answering "where did this query's time go and which caches/rules fired".
+Every user-facing action (`DataFrame.collect/count`, `create_index`,
+`delete_index`, `vacuum_index`, `explain(analyze=True)`) opens a ROOT span
+carrying a stable `query_id`; physical operators, the planner, the optimizer
+rules, the action's operation-log writes, and every stage bracket of the
+pipelined executors (`profiling.StageTimings.timed`: one span per file
+decoded, per bucket written, ...) attach child spans under it — one tree
+answering "where did this query's time go and which caches/rules fired".
 
 Design rules:
 
@@ -25,10 +24,13 @@ Design rules:
   the stage-summary histories); a long-lived session can never grow
   telemetry with query count. Per-trace span count is capped so a runaway
   loop inside one traced query cannot hold unbounded memory either.
-- **Device correlation.** While recording, spans opened via `span()` also
-  enter a `jax.profiler.TraceAnnotation` (only when jax is already imported
-  — tracing must never pay the import), so host spans line up with device
-  timelines in an xprof trace taken with `profiling.trace`.
+- **Device correlation.** While recording, every span also enters a
+  `jax.profiler.TraceAnnotation` on the thread that opened it (only when jax
+  is already imported — tracing must never pay the import), so a
+  `jax.profiler` trace running at the time holds each span in its host plane
+  beside the device timeline. `start_s` is read from the clock the profiler
+  stamps host events with (`time.time_ns()`, the wall clock); durations come
+  from the monotonic clock.
 
 JSONL export (``HYPERSPACE_TRACE_FILE``): one line per span, written when the
 root span ends — `{"query_id", "span_id", "parent_id", "name", "start_s",
@@ -130,20 +132,26 @@ class Span:
         "attrs",
         "_lock",
         "_registered",
+        "_ann",
     )
+
+    #: Whether this span records (the shared no-op span says False): lets a
+    #: site skip computing an attribute nobody will read.
+    recording = True
 
     def __init__(self, trace: QueryTrace, name: str, parent_id: Optional[int], attrs=None):
         self.trace = trace
         self.name = name
         self.span_id = -1
         self.parent_id = parent_id
-        self.start_s = time.time()
+        self.start_s = time.time_ns() / 1e9
         self._t0 = time.monotonic()
         self.duration_s: Optional[float] = None
         self.status = "ok"
         self.attrs: dict = dict(attrs) if attrs else {}
         self._lock = threading.Lock()
         self._registered = trace._register(self)
+        self._ann = _annotation(name)
 
     @property
     def query_id(self) -> str:
@@ -181,6 +189,12 @@ class Span:
                 self.attrs["error"] = f"{type(error).__name__}: {error}"
             elif status is not None:
                 self.status = status
+            ann, self._ann = self._ann, None
+        if ann is not None:
+            try:
+                ann.__exit__(None, None, None)
+            except Exception:
+                pass
 
     def to_json(self) -> dict:
         # Attrs snapshot under the span lock: a still-running worker span
@@ -213,6 +227,7 @@ class _NoopSpan:
     duration_s = 0.0
     status = "ok"
     attrs: dict = {}
+    recording = False
 
     def set_attr(self, key, value):
         pass
@@ -330,7 +345,6 @@ def query_span(name: str, **attrs) -> Iterator:
     if tenant is not None:
         root.set_attr("tenant", tenant)
     token = _current_span.set(root)
-    ann = _annotation(name)
     led = _accounting.ledger_scope(trace.query_id, name, root=root)
     led.__enter__()
     try:
@@ -347,21 +361,17 @@ def query_span(name: str, **attrs) -> Iterator:
             led.__exit__(None, None, None)
         except Exception:
             pass
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
         _current_span.reset(token)
         _finalize(trace)
 
 
-@contextlib.contextmanager
-def span(name: str, parent=None, **attrs) -> Iterator:
-    """Child span under `parent` (default: the ambient span). Without an
-    ambient root (or with tracing off) it is a no-op — stray spans outside a
-    query never allocate a trace. Exceptions close the span with
-    ``status="error"`` and propagate.
+def start_span(name: str, parent=None, **attrs):
+    """Open a child span under `parent` (default: the ambient span) that the
+    caller ends itself (`Span.end`), for a scope that is not one lexical
+    block — e.g. a `StageTimings` that opens its ``<kind>:stages`` span at
+    construction and closes it when the summary is taken. The span does NOT
+    become the ambient span. Without an ambient root (or with tracing off)
+    it returns the shared no-op span.
 
     An EXPLICIT real parent records regardless of this thread's `active()`
     view: pool workers run in a fresh contextvars context, so the submitting
@@ -369,15 +379,23 @@ def span(name: str, parent=None, **attrs) -> Iterator:
     worker's span would silently no-op (found by the pool hammer test)."""
     if parent is None:
         if not active():
-            yield NOOP_SPAN
-            return
+            return NOOP_SPAN
         parent = _current_span.get()
     if parent is None or isinstance(parent, _NoopSpan):
-        yield NOOP_SPAN
+        return NOOP_SPAN
+    return Span(parent.trace, name, parent.span_id, attrs)
+
+
+@contextlib.contextmanager
+def span(name: str, parent=None, **attrs) -> Iterator:
+    """Child span under `parent` (default: the ambient span), ambient for the
+    body (`start_span` decides whether it records). Exceptions close the span
+    with ``status="error"`` and propagate."""
+    sp = start_span(name, parent, **attrs)
+    if sp is NOOP_SPAN:
+        yield sp
         return
-    sp = Span(parent.trace, name, parent.span_id, attrs)
     token = _current_span.set(sp)
-    ann = _annotation(name)
     try:
         yield sp
         sp.end()
@@ -385,64 +403,7 @@ def span(name: str, parent=None, **attrs) -> Iterator:
         sp.end(error=e)
         raise
     finally:
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
         _current_span.reset(token)
-
-
-def record_stage_spans(kind: str, summary: dict, parent=None) -> None:
-    """Adapt one `StageTimings` summary into child spans of the ambient span:
-    per stage a span named ``<kind>:<stage>`` whose duration is that stage's
-    BUSY seconds (stages overlap — they are not a wall-clock partition, which
-    is why `overlap_ratio` rides the summary span), plus one ``<kind>:stages``
-    span carrying the whole summary verbatim. This is the bridge that keeps
-    `bench_detail.*_stages` and the span tree telling the same story: the
-    recorders in `telemetry.profiling` call it on every summary they keep."""
-    if parent is None:
-        if not active():
-            return
-        parent = _current_span.get()
-    if parent is None or isinstance(parent, _NoopSpan):
-        return
-    meta = Span(parent.trace, f"{kind}:stages", parent.span_id)
-    # These spans are SYNTHESIZED at summary-record time (the operation's
-    # end): back-date them by the recorded wall so a timeline viewer places
-    # them inside the operation, not after the root ended. Stage durations
-    # are BUSY seconds summed across workers — concurrent by design, so they
-    # all start at the operation start and legitimately overlap.
-    wall = summary.get("wall_s")
-    wall = float(wall) if isinstance(wall, (int, float)) else 0.0
-    meta.start_s -= wall
-    meta.duration_s = wall
-    meta.set_attr("synthesized", True)
-    counts = summary.get("stage_counts") or {}
-    for key, val in summary.items():
-        if not key.endswith("_s") or key == "wall_s" or not isinstance(val, (int, float)):
-            continue
-        stage = key[:-2]
-        sp = Span(meta.trace, f"{kind}:{stage}", meta.span_id)
-        sp.start_s = meta.start_s
-        sp.set_attr("busy_s", float(val))
-        cnt = counts.get(stage)
-        if cnt is not None:
-            sp.set_attr("count", cnt)
-        sp.duration_s = max(0.0, float(val))
-        sp.status = "ok"
-    try:
-        meta.attrs.update({k: v for k, v in summary.items() if _json_safe(v)})
-    except Exception:
-        pass
-
-
-def _json_safe(v) -> bool:
-    try:
-        json.dumps(v)
-        return True
-    except (TypeError, ValueError):
-        return False
 
 
 def recent_traces() -> List[QueryTrace]:

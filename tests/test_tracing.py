@@ -375,3 +375,207 @@ def test_traced_query_equals_untraced(session, tmp_path, monkeypatch):
     monkeypatch.setenv(tracing.ENV_TRACE_FILE, os.path.join(str(tmp_path), "t.jsonl"))
     traced = sorted(map(tuple, q().collect().rows()))
     assert traced == plain
+
+
+# ---------------------------------------------------------------------------
+# Real stage spans: every StageTimings bracket of the index build is a span
+# ---------------------------------------------------------------------------
+
+N_SRC_FILES, SRC_ROWS = 4, 4000
+
+
+def _write_build_source(session, tmp_path):
+    from hyperspace_tpu.engine import io as engine_io
+    from hyperspace_tpu.engine.table import Table
+
+    path = os.path.join(str(tmp_path), "src")
+    rng = np.random.RandomState(11)
+    per = SRC_ROWS // N_SRC_FILES
+    for i in range(N_SRC_FILES):
+        engine_io.write_parquet(
+            Table.from_pydict(
+                {
+                    "k": rng.randint(0, 500, per).astype(np.int64),
+                    "v": rng.randint(0, 100, per).astype(np.int64),
+                    "f": rng.rand(per),
+                }
+            ),
+            os.path.join(path, f"part-{i:05d}.parquet"),
+        )
+    return path
+
+
+def _pipelined_build(session, tmp_path, monkeypatch, name="idx"):
+    """A pipelined build (2 decode threads, 3 writers) of a 4-file source."""
+    monkeypatch.setenv("HYPERSPACE_BUILD_DECODE_THREADS", "2")
+    monkeypatch.setenv("HYPERSPACE_BUILD_WRITERS", "3")
+    src = _write_build_source(session, tmp_path)
+    Hyperspace(session).create_index(
+        session.read.parquet(src), IndexConfig(name, ["k"], ["v", "f"])
+    )
+
+
+@pytest.mark.parametrize("device_ops, staging", [("0", "hash"), ("1", "h2d")])
+def test_pipelined_build_records_real_stage_spans(
+    session, tmp_path, monkeypatch, device_ops, staging
+):
+    monkeypatch.setenv("HYPERSPACE_FORCE_DEVICE_OPS", device_ops)
+    with tracing.capture() as cap:
+        _pipelined_build(session, tmp_path, monkeypatch)
+    trace = cap.trace
+    (stages,) = trace.find("build:stages")
+    children = trace.spans_by_parent()[stages.span_id]
+    by_stage = {}
+    for sp in children:
+        by_stage.setdefault(sp.name.split(":", 1)[1], []).append(sp)
+
+    files = [
+        f
+        for _root, _dirs, fs in os.walk(os.path.join(str(tmp_path), "indexes", "idx"))
+        for f in fs
+        if f.endswith(".parquet")
+    ]
+    assert sorted(sp.attrs["file"] for sp in by_stage["decode"]) == list(
+        range(N_SRC_FILES)
+    )
+    assert len(by_stage[staging]) == N_SRC_FILES  # one chunk per file
+    assert len(by_stage["sort"]) == 1 and by_stage["sort"][0].attrs["rows"] == SRC_ROWS
+    assert [sp.attrs["rows"] for sp in by_stage["take"]] == [SRC_ROWS] * 3  # k, v, f
+    writes = by_stage["write"]
+    assert len(writes) == len(files) > 1  # one per non-empty bucket
+    assert len({sp.attrs["bucket"] for sp in writes}) == len(writes)
+    assert sum(sp.attrs["rows"] for sp in writes) == SRC_ROWS
+    assert sum(sp.attrs["rows"] for sp in by_stage["decode"]) == SRC_ROWS
+    assert all(sp.attrs["bytes"] > 0 for sp in writes + by_stage["decode"])
+    if staging == "h2d":
+        assert all(sp.attrs["bytes"] > 0 for sp in by_stage["h2d"])
+
+    # Real spans: each starts and ends inside the pipeline's wall, under the
+    # build's query_id, and they time exactly the brackets the summary sums.
+    end = stages.start_s + stages.duration_s
+    for sp in children:
+        assert sp.query_id == stages.query_id == trace.query_id
+        assert sp.status == "ok" and sp.duration_s is not None
+        assert stages.start_s <= sp.start_s
+        assert sp.start_s + sp.duration_s <= end + 1e-3
+    assert set(by_stage) == set(stages.attrs["stage_counts"])
+    for stage, spans in by_stage.items():
+        assert stages.attrs["stage_counts"][stage] == len(spans)
+        busy = sum(sp.duration_s for sp in spans)
+        assert busy == pytest.approx(stages.attrs[f"{stage}_s"], abs=2e-3), stage
+    assert stages.attrs["rows"] == SRC_ROWS
+    assert "synthesized" not in stages.attrs
+
+
+def test_build_allocates_no_span_with_tracing_off(session, tmp_path, monkeypatch):
+    for env in (tracing.ENV_TRACE_FILE, tracing.ENV_TRACING, tracing.ENV_TIMELINE_DIR):
+        monkeypatch.delenv(env, raising=False)
+    made = []
+    real_init = tracing.Span.__init__
+
+    def counting_init(self, trace, name, *a, **k):
+        made.append(name)
+        real_init(self, trace, name, *a, **k)
+
+    monkeypatch.setattr(tracing.Span, "__init__", counting_init)
+    _pipelined_build(session, tmp_path, monkeypatch, name="off")
+    assert made == []
+    # The count sees spans when a sink is attached (the control).
+    with tracing.capture():
+        Hyperspace(session).create_index(
+            session.read.parquet(os.path.join(str(tmp_path), "src")),
+            IndexConfig("on", ["k"], ["v"]),
+        )
+    assert "build:stages" in made and "build:write" in made
+
+
+def test_failing_build_closes_stage_spans_with_error(session, tmp_path, monkeypatch):
+    from hyperspace_tpu.index.build_pipeline import _BucketWriter
+
+    def boom(self, b, lo, hi):
+        raise RuntimeError("writer down")
+
+    monkeypatch.setattr(_BucketWriter, "write_bucket", boom)
+    with tracing.capture() as cap:
+        with pytest.raises(RuntimeError, match="writer down"):
+            _pipelined_build(session, tmp_path, monkeypatch)
+    (stages,) = cap.trace.find("build:stages")
+    assert stages.status == "error" and "writer down" in stages.attrs["error"]
+    assert any(sp.status == "error" for sp in cap.trace.find("build:write"))
+
+
+@pytest.mark.parametrize("outcome", ["summary", "fallback", "error"])
+def test_stage_timings_closes_its_stages_span(outcome):
+    """The stages span ends with the summary, with ``status="fallback"`` when
+    the operation returns before it ran, or with the error; only a summary
+    renders a stage line in explain(analyze=True)."""
+    from hyperspace_tpu.plananalysis.analyze import _stage_lines
+    from hyperspace_tpu.telemetry.profiling import StageTimings
+
+    with tracing.capture() as cap:
+        with tracing.query_span("query:collect"):
+            try:
+                with StageTimings("query", "stream-cpu") as stages:
+                    with stages.timed("decode", rows=3):
+                        pass
+                    if outcome == "summary":
+                        stages.summary(rows=3)
+                    elif outcome == "error":
+                        raise ValueError("decode failed")
+            except ValueError:
+                pass
+    trace = cap.trace
+    (sp,) = trace.find("query:stages")
+    (decode,) = trace.find("query:decode")
+    assert decode.parent_id == sp.span_id and decode.attrs["rows"] == 3
+    assert sp.duration_s is not None
+    assert sp.status == {"summary": "ok"}.get(outcome, outcome)
+    lines = _stage_lines(trace.root, trace.spans_by_parent(), 0)
+    if outcome == "summary":
+        assert sp.attrs["rows"] == 3 and sp.attrs["stage_counts"] == {"decode": 1}
+        assert len(lines) == 1 and "query stages: decode=" in lines[0]
+    else:
+        assert "stage_counts" not in sp.attrs and lines == []
+
+
+@pytest.mark.parametrize(
+    "action, inner",
+    [
+        ("create", ("build:stages", "build:commit")),
+        ("delete", ()),
+        ("vacuum", ()),
+    ],
+)
+def test_lifecycle_actions_record_spans(session, tmp_path, monkeypatch, action, inner):
+    """Each lifecycle call is one root span whose action writes the operation
+    log under `log:begin`/`log:commit` around the body's `action:op`."""
+    hs = Hyperspace(session)
+    if action != "create":
+        _pipelined_build(session, tmp_path, monkeypatch)
+    if action == "vacuum":
+        hs.delete_index("idx")
+    with tracing.capture() as cap:
+        if action == "create":
+            _pipelined_build(session, tmp_path, monkeypatch)
+        else:
+            getattr(hs, f"{action}_index")("idx")
+    trace = cap.trace
+    assert trace.root.name == f"build:{action}_index"
+    assert trace.root.attrs["index_name"] == "idx"
+    top = [s.name for s in trace.spans_by_parent()[trace.root.span_id]]
+    assert top == ["log:begin", "action:op", "log:commit"]
+    (op,) = trace.find("action:op")
+    for name in inner:
+        (sp,) = trace.find(name)
+        assert op.start_s <= sp.start_s <= op.start_s + op.duration_s
+
+
+def test_explain_analyze_stage_line_reads_summary(session, tmp_path, monkeypatch):
+    monkeypatch.setenv("HYPERSPACE_FORCE_DEVICE_OPS", "0")
+    monkeypatch.setenv("HYPERSPACE_QUERY_STREAMING", "1")
+    q = _indexed_join_agg(session, tmp_path)
+    txt = q().explain(analyze=True)
+    line = next(ln for ln in txt.splitlines() if "join stages:" in ln)
+    for stage in ("probe", "verify", "gather"):
+        assert f" {stage}=" in line and f" {stage}=?" not in line, line
+    assert "wall=" in line and "overlap=" in line

@@ -4,10 +4,11 @@
 The JSONL span export (``HYPERSPACE_TRACE_FILE``) records every span of
 every traced query — one JSON object per span, whole traces appended
 atomically. This tool joins one query's spans back into a causal timeline:
-one lane per stage (the synthesized ``<kind>:<stage>`` spans), one lane for
-operator spans, one for pool-worker families — the `stage_ledger.
-chrome_trace` conversion, loadable in ``chrome://tracing`` or Perfetto's
-legacy importer.
+one lane per stage (the ``<kind>:<stage>`` span of every stage bracket,
+with numbered sub-lanes where a stage's spans ran at once on several
+threads), one lane for operator spans, one for pool-worker families — the
+`stage_ledger.chrome_trace` conversion, loadable in ``chrome://tracing`` or
+Perfetto's legacy importer.
 
 Usage:
     python tools/hstimeline.py TRACE_FILE [--query-id ID] [--list]
