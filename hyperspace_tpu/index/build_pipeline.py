@@ -24,17 +24,20 @@ training input pipeline:
    composite sort for small builds (`pallas_composite_build_sort`). On the
    CPU backend the permutation comes from the exact same
    `ops.partition.host_sort_perm` the serial path uses.
-4. **Writer pool** (``HYPERSPACE_BUILD_WRITERS``): per-bucket files gather
-   their rows straight from the decoded chunks via ``perm[lo:hi]`` (no
-   materialized full-table copy) and encode in parallel, overlapped with each
-   other's gathers.
+4. **Writer pool** (``HYPERSPACE_BUILD_WRITERS``): while the sort runs, each
+   output column is assembled into one arrow array in Arrow's memory pool
+   (the columns concurrently). The sorted order is then split at bucket
+   starts into up to one row range per writer; each range gathers every
+   column through its slice of ``perm``, and its bucket files encode from
+   zero-copy slices of that range's arrays as soon as its gather lands.
 
 **Determinism contract**: the pipelined build produces BYTE-IDENTICAL index
 files to the serial path, for any thread counts. The global row order is
 fixed by the same (file order, chunk concat order) the serial concat uses;
 bucket hashing is elementwise; the sort permutation comes from the identical
 sort implementation over identical arrays; and bucket rows gathered through
-``perm[lo:hi]`` equal ``sorted_table[lo:hi]`` by construction.
+``perm[lo:hi]`` equal ``sorted_table[lo:hi]`` by construction, whichever
+gather range holds them.
 ``HYPERSPACE_BUILD_DECODE_THREADS=1`` bypasses this module entirely and runs
 the pre-pipeline serial code path (`tests/test_build_pipeline.py` pins the
 two to each other).
@@ -52,9 +55,10 @@ Every stage runs inside `StageTimings.timed`, the one bracket behind the
 busy-time sums, the stage ledger and the spans: under ``build:stages`` (the
 pipeline's wall) each file decoded is a ``build:decode`` span, each chunk's
 key staging ``build:h2d`` (``build:hash`` on the CPU), then ``build:sort``,
-the writer's ``build:concat``, one ``build:take`` per column and one
+the writer's ``build:concat``, one ``build:take`` per gather range and one
 ``build:write`` per non-empty bucket — each on the thread that did the work,
-with its ``rows``/``bytes``/``file``/``bucket`` counts as attributes. The
+with its ``rows``/``bytes``/``file``/``bucket``/``buckets``/``columns``
+counts as attributes. The
 summary (busy seconds per stage, wall, overlap ratio) is recorded via
 `telemetry.profiling.record_build_stages` and surfaced in `bench.py`'s
 ``bench_detail``.
@@ -65,7 +69,12 @@ from __future__ import annotations
 import os
 import queue
 import threading
-from concurrent.futures import FIRST_EXCEPTION, ThreadPoolExecutor, wait
+from concurrent.futures import (
+    FIRST_EXCEPTION,
+    ThreadPoolExecutor,
+    as_completed,
+    wait,
+)
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -269,23 +278,50 @@ def _sort_pipeline(
     return perm, bucket_starts(sorted_b, num_buckets)
 
 
+def _gather_ranges(starts: np.ndarray, parts: int) -> List[Tuple[int, int]]:
+    """Split the sorted order into up to `parts` gather ranges of about equal
+    rows, cut only at bucket starts: [(b0, b1)] bucket ranges, contiguous,
+    each holding rows ``[starts[b0], starts[b1])`` (at least one), together
+    covering every row. A bucket is never split, so a build with one huge
+    bucket, or fewer non-empty buckets than `parts`, gets fewer ranges, and
+    a build of no rows none."""
+    n = int(starts[-1])
+    cuts = [0]
+    for i in range(1, parts):
+        # The first bucket that starts at or past the i-th share of the rows.
+        b = int(np.searchsorted(starts, i * n / parts))
+        if starts[cuts[-1]] < starts[b] < n:
+            cuts.append(b)
+    cuts.append(len(starts) - 1)
+    return [(b0, b1) for b0, b1 in zip(cuts, cuts[1:]) if starts[b1] > starts[b0]]
+
+
 class _BucketWriter:
-    """Writer-pool stage: per-bucket gather + parquet encode, GIL-free.
+    """Writer-pool stage: gather into sorted order + parquet encode, GIL-free.
 
     `prepare()` assembles ONE arrow array per output column over the chunk
     concatenation — decoded values + null mask, exactly what the serial path's
-    `table_to_arrow` feeds the writer. Under encoded execution
-    (``HYPERSPACE_ENCODED_EXEC``), string columns stay CODES end to end
-    instead: the chunk columns re-encode over their union dictionary (the
-    exact `Table.concat` implementation the serial path runs), the gather
-    moves int32 codes, and `write_bucket` emits a compacted
-    `pa.DictionaryArray` through the SAME `encoding.dictionary_arrow_array`
-    helper the serial `table_to_arrow` uses — so serial == pipelined stays
-    byte-identical in both flag states, and the N decoded strings never
-    materialize. `write_bucket` gathers `perm[lo:hi]` with
-    `pyarrow.compute.take` and encodes — both C++ paths that release the GIL,
-    so the writer pool runs bucket gathers and encodes truly in parallel
-    (the earlier numpy per-bucket gather serialized the pool on the GIL).
+    `table_to_arrow` feeds the writer. A numeric column of several chunks is
+    each chunk wrapped without a copy and joined by `pa.concat_arrays` into
+    Arrow's memory pool (which releases the GIL and keeps its pages from
+    build to build); one chunk is wrapped as it is. The columns assemble
+    concurrently. Under encoded execution (``HYPERSPACE_ENCODED_EXEC``),
+    string columns stay CODES end to end instead: the chunk columns re-encode
+    over their union dictionary (the exact `Table.concat` implementation the
+    serial path runs), the gather moves int32 codes, and `write_bucket` emits
+    a compacted `pa.DictionaryArray` through the SAME
+    `encoding.dictionary_arrow_array` helper the serial `table_to_arrow`
+    uses — so serial == pipelined stays byte-identical in both flag states,
+    and the N decoded strings never materialize.
+
+    `run()` splits the sorted order at bucket starts into up to one gather
+    range per writer (`_gather_ranges`). Each range takes every column
+    through its slice of `perm` with `pyarrow.compute.take`, and as soon as
+    it lands, each of its buckets is handed to the pool: `write_bucket`
+    encodes a zero-copy slice of the range's arrays — the same values as a
+    slice of a whole-column take, so the parquet bytes are too. Take and
+    encode are C++ paths that release the GIL, so the pool runs gathers and
+    encodes truly in parallel, with no barrier between them.
 
     `prepare()` is designed to run on its own thread OVERLAPPED with the sort
     stage: the sort only touches the key columns, the writers need them all."""
@@ -297,61 +333,78 @@ class _BucketWriter:
         self.stages = stages
         self.arrays: Dict[str, "object"] = {}
         self.dicts: Dict[str, np.ndarray] = {}  # union dict of encoded string cols
+        # bucket -> (first row of its gather range, the range's arrays)
+        self.ranges: Dict[int, Tuple[int, Dict[str, "object"]]] = {}
 
-    def prepare(self) -> None:
-        import pyarrow as pa
-
+    def prepare(self, pool_size: int) -> None:
         from ..engine import encoding as _encoding
 
         encode = _encoding.encoded_exec_enabled()
-        with self.stages.timed("concat"):
-            for name in self.names:
-                cols = [t.column(name) for t in self.chunks]
-                if any(c.validity is not None for c in cols):
-                    if len(cols) == 1:
-                        validity = cols[0].validity
-                    else:
-                        validity = np.concatenate(
-                            [
-                                c.validity
-                                if c.validity is not None
-                                else np.ones(len(c), dtype=bool)
-                                for c in cols
-                            ]
-                        )
-                    mask = ~validity
-                else:
-                    mask = None
-                if cols[0].is_string and encode:
-                    # Encoded path: ONE union re-encode over the chunk
-                    # dictionaries (`Table.concat` — the serial concat's own
-                    # implementation, so codes and dictionary are bit-equal
-                    # to the serial path's) — the gather below then moves
-                    # int32 codes, never decoded strings.
-                    merged = Table.concat([Table({name: c}) for c in cols])
-                    mc = merged.column(name)
-                    self.dicts[name] = mc.dictionary
-                    self.arrays[name] = pa.array(mc.data, mask=mask)
-                elif cols[0].is_string:
-                    # Decoded fallback: decode per chunk through its own
-                    # dictionary — value-identical to the serial union-
-                    # dictionary decode.
-                    values = np.concatenate([c.dictionary[c.data] for c in cols])
-                    self.arrays[name] = pa.array(values, mask=mask)
-                elif len(cols) == 1:
-                    self.arrays[name] = pa.array(cols[0].data, mask=mask)
-                else:
-                    self.arrays[name] = pa.array(
-                        np.concatenate([c.data for c in cols]), mask=mask
-                    )
+        with self.stages.timed("concat") as sp:
+            with ThreadPoolExecutor(max_workers=min(pool_size, len(self.names))) as pool:
+                built = list(pool.map(lambda n: self._assemble(n, encode), self.names))
+            copied = 0
+            for name, (arr, dictionary, nbytes) in zip(self.names, built):
+                self.arrays[name] = arr
+                if dictionary is not None:
+                    self.dicts[name] = dictionary
+                copied += nbytes
+            if sp.recording:
+                sp.set_attr("bytes", copied)
 
-    def _bucket_array(self, n: str, lo: int, hi: int):
-        """One column's arrow array for rows [lo, hi): a zero-copy slice, or
-        — for encoded string columns — the compacted dictionary array built
-        from the sliced codes (the shared write-side primitive)."""
+    def _assemble(self, name: str, encode: bool):
+        """(arrow array, union dictionary | None, bytes copied) of one output
+        column over the chunk concatenation."""
+        import pyarrow as pa
+
+        cols = [t.column(name) for t in self.chunks]
+        if cols[0].is_string:
+            mask = None
+            if any(c.validity is not None for c in cols):
+                mask = ~np.concatenate(
+                    [
+                        c.validity
+                        if c.validity is not None
+                        else np.ones(len(c), dtype=bool)
+                        for c in cols
+                    ]
+                )
+            if encode:
+                # Encoded path: ONE union re-encode over the chunk
+                # dictionaries (`Table.concat` — the serial concat's own
+                # implementation, so codes and dictionary are bit-equal to
+                # the serial path's) — the gather then moves int32 codes,
+                # never decoded strings.
+                mc = Table.concat([Table({name: c}) for c in cols]).column(name)
+                arr = pa.array(mc.data, mask=mask)
+                return arr, mc.dictionary, arr.nbytes if len(cols) > 1 else 0
+            # Decoded fallback: decode per chunk through its own dictionary —
+            # value-identical to the serial union-dictionary decode.
+            values = np.concatenate([c.dictionary[c.data] for c in cols])
+            arr = pa.array(values, mask=mask)
+            return arr, None, arr.nbytes
+        # Numeric: each chunk wrapped without a copy (values, and its own null
+        # mask), promoted as `np.concatenate` would promote mixed dtypes.
+        dtype = np.result_type(*(c.data.dtype for c in cols))
+        parts = [
+            pa.array(
+                c.data.astype(dtype, copy=False),
+                mask=None if c.validity is None else ~c.validity,
+            )
+            for c in cols
+        ]
+        if len(parts) == 1:
+            return parts[0], None, 0
+        arr = pa.concat_arrays(parts)
+        return arr, None, arr.nbytes
+
+    def _bucket_array(self, n: str, sl):
+        """One column's arrow array for a bucket, from its slice `sl` of the
+        gathered range: the slice itself, or — for encoded string columns —
+        the compacted dictionary array built from the sliced codes (the
+        shared write-side primitive)."""
         from ..engine import encoding as _encoding
 
-        sl = self.gathered[n].slice(lo, hi - lo)
         if n not in self.dicts:
             return sl
         if sl.null_count:
@@ -363,14 +416,20 @@ class _BucketWriter:
         return _encoding.dictionary_arrow_array(codes, self.dicts[n], mask)
 
     def write_bucket(self, b: int, lo: int, hi: int) -> Optional[str]:
-        """Write bucket `b` (rows [lo, hi) of the sorted order); returns the
-        file's path, None for an empty bucket."""
+        """Write bucket `b` (rows [lo, hi) of the sorted order, inside one
+        gathered range); returns the file's path, None for an empty bucket."""
         if hi <= lo:
             return None  # empty bucket: no file (same contract as the serial path)
         import pyarrow as pa
 
+        row0, arrays = self.ranges[b]
         path = os.path.join(self.index_data_path, f"part-{b:05d}.parquet")
-        out = pa.table({n: self._bucket_array(n, lo, hi) for n in self.names})
+        out = pa.table(
+            {
+                n: self._bucket_array(n, arrays[n].slice(lo - row0, hi - lo))
+                for n in self.names
+            }
+        )
         # Bounded row groups over the key-sorted bucket rows: the footer zone
         # maps then resolve point/range filters INSIDE the bucket file (scan
         # pushdown). Same bound as the serial writer — the byte-identity
@@ -383,38 +442,46 @@ class _BucketWriter:
 
     def run(self, perm: np.ndarray, starts: np.ndarray, pool_size: int) -> None:
         import pyarrow as pa
-        import pyarrow.compute as pc
 
-        num_buckets = len(starts) - 1
+        idx = pa.array(perm)
         with ThreadPoolExecutor(max_workers=pool_size) as pool:
-            # One full gather per column (column-parallel, C++ GIL-free),
-            # then per-bucket ZERO-COPY slices feed the encoders — strictly
-            # less gather work than per-bucket takes (no re-walk of the
-            # bucket index lists) and both stages spread over the pool.
-            idx = pa.array(perm)
-            futs = {
-                n: pool.submit(self._timed_take, pc, self.arrays[n], idx)
-                for n in self.names
+            gathers = {
+                pool.submit(self._gather, idx, starts, b0, b1): (b0, b1)
+                for b0, b1 in _gather_ranges(starts, pool_size)
             }
-            self.gathered = {}
-            for n, f in futs.items():
-                self.gathered[n] = f.result()
-                # Release the pre-gather copy as soon as its take resolves:
-                # keeps peak memory at ~one extra full-table copy, like the
-                # serial path's sorted_table.
-                self.arrays.pop(n, None)
-            bfuts = [
-                pool.submit(self._timed_bucket, b, int(starts[b]), int(starts[b + 1]))
-                for b in range(num_buckets)
-                if starts[b + 1] > starts[b]  # an empty bucket has no file
-            ]
-            done, _ = wait(bfuts, return_when=FIRST_EXCEPTION)
+            writes = []
+            for f in as_completed(gathers):
+                b0, b1 = gathers[f]
+                row0, arrays = int(starts[b0]), f.result()
+                for b in range(b0, b1):
+                    if starts[b + 1] > starts[b]:  # an empty bucket has no file
+                        self.ranges[b] = (row0, arrays)
+                        writes.append(
+                            pool.submit(
+                                self._timed_bucket, b, int(starts[b]), int(starts[b + 1])
+                            )
+                        )
+            # Every range is gathered: release the pre-gather copy, so peak
+            # memory stays at ~one extra full-table copy, like the serial
+            # path's sorted_table.
+            self.arrays.clear()
+            done, _ = wait(writes, return_when=FIRST_EXCEPTION)
             for f in done:
                 f.result()  # re-raise the first worker failure
 
-    def _timed_take(self, pc, arr, idx):
-        with self.stages.timed("take", rows=len(idx)):
-            return pc.take(arr, idx)
+    def _gather(self, idx, starts: np.ndarray, b0: int, b1: int) -> Dict[str, "object"]:
+        """Every column's rows [starts[b0], starts[b1]) of the sorted order."""
+        import pyarrow.compute as pc
+
+        lo, hi = int(starts[b0]), int(starts[b1])
+        with self.stages.timed(
+            "take",
+            rows=hi - lo,
+            buckets=int(np.count_nonzero(np.diff(starts[b0 : b1 + 1]))),
+            columns=len(self.names),
+        ):
+            rows = idx.slice(lo, hi - lo)
+            return {n: pc.take(self.arrays[n], rows) for n in self.names}
 
     def _timed_bucket(self, b: int, lo: int, hi: int) -> None:
         with self.stages.timed("write", bucket=b, rows=hi - lo) as sp:
@@ -710,7 +777,7 @@ def _sort_write_summarize(
 
     def prep_guarded() -> None:
         try:
-            writer.prepare()
+            writer.prepare(cfg.writers)
         except BaseException as e:
             prep_err.append(e)
 

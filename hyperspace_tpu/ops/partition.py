@@ -101,8 +101,12 @@ def host_sort_perm(b_host: np.ndarray, cols, num_buckets: int) -> np.ndarray:
 
 
 def bucket_starts(sorted_b_host: np.ndarray, num_buckets: int) -> np.ndarray:
-    """Bucket start offsets (length num_buckets+1) of a bucket-sorted id array."""
-    return np.searchsorted(sorted_b_host, np.arange(num_buckets + 1))
+    """Bucket start offsets (length num_buckets+1) of a bucket-sorted id array.
+    The needles take the ids' dtype: mixed dtypes would cast the whole id
+    array into a fresh buffer first (48 MB at 6 M int32 ids)."""
+    return np.searchsorted(
+        sorted_b_host, np.arange(num_buckets + 1, dtype=sorted_b_host.dtype)
+    )
 
 
 def bucketize_table(

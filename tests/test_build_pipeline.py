@@ -26,7 +26,9 @@ from hyperspace_tpu.engine.table import Table
 from hyperspace_tpu.hyperspace import Hyperspace
 
 
-def _write_source(src_dir, n=6000, n_files=5, strings=False, nulls=False, seed=3):
+def _write_source(
+    src_dir, n=6000, n_files=5, strings=False, nulls=False, mixed_widths=False, seed=3
+):
     rng = np.random.RandomState(seed)
     per = n // n_files
     for i in range(n_files):
@@ -39,6 +41,8 @@ def _write_source(src_dir, n=6000, n_files=5, strings=False, nulls=False, seed=3
             "v": rng.randint(0, 100, per).astype(np.int64),
             "f": rng.rand(per),
         }
+        if mixed_widths and i == 0:
+            d["v"] = d["v"].astype(np.int32)  # promoted to int64 by the concat
         if nulls:
             vals = d["v"].astype(object)
             vals[rng.rand(per) < 0.1] = None
@@ -89,6 +93,7 @@ def _fresh_caches():
     cc.set_capacity(budget)
 
 
+@pytest.mark.parametrize("writers", [None, "1", "3"], ids=["writers_default", "writers1", "writers3"])
 @pytest.mark.parametrize(
     "variant",
     [
@@ -96,12 +101,14 @@ def _fresh_caches():
         {"strings": True},
         {"nulls": True},
         {"lineage": True},
+        {"mixed_widths": True},
     ],
-    ids=["ints", "strings", "nulls", "lineage"],
+    ids=["ints", "strings", "nulls", "lineage", "mixed_widths"],
 )
-def test_pipelined_build_is_byte_identical_to_serial(tmp_path, monkeypatch, variant):
+def test_pipelined_build_is_byte_identical_to_serial(tmp_path, monkeypatch, variant, writers):
     """threads>1 must produce byte-identical index files AND an identical
-    IndexLogEntry signature to the serial (threads=1) build."""
+    IndexLogEntry signature to the serial (threads=1) build, for any writer
+    count: one gather range (1 writer), several (3), one per bucket (8)."""
     lineage = variant.pop("lineage", False)
     src = str(tmp_path / "src")
     _write_source(src, **variant)
@@ -110,9 +117,15 @@ def test_pipelined_build_is_byte_identical_to_serial(tmp_path, monkeypatch, vari
     _fresh_caches()
     serial_hashes, serial_entry = _build(tmp_path, src, "serial", lineage=lineage)
 
+    if writers is not None:
+        monkeypatch.setenv("HYPERSPACE_BUILD_WRITERS", writers)
     monkeypatch.setenv("HYPERSPACE_BUILD_DECODE_THREADS", "3")
     _fresh_caches()
     piped_hashes, piped_entry = _build(tmp_path, src, "piped", lineage=lineage)
+    if writers is not None:  # 1 writer: one gather range; 3: the sort splits in 3
+        from hyperspace_tpu.telemetry.profiling import last_build_stages
+
+        assert last_build_stages()["stage_counts"]["take"] == int(writers)
 
     assert len(serial_hashes) > 0
     assert piped_hashes == serial_hashes
@@ -124,6 +137,41 @@ def test_pipelined_build_is_byte_identical_to_serial(tmp_path, monkeypatch, vari
     ) == sorted(
         (os.path.basename(f.name), f.size) for f in serial_entry.content.file_infos()
     )
+
+
+@pytest.mark.parametrize(
+    "counts, writers",
+    [
+        ([0, 5, 0, 0, 7, 3, 0, 9], 3),  # empty buckets between and at the ends
+        ([1, 1000, 1, 1], 4),  # one huge bucket
+        ([0, 4, 0, 6, 0, 0], 8),  # fewer non-empty buckets than writers
+        ([0] * 8, 4),  # no rows
+        ([10] * 64, 8),  # even buckets: one range per writer
+        ([3, 1, 4, 1, 5, 9, 2, 6], 1),  # one writer: one range
+    ],
+    ids=["empty_buckets", "huge_bucket", "few_buckets", "no_rows", "even", "one_writer"],
+)
+def test_gather_ranges_cover_every_bucket_once(counts, writers):
+    """The writer's gather ranges: contiguous, cut at bucket starts, covering
+    rows [0, n) with every non-empty bucket in exactly one range, each range
+    holding rows, and no more ranges than writers."""
+    from hyperspace_tpu.index.build_pipeline import _gather_ranges
+
+    starts = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
+    n = int(starts[-1])
+    ranges = _gather_ranges(starts, writers)
+    assert len(ranges) <= writers
+    if n == 0:
+        assert ranges == []
+        return
+    assert starts[ranges[0][0]] == 0 and starts[ranges[-1][1]] == n
+    for (_, b1), (b0, _) in zip(ranges, ranges[1:]):
+        assert b1 == b0  # contiguous, cut at a bucket start
+    assert all(starts[b1] > starts[b0] for b0, b1 in ranges)
+    held = [b for b0, b1 in ranges for b in range(b0, b1) if counts[b] > 0]
+    assert held == [b for b, c in enumerate(counts) if c > 0]
+    if counts == [10] * 64:
+        assert [(b1 - b0) for b0, b1 in ranges] == [8] * 8
 
 
 def test_pipelined_build_warm_cache_identical(tmp_path, monkeypatch):

@@ -440,9 +440,17 @@ def test_pipelined_build_records_real_stage_spans(
     )
     assert len(by_stage[staging]) == N_SRC_FILES  # one chunk per file
     assert len(by_stage["sort"]) == 1 and by_stage["sort"][0].attrs["rows"] == SRC_ROWS
-    assert [sp.attrs["rows"] for sp in by_stage["take"]] == [SRC_ROWS] * 3  # k, v, f
+    # One take per gather range, each over every column (k, v, f): the
+    # ranges' rows cover the source once, their buckets the files written.
+    takes = by_stage["take"]
+    assert 1 < len(takes) <= 3  # at most one range per writer
+    assert all(sp.attrs["columns"] == 3 for sp in takes)
+    assert sum(sp.attrs["rows"] for sp in takes) == SRC_ROWS
     writes = by_stage["write"]
     assert len(writes) == len(files) > 1  # one per non-empty bucket
+    assert sum(sp.attrs["buckets"] for sp in takes) == len(files)
+    (concat,) = by_stage["concat"]
+    assert concat.attrs["bytes"] > 0  # 4 source files: every column copied together
     assert len({sp.attrs["bucket"] for sp in writes}) == len(writes)
     assert sum(sp.attrs["rows"] for sp in writes) == SRC_ROWS
     assert sum(sp.attrs["rows"] for sp in by_stage["decode"]) == SRC_ROWS
