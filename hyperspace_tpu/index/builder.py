@@ -187,7 +187,7 @@ class CoveringIndexBuilder(IndexerBuilder):
                     from ..parallel.table_ops import distributed_bucketize_table
 
                     sorted_table, starts = distributed_bucketize_table(
-                        mesh, table, indexed, num_buckets
+                        mesh, table, indexed, num_buckets, stages
                     )
                 else:
                     sorted_table, starts = bucketize_table(table, indexed, num_buckets)

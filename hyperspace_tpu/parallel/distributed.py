@@ -31,7 +31,7 @@ mesh-built index files byte-identical to single-device ones.
 from __future__ import annotations
 
 from functools import lru_cache
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +41,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..telemetry import metrics as _metrics
 from ..telemetry import stage_ledger as _stage_ledger
 from ..telemetry.compile_log import observed_jit as _observed_jit
+from ..telemetry.profiling import StageTimings
 from .mesh import BUCKET_AXIS, quantize_cap
 
 _PAD_SLOT = -1
@@ -50,8 +51,11 @@ _WORD_BITS = 32
 #: All-to-all traffic accounting (ticked once per exchange on the host):
 #: payload = real row bytes moved, moved = the padded send-matrix bytes the
 #: interconnect actually carries. The gap between them is the static-shape
-#: padding tax — `bench_detail.mesh` reports both.
+#: padding tax — `bench_detail.mesh` reports both. `pad_rows` counts the
+#: rows the caller padded the input with (validity 0), which the count pass
+#: counts and the exchange carries like real rows.
 _EXCHANGE_ROWS = _metrics.counter("parallel.exchange.rows")
+_EXCHANGE_PAD_ROWS = _metrics.counter("parallel.exchange.pad_rows")
 _EXCHANGE_BYTES_PAYLOAD = _metrics.counter("parallel.exchange.bytes_payload")
 _EXCHANGE_BYTES_MOVED = _metrics.counter("parallel.exchange.bytes_moved")
 _EXCHANGES = _metrics.counter("parallel.exchange.count")
@@ -143,14 +147,18 @@ def _exchange_program(mesh: Mesh, num_buckets: int, cap: int):
     )
 
 
-def _record_exchange(n_valid: int, n_dev: int, cap: int, lanes, packed_spec=None) -> None:
+def _record_exchange(
+    n_valid: int, n_dev: int, cap: int, lanes, packed_spec=None
+) -> Tuple[int, int]:
     """Host-side traffic accounting for one exchange call (cheap: arithmetic
     over lane dtypes, no device sync). `packed_spec` (aligned with `lanes`)
     marks bit-packed wire lanes: a packed lane's interconnect cost is its
     uint32 WORD matrix — `cap // lanes_per_word` words per destination — and
-    its payload is the true bits-on-the-wire of the valid rows."""
+    its payload is the true bits-on-the-wire of the valid rows. Returns
+    (payload bytes, moved bytes)."""
     _EXCHANGES.inc()
     _EXCHANGE_ROWS.inc(int(n_valid))
+    _EXCHANGE_PAD_ROWS.inc(int(lanes[0].shape[0]) - int(n_valid))
     payload = 0
     moved = 0
     for i, lane in enumerate(lanes):
@@ -170,6 +178,40 @@ def _record_exchange(n_valid: int, n_dev: int, cap: int, lanes, packed_spec=None
     from ..telemetry import device_observatory as _devobs
 
     _devobs.record_pad("mesh_exchange", payload, moved - payload)
+    return payload, moved
+
+
+def _run_exchange(
+    stages: Optional[StageTimings], n_dev: int, cap: int, traffic, program, *args
+):
+    """The exchange program on every device, to its outputs being ready: the
+    index build's ``exchange`` stage. `traffic` is (payload, moved) bytes
+    from `_record_exchange`, or None for an unaccounted call."""
+    with StageTimings.timed_or_not(stages, "exchange") as sp:
+        out = jax.block_until_ready(program(*args))
+        if sp.recording:
+            sp.add_attrs(devices=n_dev, cap=cap)
+            if traffic is not None:
+                sp.add_attrs(bytes_payload=traffic[0], bytes_moved=traffic[1])
+    return out
+
+
+def _exchange_cap(
+    stages: Optional[StageTimings], count, mesh: Mesh, lead, num_buckets: int
+) -> int:
+    """Pass 1 (`count`: `exchange_counts` or `exchange_counts_coded`) and the
+    per-destination capacity, the largest count quantized: the index build's
+    ``exchange_counts`` stage."""
+    with StageTimings.timed_or_not(stages, "exchange_counts") as sp:
+        counts = count(mesh, lead, num_buckets)
+        max_count = int(counts.max()) if counts.size else 0
+        # Quantize to the mesh row quantum's power-of-two grid so repeated
+        # builds of growing data reuse ONE compiled exchange instead of
+        # recompiling per exact capacity (the compile-boundedness contract).
+        cap = quantize_cap(max_count)
+        if sp.recording:
+            sp.add_attrs(cap=cap, max_count=max_count)
+    return cap
 
 
 def exchange_rows(
@@ -181,6 +223,7 @@ def exchange_rows(
     cap: int,
     in_valid=None,
     n_valid=None,
+    stages: Optional[StageTimings] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, List[jnp.ndarray]]:
     """Pass 2: all-to-all exchange + local in-bucket sort.
 
@@ -190,7 +233,8 @@ def exchange_rows(
     `n_valid` is the real (un-padded) row count for the traffic counters; the
     table-level callers pass it, and a plain unpadded call infers it from the
     shape — never from a device sync, so tracing/lowering this function stays
-    legal.
+    legal. `stages` is the index build's, which times this pass as its
+    ``exchange`` stage.
 
     Returns (bucket_ids [n_dev*cap], valid mask, payload arrays), each sharded over
     the mesh: device d's block holds its bucket range, valid rows sorted by
@@ -204,10 +248,21 @@ def exchange_rows(
             in_valid = jnp.ones(h1.shape, dtype=jnp.int32)
             if n_valid is None:
                 n_valid = int(h1.shape[0])
+        traffic = None
         if n_valid is not None:
-            _record_exchange(n_valid, n_dev, cap, [h1, in_valid, *payload, *sort_keys])
-        return _exchange_program(mesh, num_buckets, cap)(
-            h1, in_valid, list(payload), list(sort_keys)
+            traffic = _record_exchange(
+                n_valid, n_dev, cap, [h1, in_valid, *payload, *sort_keys]
+            )
+        return _run_exchange(
+            stages,
+            n_dev,
+            cap,
+            traffic,
+            _exchange_program(mesh, num_buckets, cap),
+            h1,
+            in_valid,
+            list(payload),
+            list(sort_keys),
         )
 
 
@@ -219,17 +274,16 @@ def distributed_bucketize(
     num_buckets: int,
     in_valid=None,
     n_valid=None,
+    stages: Optional[StageTimings] = None,
 ):
     """Full two-pass distributed bucketize. Rows arrive sharded over the mesh; the
-    result is (bucket_ids, valid, payload) blocks, one bucket range per device."""
-    counts = exchange_counts(mesh, h1, num_buckets)
-    cap = int(counts.max()) if counts.size else 0
-    # Quantize to the mesh row quantum's power-of-two grid so repeated builds
-    # of growing data reuse ONE compiled exchange instead of recompiling per
-    # exact capacity (the compile-boundedness contract).
-    cap = quantize_cap(cap)
+    result is (bucket_ids, valid, payload) blocks, one bucket range per device.
+    `stages` (the index build's) times the two passes as its
+    ``exchange_counts`` and ``exchange`` stages."""
+    cap = _exchange_cap(stages, exchange_counts, mesh, h1, num_buckets)
     return exchange_rows(
-        mesh, h1, payload, sort_keys, num_buckets, cap, in_valid, n_valid=n_valid
+        mesh, h1, payload, sort_keys, num_buckets, cap, in_valid, n_valid=n_valid,
+        stages=stages,
     )
 
 
@@ -390,6 +444,7 @@ def distributed_bucketize_coded(
     n_valid: int,
     sort_from_payload: Sequence[int] = (),
     packed_spec: Sequence[Tuple[int, int]] = (),
+    stages: Optional[StageTimings] = None,
 ):
     """Two-pass distributed bucketize over NARROW lanes: `bucket` is the
     pre-computed (h1 % num_buckets) lane in its smallest width, `in_valid` is
@@ -398,22 +453,29 @@ def distributed_bucketize_coded(
     (bucket, valid, *payload, *sort_keys)) bit-packs the marked lanes across
     the all_to_all (`HYPERSPACE_PACKED_CODES`). Output contract (and bytes of
     the output) match `distributed_bucketize`: int32 bucket ids, int32
-    validity, payload lanes in their input dtypes."""
+    validity, payload lanes in their input dtypes. `stages` as there."""
     with _stage_ledger.stage_scope("exchange"):
-        counts = exchange_counts_coded(mesh, bucket, num_buckets)
-        cap = quantize_cap(int(counts.max()) if counts.size else 0)
+        cap = _exchange_cap(stages, exchange_counts_coded, mesh, bucket, num_buckets)
         n_dev = mesh.devices.size
         spec = tuple(tuple(s) for s in packed_spec)
-        _record_exchange(
+        traffic = _record_exchange(
             n_valid,
             n_dev,
             cap,
             [bucket, in_valid, *payload, *sort_keys],
             packed_spec=spec if spec else None,
         )
-        return _exchange_coded_program(
-            mesh, num_buckets, cap, tuple(sort_from_payload), spec
-        )(bucket, in_valid, list(payload), list(sort_keys))
+        return _run_exchange(
+            stages,
+            n_dev,
+            cap,
+            traffic,
+            _exchange_coded_program(mesh, num_buckets, cap, tuple(sort_from_payload), spec),
+            bucket,
+            in_valid,
+            list(payload),
+            list(sort_keys),
+        )
 
 
 # ---------------------------------------------------------------------------

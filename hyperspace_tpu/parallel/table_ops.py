@@ -38,9 +38,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..engine.encoding import column_nbytes
 from ..engine.table import Table
 from ..ops.hashing import _SEED1, combined_hash_u32, key64
 from ..telemetry.compile_log import observed_jit as _observed_jit
+from ..telemetry.profiling import StageTimings
 from .distributed import distributed_bucketize, distributed_bucketize_coded
 from .mesh import BUCKET_AXIS, quantize_cap, quantized_rows, row_sharding
 
@@ -183,7 +185,11 @@ def _gather_valid_perm(bucket, valid, rowid) -> Tuple[np.ndarray, np.ndarray]:
 
 
 def distributed_bucketize_table(
-    mesh: Mesh, table: Table, bucket_columns: Sequence[str], num_buckets: int
+    mesh: Mesh,
+    table: Table,
+    bucket_columns: Sequence[str],
+    num_buckets: int,
+    stages: Optional[StageTimings] = None,
 ) -> Tuple[Table, np.ndarray]:
     """Mesh-wide hash-partition + in-bucket sort; drop-in for `bucketize_table`.
 
@@ -194,78 +200,100 @@ def distributed_bucketize_table(
     the same column hash) AND the within-bucket order is the same canonical
     stable (bucket, keys..., original row) order, so the two paths produce
     BYTE-IDENTICAL index files — `HYPERSPACE_DISTRIBUTED=0` is an exact
-    fallback, pinned by the on/off oracles in tests/test_mesh_compile.py."""
+    fallback, pinned by the on/off oracles in tests/test_mesh_compile.py.
+
+    `stages` (the index build's) times each step as a stage: ``hash``, ``h2d``
+    (the wire lanes built and put on the mesh), ``exchange_counts`` and
+    ``exchange`` (`distributed.py`), ``gather`` (the exchange's output copied
+    back into the permutation and bucket starts) and ``take`` (the reordered
+    table)."""
     n_dev = mesh.devices.size
     n = table.num_rows
     cols = [table.column(c) for c in bucket_columns]
 
     n_pad_total = quantized_rows(n, n_dev)
     pad = n_pad_total - n
-    arrs_p = _padded_hash_inputs(cols, pad)
-    h1_np = np.asarray(combined_hash_u32(cols, arrs_p, _SEED1))
-
-    sh = row_sharding(mesh)
-
-    def put(x):
-        return jax.device_put(jnp.asarray(x), sh)
+    with StageTimings.timed_or_not(stages, "hash", rows=n, pad_rows=pad):
+        arrs_p = _padded_hash_inputs(cols, pad)
+        h1_np = np.asarray(combined_hash_u32(cols, arrs_p, _SEED1))
 
     from ..engine.encoded_device import encoded_device_enabled
 
-    if encoded_device_enabled():
-        # Code-space wire lanes: the narrow (h1 % num_buckets) lane replaces
-        # the uint32 hash, validity rides int8, row ids int32 when the
-        # padded count fits, and string sort keys travel as narrow codes.
-        # Every lane carries the SAME VALUES as the flat path, so the
-        # exchange permutation — and the index files — are byte-identical;
-        # only `parallel.exchange.bytes_moved` shrinks.
-        bucket_np = (h1_np % np.uint32(num_buckets)).astype(
-            _bucket_lane_dtype(num_buckets)
-        )
-        valid_p = np.ones(n + pad, np.int8)
+    coded = encoded_device_enabled()
+    with StageTimings.timed_or_not(stages, "h2d") as sp:
+        if coded:
+            # Code-space wire lanes: the narrow (h1 % num_buckets) lane replaces
+            # the uint32 hash, validity rides int8, row ids int32 when the
+            # padded count fits, and string sort keys travel as narrow codes.
+            # Every lane carries the SAME VALUES as the flat path, so the
+            # exchange permutation — and the index files — are byte-identical;
+            # only `parallel.exchange.bytes_moved` shrinks.
+            lead = (h1_np % np.uint32(num_buckets)).astype(
+                _bucket_lane_dtype(num_buckets)
+            )
+            valid_p = np.ones(n + pad, np.int8)
+            rowid_p = _pad_rows(np.arange(n, dtype=_coded_rowid_dtype(n_pad_total)), pad)
+            keys_p = _sort_key_arrays(table, bucket_columns, pad, narrow=True)
+            flat_keys = [
+                np.int32 if c.data.dtype == np.bool_ else c.data.dtype for c in cols
+            ]
+            packed_spec = _packed_wire_spec(
+                num_buckets, lead, n_pad_total, rowid_p, list(zip(cols, keys_p))
+            )
+            _record_coded_stage(
+                n_pad_total,
+                [np.uint32, np.int32, np.int64, *flat_keys],
+                [lead, valid_p, rowid_p, *keys_p],
+                packed_spec=packed_spec or None,
+            )
+        else:
+            lead = h1_np
+            valid_p = np.ones(n + pad, np.int32)
+            rowid_p = _pad_rows(np.arange(n, dtype=np.int64), pad)
+            keys_p = _sort_key_arrays(table, bucket_columns, pad)
         valid_p[n:] = 0
-        rowid_p = _pad_rows(np.arange(n, dtype=_coded_rowid_dtype(n_pad_total)), pad)
-        keys_p = _sort_key_arrays(table, bucket_columns, pad, narrow=True)
-        flat_keys = [
-            np.int32 if c.data.dtype == np.bool_ else c.data.dtype for c in cols
-        ]
-        packed_spec = _packed_wire_spec(
-            num_buckets, bucket_np, n_pad_total, rowid_p, list(zip(cols, keys_p))
+        host = [lead, valid_p, rowid_p, *keys_p]
+        sh = row_sharding(mesh)
+        lead_d, valid_d, rowid_d, *keys_d = jax.block_until_ready(
+            [jax.device_put(jnp.asarray(a), sh) for a in host]
         )
-        _record_coded_stage(
-            n_pad_total,
-            [np.uint32, np.int32, np.int64, *flat_keys],
-            [bucket_np, valid_p, rowid_p, *keys_p],
-            packed_spec=packed_spec or None,
-        )
+        if sp.recording:
+            sp.set_attr("bytes", sum(a.nbytes for a in host))
+
+    if coded:
         bucket, out_valid, (rowid_out,) = distributed_bucketize_coded(
             mesh,
-            put(bucket_np),
-            [put(rowid_p)],
-            [put(k) for k in keys_p],
+            lead_d,
+            [rowid_d],
+            keys_d,
             num_buckets,
-            in_valid=put(valid_p),
+            in_valid=valid_d,
             n_valid=n,
             packed_spec=packed_spec,
+            stages=stages,
         )
     else:
-        valid_p = np.ones(n + pad, np.int32)
-        valid_p[n:] = 0
-        rowid_p = _pad_rows(np.arange(n, dtype=np.int64), pad)
-        keys_p = _sort_key_arrays(table, bucket_columns, pad)
-
         bucket, out_valid, (rowid_out,) = distributed_bucketize(
             mesh,
-            put(h1_np),
-            [put(rowid_p)],
-            [put(k) for k in keys_p],
+            lead_d,
+            [rowid_d],
+            keys_d,
             num_buckets,
-            in_valid=put(valid_p),
+            in_valid=valid_d,
             n_valid=n,
+            stages=stages,
         )
-    perm, bucket_v = _gather_valid_perm(bucket, out_valid, rowid_out)
-    assert len(perm) == n, f"exchange dropped rows: {len(perm)} != {n}"
-    starts = np.searchsorted(bucket_v, np.arange(num_buckets + 1))
-    return table.take(perm), starts
+    with StageTimings.timed_or_not(stages, "gather") as sp:
+        perm, bucket_v = _gather_valid_perm(bucket, out_valid, rowid_out)
+        assert len(perm) == n, f"exchange dropped rows: {len(perm)} != {n}"
+        starts = np.searchsorted(bucket_v, np.arange(num_buckets + 1))
+        if sp.recording:
+            sp.set_attr("bytes", bucket.nbytes + out_valid.nbytes + rowid_out.nbytes)
+    with StageTimings.timed_or_not(stages, "take", rows=n, columns=len(table.columns)) as sp:
+        out = table.take(perm)
+        if sp.recording:
+            sp.set_attr("bytes", sum(column_nbytes(c) for c in out.columns.values()))
+    return out, starts
 
 
 def distributed_exchange_table(

@@ -39,6 +39,9 @@ class StageTimings:
     sums measure busy time across workers, not a wall-clock partition:
     `overlap_ratio = sum(stage_s) / wall_s` > 1 means stages genuinely ran on
     top of each other, ~1 means the pipeline degenerated to a serial chain.
+    A bracket opened inside another on the same thread (the mesh build's
+    steps inside its ``sort``) adds to its own stage's sum but not again to
+    the busy time the ratio counts.
 
     While tracing records, construction opens the ``<kind>:stages`` span under
     the ambient span; `summary()` closes it and hangs the summary on it, and
@@ -52,6 +55,8 @@ class StageTimings:
         self._lock = threading.Lock()
         self._stages: Dict[str, float] = {}
         self._counts: Dict[str, int] = {}
+        self._nested_s = 0.0  # seconds of brackets inside another, same thread
+        self._depth = threading.local()
         self.kind = kind
         self.mode = mode
         self._t0 = time.monotonic()
@@ -65,10 +70,12 @@ class StageTimings:
         # A no-op after summary(): the first end wins.
         self.span.end(status="fallback", error=exc)
 
-    def add(self, stage: str, seconds: float) -> None:
+    def add(self, stage: str, seconds: float, nested: bool = False) -> None:
         with self._lock:
             self._stages[stage] = self._stages.get(stage, 0.0) + float(seconds)
             self._counts[stage] = self._counts.get(stage, 0) + 1
+            if nested:
+                self._nested_s += float(seconds)
 
     @contextlib.contextmanager
     def timed(self, stage: str, **attrs) -> Iterator:
@@ -79,6 +86,8 @@ class StageTimings:
         span — on whichever thread runs it, hence the explicit parent. Yields
         that span (the shared no-op span while tracing is off) so the site
         can record the bracket's counts on it; `attrs` start it."""
+        depth = getattr(self._depth, "n", 0)
+        self._depth.n = depth + 1
         t0 = time.monotonic()
         try:
             with tracing.span(
@@ -86,7 +95,17 @@ class StageTimings:
             ) as sp, stage_ledger.stage_scope(stage):
                 yield sp
         finally:
-            self.add(stage, time.monotonic() - t0)
+            self._depth.n = depth
+            self.add(stage, time.monotonic() - t0, nested=depth > 0)
+
+    @staticmethod
+    def timed_or_not(stages: Optional["StageTimings"], stage: str, **attrs):
+        """`stages.timed(stage, **attrs)`, or, for a caller that runs outside
+        any operation's stages (`stages` None), a bracket that times and
+        records nothing and yields the no-op span."""
+        if stages is None:
+            return contextlib.nullcontext(tracing.NOOP_SPAN)
+        return stages.timed(stage, **attrs)
 
     def finish(self) -> None:
         if self._wall is None:
@@ -98,7 +117,7 @@ class StageTimings:
         self.finish()
         with self._lock:
             wall = self._wall or 0.0
-            busy = sum(self._stages.values())
+            busy = sum(self._stages.values()) - self._nested_s
             out = {f"{k}_s": round(v, 4) for k, v in sorted(self._stages.items())}
             out["wall_s"] = round(wall, 4)
             out["overlap_ratio"] = round(busy / wall, 3) if wall > 0 else None

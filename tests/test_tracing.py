@@ -512,6 +512,80 @@ def test_failing_build_closes_stage_spans_with_error(session, tmp_path, monkeypa
     assert any(sp.status == "error" for sp in cap.trace.find("build:write"))
 
 
+MESH_STAGES = ("hash", "h2d", "exchange_counts", "exchange", "gather", "take")
+
+
+def test_mesh_build_records_exchange_spans(session, tmp_path, monkeypatch):
+    """A build on the mesh (every visible device; 8 here) times each step of
+    its bucketize as a stage inside `build:sort`, once per build, with the
+    counts that step saw; the exchange's bytes are the counters' deltas."""
+    session.conf.set(IndexConstants.DISTRIBUTED_MIN_ROWS, 0)
+    src = _write_build_source(session, tmp_path)
+    before = metrics.snapshot()["counters"]
+    with tracing.capture() as cap:
+        Hyperspace(session).create_index(
+            session.read.parquet(src), IndexConfig("idx", ["k"], ["v", "f"])
+        )
+    after = metrics.snapshot()["counters"]
+    delta = {
+        k: after[k] - before.get(k, 0)
+        for k in after
+        if k.startswith("parallel.exchange.")
+    }
+    trace = cap.trace
+    (stages,) = trace.find("build:stages")
+    (sort,) = trace.find("build:sort")
+    assert stages.attrs["mode"] == "mesh"
+    sort_end = sort.start_s + sort.duration_s
+    spans = {}
+    for stage in MESH_STAGES:
+        (sp,) = trace.find(f"build:{stage}")
+        assert sp.parent_id == stages.span_id and sp.status == "ok"
+        assert sort.start_s <= sp.start_s
+        assert sp.start_s + sp.duration_s <= sort_end + 1e-3
+        assert stages.attrs["stage_counts"][stage] == 1
+        spans[stage] = sp.attrs
+    assert [s.name.split(":", 1)[1] for s in sorted(
+        (trace.find(f"build:{st}")[0] for st in MESH_STAGES), key=lambda s: s.start_s
+    )] == list(MESH_STAGES)
+    assert spans["hash"]["rows"] == SRC_ROWS
+    assert spans["hash"]["pad_rows"] == delta["parallel.exchange.pad_rows"] > 0
+    assert spans["h2d"]["bytes"] > 0 and spans["gather"]["bytes"] > 0
+    counts, exchange = spans["exchange_counts"], spans["exchange"]
+    assert 0 < counts["max_count"] <= counts["cap"] == exchange["cap"]
+    assert exchange["devices"] == 8
+    assert delta["parallel.exchange.count"] == 1
+    assert exchange["bytes_payload"] == delta["parallel.exchange.bytes_payload"] > 0
+    assert exchange["bytes_moved"] == delta["parallel.exchange.bytes_moved"]
+    assert spans["take"]["rows"] == SRC_ROWS and spans["take"]["columns"] == 3
+    assert spans["take"]["bytes"] == SRC_ROWS * 3 * 8
+    # The steps nest in the sort: the ratio counts their seconds once.
+    assert stages.attrs["overlap_ratio"] <= 1.05
+
+
+@pytest.mark.parametrize("route", ["pipelined", "serial"])
+def test_single_device_build_records_no_exchange_spans(
+    session, tmp_path, monkeypatch, route
+):
+    session.conf.set(IndexConstants.DISTRIBUTED_ENABLED, False)
+    if route == "serial":
+        monkeypatch.setenv("HYPERSPACE_BUILD_DECODE_THREADS", "1")
+        src = _write_build_source(session, tmp_path)
+        with tracing.capture() as cap:
+            Hyperspace(session).create_index(
+                session.read.parquet(src), IndexConfig("idx", ["k"], ["v", "f"])
+            )
+    else:
+        with tracing.capture() as cap:
+            _pipelined_build(session, tmp_path, monkeypatch)
+    names = {sp.name for sp in cap.trace.spans}
+    for stage in ("exchange_counts", "exchange", "gather"):
+        assert f"build:{stage}" not in names
+    if route == "serial":  # decode, sort and write alone
+        assert not names & {f"build:{stage}" for stage in MESH_STAGES}
+    assert not any("pad_rows" in sp.attrs for sp in cap.trace.spans)
+
+
 @pytest.mark.parametrize("outcome", ["summary", "fallback", "error"])
 def test_stage_timings_closes_its_stages_span(outcome):
     """The stages span ends with the summary, with ``status="fallback"`` when
