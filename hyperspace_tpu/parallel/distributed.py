@@ -6,12 +6,14 @@ This is the TPU-native replacement for Spark's cluster-wide shuffle machinery
 all because both sides' bucket blocks are co-located by construction.
 
 Build exchange (two-pass, static shapes — the standard way around ragged all-to-all):
-1. Count pass (shard_map): each device computes its per-destination row counts.
+1. Count pass (shard_map): each device computes its per-destination counts of
+   VALID rows (the validity lane masks the caller's pad rows out).
 2. Host sync: capacity = global max count (one scalar per mesh; amortized, and
    stable across repeated builds of similar data).
-3. Exchange pass (shard_map): rows sorted by destination, scattered into a padded
-   [n_dev, cap] send matrix per column, `lax.all_to_all` over the bucket axis,
-   then a local (bucket, keys...) sort of the received rows.
+3. Exchange pass (shard_map): valid rows sorted by destination, scattered into a
+   padded [n_dev, cap] send matrix per column (pad rows take no slot),
+   `lax.all_to_all` over the bucket axis, then a local (bucket, keys...) sort of
+   the received rows.
 
 Device d ends up owning buckets [d*B/n, (d+1)*B/n) fully sorted — exactly the layout
 the bucketed writer persists and the co-bucketed join consumes.
@@ -52,8 +54,8 @@ _WORD_BITS = 32
 #: payload = real row bytes moved, moved = the padded send-matrix bytes the
 #: interconnect actually carries. The gap between them is the static-shape
 #: padding tax — `bench_detail.mesh` reports both. `pad_rows` counts the
-#: rows the caller padded the input with (validity 0), which the count pass
-#: counts and the exchange carries like real rows.
+#: rows the caller padded the input with (validity 0); the count pass skips
+#: them and the exchange gives them no send slot.
 _EXCHANGE_ROWS = _metrics.counter("parallel.exchange.rows")
 _EXCHANGE_PAD_ROWS = _metrics.counter("parallel.exchange.pad_rows")
 _EXCHANGE_BYTES_PAYLOAD = _metrics.counter("parallel.exchange.bytes_payload")
@@ -66,6 +68,26 @@ def _dest_of(h1, num_buckets: int, n_dev: int):
     return bucket * n_dev // num_buckets, bucket
 
 
+def _valid_counts(dest, valid, n_dev: int):
+    """[1, n_dev] counts of this device's VALID rows per destination."""
+    one_hot = jax.nn.one_hot(dest, n_dev, dtype=jnp.int32)
+    return jnp.sum(one_hot * valid.astype(jnp.int32)[:, None], axis=0, keepdims=True)
+
+
+def _send_slots(dest, valid, n_dev: int):
+    """(order, destination, slot) of each row in its send matrix [n_dev, cap].
+    A pad row (valid 0) takes destination n_dev, past the last: the stable
+    argsort puts it last, and its out-of-range row index makes the scatter
+    (`mode="drop"`) skip it, so pads never cross the wire. Valid rows keep
+    their original (= global) order within each destination."""
+    dest = jnp.where(valid != 0, dest, n_dev)
+    order = jnp.argsort(dest)  # stable: ties keep original (= global) order
+    dest_s = dest[order]
+    starts = jnp.searchsorted(dest_s, jnp.arange(n_dev + 1))
+    slot = jnp.arange(dest.shape[0]) - starts[dest_s]
+    return order, dest_s, slot
+
+
 # Program factories are lru_cached so repeated exchanges (every distributed build
 # and every exchanged join in a process) hit jax's compiled-computation cache
 # instead of re-tracing a fresh shard_map closure per call.
@@ -75,20 +97,24 @@ def _dest_of(h1, num_buckets: int, n_dev: int):
 def _counts_program(mesh: Mesh, num_buckets: int):
     n_dev = mesh.devices.size
 
-    def count_fn(h1_local):
+    def count_fn(h1_local, valid_local):
         dest, _ = _dest_of(h1_local, num_buckets, n_dev)
-        one_hot = jax.nn.one_hot(dest, n_dev, dtype=jnp.int32)
-        return jnp.sum(one_hot, axis=0, keepdims=True)  # [1, n_dev]
+        return _valid_counts(dest, valid_local, n_dev)
 
     return _observed_jit(
-        jax.shard_map(count_fn, mesh=mesh, in_specs=P(BUCKET_AXIS), out_specs=P(BUCKET_AXIS)),
+        jax.shard_map(
+            count_fn,
+            mesh=mesh,
+            in_specs=(P(BUCKET_AXIS), P(BUCKET_AXIS)),
+            out_specs=P(BUCKET_AXIS),
+        ),
         label="parallel.exchange_counts",
     )
 
 
-def exchange_counts(mesh: Mesh, h1, num_buckets: int) -> np.ndarray:
-    """Pass 1: [n_dev, n_dev] matrix of rows device i sends to device j."""
-    return np.asarray(_counts_program(mesh, num_buckets)(h1))
+def exchange_counts(mesh: Mesh, h1, num_buckets: int, valid) -> np.ndarray:
+    """Pass 1: [n_dev, n_dev] matrix of valid rows device i sends to device j."""
+    return np.asarray(_counts_program(mesh, num_buckets)(h1, valid))
 
 
 @lru_cache(maxsize=128)
@@ -96,16 +122,12 @@ def _exchange_program(mesh: Mesh, num_buckets: int, cap: int):
     n_dev = mesh.devices.size
 
     def fn(h1_local, valid_local, payload_local, keys_local):
-        n_local = h1_local.shape[0]
         dest, _ = _dest_of(h1_local, num_buckets, n_dev)
-        order = jnp.argsort(dest)  # stable: ties keep original (= global) order
-        dest_s = dest[order]
-        starts = jnp.searchsorted(dest_s, jnp.arange(n_dev))
-        slot = jnp.arange(n_local) - starts[dest_s]
+        order, dest_s, slot = _send_slots(dest, valid_local, n_dev)
 
         def scatter(col):
             send = jnp.zeros((n_dev, cap), dtype=col.dtype)
-            send = send.at[dest_s, slot].set(col[order])
+            send = send.at[dest_s, slot].set(col[order], mode="drop")
             return jax.lax.all_to_all(
                 send, BUCKET_AXIS, split_axis=0, concat_axis=0, tiled=False
             )
@@ -197,20 +219,22 @@ def _run_exchange(
 
 
 def _exchange_cap(
-    stages: Optional[StageTimings], count, mesh: Mesh, lead, num_buckets: int
+    stages: Optional[StageTimings], count, mesh: Mesh, lead, valid, num_buckets: int
 ) -> int:
     """Pass 1 (`count`: `exchange_counts` or `exchange_counts_coded`) and the
-    per-destination capacity, the largest count quantized: the index build's
-    ``exchange_counts`` stage."""
+    per-destination capacity, the largest count of valid rows quantized: the
+    index build's ``exchange_counts`` stage, whose ``shard_rows`` is the real
+    rows on the fullest sending shard."""
     with StageTimings.timed_or_not(stages, "exchange_counts") as sp:
-        counts = count(mesh, lead, num_buckets)
+        counts = count(mesh, lead, num_buckets, valid)
         max_count = int(counts.max()) if counts.size else 0
         # Quantize to the mesh row quantum's power-of-two grid so repeated
         # builds of growing data reuse ONE compiled exchange instead of
         # recompiling per exact capacity (the compile-boundedness contract).
         cap = quantize_cap(max_count)
         if sp.recording:
-            sp.add_attrs(cap=cap, max_count=max_count)
+            shard_rows = int(counts.sum(axis=1).max()) if counts.size else 0
+            sp.add_attrs(cap=cap, max_count=max_count, shard_rows=shard_rows)
     return cap
 
 
@@ -229,7 +253,7 @@ def exchange_rows(
 
     `in_valid` (optional, int32 0/1 per row, sharded like `h1`) marks padding rows
     added by the caller to make the global row count divisible by the mesh size;
-    they travel through the exchange but come out with valid=0 (sorted last).
+    they take no send slot, so `cap` need only hold the valid rows.
     `n_valid` is the real (un-padded) row count for the traffic counters; the
     table-level callers pass it, and a plain unpadded call infers it from the
     shape — never from a device sync, so tracing/lowering this function stays
@@ -280,7 +304,11 @@ def distributed_bucketize(
     result is (bucket_ids, valid, payload) blocks, one bucket range per device.
     `stages` (the index build's) times the two passes as its
     ``exchange_counts`` and ``exchange`` stages."""
-    cap = _exchange_cap(stages, exchange_counts, mesh, h1, num_buckets)
+    if in_valid is None:
+        in_valid = jnp.ones(h1.shape, dtype=jnp.int32)
+        if n_valid is None:
+            n_valid = int(h1.shape[0])
+    cap = _exchange_cap(stages, exchange_counts, mesh, h1, in_valid, num_buckets)
     return exchange_rows(
         mesh, h1, payload, sort_keys, num_buckets, cap, in_valid, n_valid=n_valid,
         stages=stages,
@@ -307,20 +335,24 @@ def distributed_bucketize(
 def _counts_coded_program(mesh: Mesh, num_buckets: int):
     n_dev = mesh.devices.size
 
-    def count_fn(bucket_local):
+    def count_fn(bucket_local, valid_local):
         dest = bucket_local.astype(jnp.int32) * n_dev // num_buckets
-        one_hot = jax.nn.one_hot(dest, n_dev, dtype=jnp.int32)
-        return jnp.sum(one_hot, axis=0, keepdims=True)  # [1, n_dev]
+        return _valid_counts(dest, valid_local, n_dev)
 
     return _observed_jit(
-        jax.shard_map(count_fn, mesh=mesh, in_specs=P(BUCKET_AXIS), out_specs=P(BUCKET_AXIS)),
+        jax.shard_map(
+            count_fn,
+            mesh=mesh,
+            in_specs=(P(BUCKET_AXIS), P(BUCKET_AXIS)),
+            out_specs=P(BUCKET_AXIS),
+        ),
         label="parallel.exchange_counts",
     )
 
 
-def exchange_counts_coded(mesh: Mesh, bucket, num_buckets: int) -> np.ndarray:
+def exchange_counts_coded(mesh: Mesh, bucket, num_buckets: int, valid) -> np.ndarray:
     """Pass 1 over a pre-computed (narrow) bucket-id lane."""
-    return np.asarray(_counts_coded_program(mesh, num_buckets)(bucket))
+    return np.asarray(_counts_coded_program(mesh, num_buckets)(bucket, valid))
 
 
 def _pack_wire(send, bits: int):
@@ -370,12 +402,8 @@ def _exchange_coded_program(
             )
 
     def fn(bucket_local, valid_local, payload_local, keys_local):
-        n_local = bucket_local.shape[0]
         dest = bucket_local.astype(jnp.int32) * n_dev // num_buckets
-        order = jnp.argsort(dest)  # stable: ties keep original (= global) order
-        dest_s = dest[order]
-        starts = jnp.searchsorted(dest_s, jnp.arange(n_dev))
-        slot = jnp.arange(n_local) - starts[dest_s]
+        order, dest_s, slot = _send_slots(dest, valid_local, n_dev)
 
         def _wire(send):
             return jax.lax.all_to_all(
@@ -388,7 +416,7 @@ def _exchange_coded_program(
         def scatter(col, spec=(0, 0)):
             bits, bias = spec
             send = jnp.zeros((n_dev, cap), dtype=col.dtype)
-            send = send.at[dest_s, slot].set(col[order])
+            send = send.at[dest_s, slot].set(col[order], mode="drop")
             if not bits:
                 return _wire(send)
             biased = (send.astype(jnp.int32) + bias) if bias else send
@@ -455,7 +483,9 @@ def distributed_bucketize_coded(
     the output) match `distributed_bucketize`: int32 bucket ids, int32
     validity, payload lanes in their input dtypes. `stages` as there."""
     with _stage_ledger.stage_scope("exchange"):
-        cap = _exchange_cap(stages, exchange_counts_coded, mesh, bucket, num_buckets)
+        cap = _exchange_cap(
+            stages, exchange_counts_coded, mesh, bucket, in_valid, num_buckets
+        )
         n_dev = mesh.devices.size
         spec = tuple(tuple(s) for s in packed_spec)
         traffic = _record_exchange(

@@ -134,8 +134,41 @@ def _pad_rows(arr: np.ndarray, pad: int, fill=0) -> np.ndarray:
     return np.concatenate([arr, np.full(pad, fill, dtype=arr.dtype)])
 
 
+def _even_shards(arr: np.ndarray, n_dev: int, n_pad_total: int) -> np.ndarray:
+    """One exchange wire lane, row-sharded as [n_dev, S] (S = n_pad_total /
+    n_dev) and flattened: shard d holds real rows [d*q, min((d+1)*q, n)),
+    q = ceil(n / n_dev), then zeros (pad rows, valid 0) to its end. Spreading
+    the real rows evenly keeps every shard's sends, and with them the
+    exchange capacity, at the real rows' share; keeping them contiguous and
+    in shard order keeps (sender, sender-local position) the original global
+    row order, the receive sort's tie-break."""
+    n = len(arr)
+    shard = n_pad_total // n_dev
+    q = max(1, -(-n // n_dev))
+    full = n // q  # shards filled to q real rows
+    out = np.empty((n_dev, shard), dtype=arr.dtype)
+    out[:full, :q] = arr[: full * q].reshape(full, q)
+    out[:full, q:] = 0
+    if full < n_dev:
+        rest = n - full * q
+        out[full, :rest] = arr[full * q :]
+        out[full, rest:] = 0
+        out[full + 1 :] = 0
+    return out.reshape(-1)
+
+
+def _wire_rows(n: int, n_dev: int, n_pad_total: int, valid_dtype, rowid_dtype):
+    """The (validity, row id) wire lanes of `n` real rows in the
+    `_even_shards` layout: row ids carry each row's original index, so the
+    permutation the exchange returns is in source-row terms."""
+    valid = _even_shards(np.ones(n, valid_dtype), n_dev, n_pad_total)
+    rowid = _even_shards(np.arange(n, dtype=rowid_dtype), n_dev, n_pad_total)
+    return valid, rowid
+
+
 def _sort_key_arrays(
-    table: Table, columns: Sequence[str], pad: int, narrow: bool = False
+    table: Table, columns: Sequence[str], n_dev: int, n_pad_total: int,
+    narrow: bool = False,
 ) -> List[np.ndarray]:
     from ..engine.encoded_device import narrow_codes, narrowable
 
@@ -149,7 +182,7 @@ def _sort_key_arrays(
             a = narrow_codes(col)
         if a.dtype == np.bool_:
             a = a.astype(np.int32)
-        out.append(_pad_rows(a, pad))
+        out.append(_even_shards(a, n_dev, n_pad_total))
     return out
 
 
@@ -158,10 +191,11 @@ def _padded_hash_inputs(cols, pad: int):
     count BEFORE hashing: the hash is elementwise, so padding changes nothing
     for the real rows, and the program traces ONE shape per pow2 class
     instead of one per exact table size. String columns ride their dictionary
-    codes (pad code 0 = a valid in-range index; the pad rows are dropped by
-    the exchange's validity lane anyway), narrowed to the dictionary's width
-    when the encoded-device path is on — the hash gathers dh_table[codes],
-    so the hash values are identical from narrow lanes."""
+    codes (pad code 0 = a valid in-range index; the pad rows' hashes are
+    sliced off before the wire lanes are laid out), narrowed to the
+    dictionary's width when the encoded-device path is on — the hash
+    gathers dh_table[codes], so the hash values are identical from narrow
+    lanes."""
     from ..engine.encoded_device import narrow_codes, narrowable
 
     return [
@@ -221,6 +255,9 @@ def distributed_bucketize_table(
 
     coded = encoded_device_enabled()
     with StageTimings.timed_or_not(stages, "h2d") as sp:
+        # Every lane in the even layout (`_even_shards`): the real rows spread
+        # over the shards, each shard's pads (valid 0) at its end.
+        h1_np = h1_np[:n]
         if coded:
             # Code-space wire lanes: the narrow (h1 % num_buckets) lane replaces
             # the uint32 hash, validity rides int8, row ids int32 when the
@@ -228,12 +265,17 @@ def distributed_bucketize_table(
             # Every lane carries the SAME VALUES as the flat path, so the
             # exchange permutation — and the index files — are byte-identical;
             # only `parallel.exchange.bytes_moved` shrinks.
-            lead = (h1_np % np.uint32(num_buckets)).astype(
-                _bucket_lane_dtype(num_buckets)
+            lead = _even_shards(
+                (h1_np % np.uint32(num_buckets)).astype(_bucket_lane_dtype(num_buckets)),
+                n_dev,
+                n_pad_total,
             )
-            valid_p = np.ones(n + pad, np.int8)
-            rowid_p = _pad_rows(np.arange(n, dtype=_coded_rowid_dtype(n_pad_total)), pad)
-            keys_p = _sort_key_arrays(table, bucket_columns, pad, narrow=True)
+            valid_p, rowid_p = _wire_rows(
+                n, n_dev, n_pad_total, np.int8, _coded_rowid_dtype(n_pad_total)
+            )
+            keys_p = _sort_key_arrays(
+                table, bucket_columns, n_dev, n_pad_total, narrow=True
+            )
             flat_keys = [
                 np.int32 if c.data.dtype == np.bool_ else c.data.dtype for c in cols
             ]
@@ -247,11 +289,9 @@ def distributed_bucketize_table(
                 packed_spec=packed_spec or None,
             )
         else:
-            lead = h1_np
-            valid_p = np.ones(n + pad, np.int32)
-            rowid_p = _pad_rows(np.arange(n, dtype=np.int64), pad)
-            keys_p = _sort_key_arrays(table, bucket_columns, pad)
-        valid_p[n:] = 0
+            lead = _even_shards(h1_np, n_dev, n_pad_total)
+            valid_p, rowid_p = _wire_rows(n, n_dev, n_pad_total, np.int32, np.int64)
+            keys_p = _sort_key_arrays(table, bucket_columns, n_dev, n_pad_total)
         host = [lead, valid_p, rowid_p, *keys_p]
         sh = row_sharding(mesh)
         lead_d, valid_d, rowid_d, *keys_d = jax.block_until_ready(
@@ -322,8 +362,9 @@ def distributed_exchange_table(
     n_pad_total = quantized_rows(n, n_dev)
     pad = n_pad_total - n
     arrs_p = _padded_hash_inputs(cols, pad)
-    h1_np = np.asarray(combined_hash_u32(cols, arrs_p, _SEED1))
-    k64_p = np.asarray(key64(cols, arrs_p))
+    h1_np = np.asarray(combined_hash_u32(cols, arrs_p, _SEED1))[:n]
+    # The wire lanes in the build's even layout (`_even_shards`).
+    k64_p = _even_shards(np.asarray(key64(cols, arrs_p))[:n], n_dev, n_pad_total)
 
     sh = row_sharding(mesh)
 
@@ -337,12 +378,14 @@ def distributed_exchange_table(
         # hash, int8 validity, int32 row ids when they fit — and the k64
         # payload lane DOUBLES as the sort key (`sort_from_payload`), so it
         # crosses the interconnect once instead of twice.
-        bucket_np = (h1_np % np.uint32(num_partitions)).astype(
-            _bucket_lane_dtype(num_partitions)
+        bucket_np = _even_shards(
+            (h1_np % np.uint32(num_partitions)).astype(_bucket_lane_dtype(num_partitions)),
+            n_dev,
+            n_pad_total,
         )
-        valid_p = np.ones(n + pad, np.int8)
-        valid_p[n:] = 0
-        rowid_p = _pad_rows(np.arange(n, dtype=_coded_rowid_dtype(n_pad_total)), pad)
+        valid_p, rowid_p = _wire_rows(
+            n, n_dev, n_pad_total, np.int8, _coded_rowid_dtype(n_pad_total)
+        )
         # Spec covers (bucket, valid, rowid); the k64 payload lane appends
         # unpacked — 64-bit hashes have no narrower wire class.
         packed_spec = _packed_wire_spec(num_partitions, bucket_np, n_pad_total, rowid_p)
@@ -366,13 +409,10 @@ def distributed_exchange_table(
             packed_spec=packed_spec,
         )
     else:
-        valid_p = np.ones(n + pad, np.int32)
-        valid_p[n:] = 0
-        rowid_p = _pad_rows(np.arange(n, dtype=np.int64), pad)
-
+        valid_p, rowid_p = _wire_rows(n, n_dev, n_pad_total, np.int32, np.int64)
         bucket, out_valid, (rowid_out, k64_out) = distributed_bucketize(
             mesh,
-            put(h1_np),
+            put(_even_shards(h1_np, n_dev, n_pad_total)),
             [put(rowid_p), put(k64_p)],
             [put(k64_p)],
             num_partitions,

@@ -134,3 +134,112 @@ class TestDistributedJoin:
         ).lower(h1, pay)
         hlo = lowered.compile().as_text()
         assert "all-to-all" in hlo
+
+
+class TestPadRowsTakeNoSlot:
+    """The caller's pad rows (validity 0) neither count toward the exchange
+    capacity nor cross the wire, and the real rows are spread evenly over the
+    shards (`table_ops._even_shards`)."""
+
+    N_DEV, NUM_BUCKETS, N = 8, 32, 600  # 75 real rows a shard, 53 pads each
+
+    @pytest.mark.parametrize(
+        "path", [{"HYPERSPACE_ENCODED_DEVICE": "0"},
+                 {"HYPERSPACE_ENCODED_DEVICE": "1", "HYPERSPACE_PACKED_CODES": "1"}],
+        ids=["flat", "packed"],
+    )
+    def test_pads_neither_size_the_cap_nor_arrive(self, mesh, monkeypatch, path):
+        from hyperspace_tpu.engine.table import Column, Table
+        from hyperspace_tpu.ops.hashing import _SEED1, column_hash_u32
+        from hyperspace_tpu.ops.partition import bucketize_table
+        from hyperspace_tpu.parallel import table_ops
+        from hyperspace_tpu.parallel.mesh import ENV_ROW_QUANTUM, quantize_cap, quantized_rows
+        from hyperspace_tpu.telemetry import tracing
+        from hyperspace_tpu.telemetry.profiling import StageTimings
+
+        # A small quantum lets the capacity tell 600 real rows from their pads
+        # (32: the packed 1-bit validity lane needs whole words per destination).
+        monkeypatch.setenv(ENV_ROW_QUANTUM, "32")
+        for k, v in path.items():
+            monkeypatch.setenv(k, v)
+        n_dev, nb, n = self.N_DEV, self.NUM_BUCKETS, self.N
+        keys = np.random.RandomState(5).randint(1, 10_000, n).astype(np.int64)
+        t = Table({"k": Column.from_values(keys), "rid": Column.from_values(np.arange(n))})
+
+        # Mark every pad row's id -1 on its way in, so an arriving pad shows.
+        name = "distributed_bucketize_coded" if len(path) > 1 else "distributed_bucketize"
+        real, seen = getattr(table_ops, name), {}
+
+        def spy(mesh_, lead, payload, keys_, nb_, in_valid, **kw):
+            payload = [jnp.where(in_valid != 0, payload[0], -1)]
+            seen["spec"] = kw.get("packed_spec")
+            seen["out"] = real(mesh_, lead, payload, keys_, nb_, in_valid=in_valid, **kw)
+            return seen["out"]
+
+        monkeypatch.setattr(table_ops, name, spy)
+        with tracing.capture() as cap, tracing.query_span("build:create_index"):
+            with StageTimings("build", "mesh") as st:
+                out, starts = table_ops.distributed_bucketize_table(
+                    mesh, t, ["k"], nb, stages=st
+                )
+                st.summary()
+        (counts_sp,) = cap.trace.find("build:exchange_counts")
+        assert bool(seen["spec"]) == (name == "distributed_bucketize_coded")
+
+        # The count matrix from the same hashes, in numpy: shard d sends
+        # real rows [d*q, (d+1)*q); every pad (key 0) hashes to one bucket.
+        def dest_of(k):
+            h1 = np.asarray(column_hash_u32(Column.from_values(k), jnp.asarray(k), _SEED1))
+            return (h1 % nb).astype(np.int64) * n_dev // nb
+
+        dest, pad_dest = dest_of(keys), int(dest_of(np.zeros(1, np.int64))[0])
+        q = -(-n // n_dev)
+        shard = quantized_rows(n, n_dev) // n_dev
+        valid_counts = np.stack(
+            [np.bincount(dest[d * q:(d + 1) * q], minlength=n_dev) for d in range(n_dev)]
+        )
+        with_pads = valid_counts.copy()
+        with_pads[:, pad_dest] += shard - valid_counts.sum(axis=1)
+        assert (shard - q) * n_dev > n // n_dev  # more pads than real rows a destination
+        assert counts_sp.attrs["max_count"] == valid_counts.max()
+        assert counts_sp.attrs["shard_rows"] == q
+        assert counts_sp.attrs["cap"] == quantize_cap(valid_counts.max())
+        assert counts_sp.attrs["cap"] < quantize_cap(with_pads.max())
+
+        # Every valid row arrives exactly once; empty slots read 0 and no
+        # pad (id -1) arrives.
+        _, valid, (rowid,) = seen["out"]
+        valid = np.asarray(valid).reshape(-1).astype(bool)
+        rowid = np.asarray(rowid).reshape(-1)
+        assert valid.size == n_dev * n_dev * counts_sp.attrs["cap"]
+        assert sorted(rowid[valid].tolist()) == list(range(n))
+        assert (rowid[~valid] == 0).all()
+
+        # And the output is the single-device build's order.
+        single, single_starts = bucketize_table(t, ["k"], nb)
+        assert (out.column("rid").data == single.column("rid").data).all()
+        assert (np.asarray(starts) == np.asarray(single_starts)).all()
+
+
+class TestEvenShards:
+    @pytest.mark.parametrize("n", [0, 3, 7, 8, 600, 1001, 4096])
+    def test_real_rows_spread_evenly_in_order(self, n):
+        from hyperspace_tpu.parallel.mesh import quantized_rows
+        from hyperspace_tpu.parallel.table_ops import _even_shards, _wire_rows
+
+        n_dev = 8
+        n_pad_total = quantized_rows(n, n_dev)
+        valid, rowid = _wire_rows(n, n_dev, n_pad_total, np.int8, np.int32)
+        lane = _even_shards(np.arange(1, n + 1), n_dev, n_pad_total)
+        assert valid.shape == rowid.shape == lane.shape == (n_pad_total,)
+        valid = valid.reshape(n_dev, -1).astype(bool)
+        per_shard = valid.sum(axis=1)
+        assert per_shard.sum() == n
+        assert per_shard.max() <= max(1, -(-n // n_dev))
+        # Real rows lead each shard, in shard order: the concatenation is the
+        # original row order, and every pad slot reads 0.
+        for d in range(n_dev):
+            assert valid[d, : per_shard[d]].all() and not valid[d, per_shard[d]:].any()
+        assert (rowid.reshape(n_dev, -1)[valid] == np.arange(n)).all()
+        assert (lane.reshape(n_dev, -1)[valid] == np.arange(1, n + 1)).all()
+        assert (lane.reshape(n_dev, -1)[~valid] == 0).all()

@@ -165,6 +165,28 @@ class TestDistributedFlagContract:
         assert list(hm) == list(hl)
         assert hm == {f.replace("local", "mesh"): h for f, h in hl.items()}
 
+    @pytest.mark.parametrize("n", [3001, 5])
+    def test_uneven_shard_build_byte_identical(self, tmp_path, monkeypatch, n):
+        """Row counts the mesh does not divide, and fewer rows than devices:
+        the real rows spread unevenly by at most one a shard, and the files
+        are still the single-device build's bytes."""
+        monkeypatch.setenv("HYPERSPACE_DISTRIBUTED", "1")
+        s = _session(tmp_path)
+        base = str(tmp_path)
+        _write_pair(s, base, n)
+        hs = Hyperspace(s)
+        df = s.read.parquet(os.path.join(base, "dept"))
+        snap0 = metrics.snapshot()["counters"]
+        hs.create_index(df, IndexConfig("meshOdd", ["k"], ["name"]))
+        snap = metrics.snapshot()["counters"]
+        assert snap["parallel.exchange.rows"] - snap0.get("parallel.exchange.rows", 0) == n
+        monkeypatch.setenv("HYPERSPACE_DISTRIBUTED", "0")
+        hs.create_index(df, IndexConfig("localOdd", ["k"], ["name"]))
+        hm = _dir_hashes(os.path.join(base, "indexes", "meshOdd", "v__=0"))
+        hl = _dir_hashes(os.path.join(base, "indexes", "localOdd", "v__=0"))
+        assert hm and list(hm) == list(hl)
+        assert hm == {f.replace("local", "mesh"): h for f, h in hl.items()}
+
     def test_string_key_build_byte_identical(self, tmp_path, monkeypatch):
         monkeypatch.setenv("HYPERSPACE_DISTRIBUTED", "1")
         s = _session(tmp_path)

@@ -553,6 +553,7 @@ def test_mesh_build_records_exchange_spans(session, tmp_path, monkeypatch):
     assert spans["h2d"]["bytes"] > 0 and spans["gather"]["bytes"] > 0
     counts, exchange = spans["exchange_counts"], spans["exchange"]
     assert 0 < counts["max_count"] <= counts["cap"] == exchange["cap"]
+    assert counts["shard_rows"] == -(-SRC_ROWS // 8)  # the real rows spread evenly
     assert exchange["devices"] == 8
     assert delta["parallel.exchange.count"] == 1
     assert exchange["bytes_payload"] == delta["parallel.exchange.bytes_payload"] > 0
