@@ -3204,13 +3204,14 @@ def run_mesh_bench() -> dict:
             return int(c1.get(key, 0) - c0.get(key, 0))
 
         # -- device-resident codes over the mesh wire -----------------------
-        # String-key builds with HYPERSPACE_ENCODED_DEVICE on vs off: the
-        # coded exchange sends (narrow bucket + int8 validity + int32 rowid +
-        # int8 codes) where the flat one sends (u32 hash + i32 validity + i64
-        # rowid + i32 codes). Runs AFTER the compile-once asserts — the coded
-        # and string-sort shapes are their own compile classes, outside the
-        # int-key workload those asserts pin. `_finish` folds this dict into
-        # `bench_detail.encoded_device` next to the H2D half.
+        # String-key builds with HYPERSPACE_ENCODED_DEVICE on vs off. The
+        # exchange sends the same narrow lanes (narrow bucket + int8 validity
+        # + int32 rowid + int8 codes) either way; off turns the auto
+        # HYPERSPACE_PACKED_CODES off, so the ratio is packing's share. Runs
+        # AFTER the compile-once asserts — the string-sort shapes are their
+        # own compile classes, outside the int-key workload those asserts
+        # pin. `_finish` folds this dict into `bench_detail.encoded_device`
+        # next to the H2D half.
         n_enc = int(os.environ.get("BENCH_ENCODED_DEVICE_ROWS", 300_000))
         card = 100  # int8 code class
         dictionary = np.asarray([f"sku#{i:04d}" for i in range(card)])

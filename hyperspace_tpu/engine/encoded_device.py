@@ -222,3 +222,31 @@ def stage_aligned(arr: np.ndarray, col, site: str):
     return device_array(
         narrow, site=site, flat_bytes=int(arr.nbytes), charged_bytes=charged
     )
+
+
+def record_mesh_wire_stage(n_rows: int, flat_lanes, lanes, packed_spec=None) -> None:
+    """Encoded-vs-flat ledger entry (site ``mesh_exchange``) for one mesh
+    exchange's wire lanes: what full-width lanes (`flat_lanes` dtypes) would
+    have staged vs what the narrow `lanes` stage. Lanes marked in
+    `packed_spec` (aligned with `lanes`) cross bit-packed — their staged cost
+    is true bits-on-the-wire, surfaced in the ledger's `packed` tier. The
+    exchange ships the same lanes whatever this layer's switch says; the
+    entry is booked only while the layer is on, as its other sites are."""
+    if not encoded_device_enabled():
+        return
+    from ..telemetry import device_observatory as _devobs
+
+    flat = sum(n_rows * int(np.dtype(d).itemsize) for d in flat_lanes)
+    staged = 0
+    packed = 0
+    for i, a in enumerate(lanes):
+        bits = packed_spec[i][0] if packed_spec is not None else 0
+        if bits:
+            lane_bytes = -(-n_rows * bits // 8)  # ceil(n_rows*bits/8)
+            packed += lane_bytes
+        else:
+            lane_bytes = n_rows * int(a.dtype.itemsize)
+        staged += lane_bytes
+    _devobs.record_encoded_stage(
+        "mesh_exchange", flat, staged, packed_bytes=packed if packed else None
+    )

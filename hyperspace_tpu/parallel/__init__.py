@@ -11,8 +11,6 @@ from .mesh import (  # noqa: F401
 from .distributed import (  # noqa: F401
     distributed_bucketed_join_counts,
     distributed_bucketize,
-    exchange_counts,
-    exchange_rows,
 )
 from .table_ops import (  # noqa: F401
     distributed_bucketed_join_pairs,

@@ -23,7 +23,8 @@ through `observed_jit` with a `parallel.*` label, and every shape it traces is
 pow2-quantized — callers pad row counts to `mesh.quantized_rows` and the
 exchange capacity is floored at the mesh row quantum — so each program
 compiles EXACTLY ONCE per process per workload class, verified by the compile
-observatory (`tests/test_mesh_compile.py`, `bench_detail.mesh`). The ordering
+observatory (`tests/test_mesh_compile.py`; the benchmark's `window_compiles`
+reading counts persistent-cache misses inside its measured window). The ordering
 contract of the receive-side sort is the engine's canonical build order:
 stable (bucket, sort keys...) with ties broken by ORIGINAL global row id —
 identical to `ops.partition.host_sort_perm`/`_sort_perm`, which is what makes
@@ -33,7 +34,7 @@ mesh-built index files byte-identical to single-device ones.
 from __future__ import annotations
 
 from functools import lru_cache
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,26 +47,22 @@ from ..telemetry.compile_log import observed_jit as _observed_jit
 from ..telemetry.profiling import StageTimings
 from .mesh import BUCKET_AXIS, quantize_cap
 
-_PAD_SLOT = -1
 #: Packed wire lanes ride uint32 words (`engine/packed_codes.py` layout).
 _WORD_BITS = 32
 
 #: All-to-all traffic accounting (ticked once per exchange on the host):
 #: payload = real row bytes moved, moved = the padded send-matrix bytes the
 #: interconnect actually carries. The gap between them is the static-shape
-#: padding tax — `bench_detail.mesh` reports both. `pad_rows` counts the
-#: rows the caller padded the input with (validity 0); the count pass skips
-#: them and the exchange gives them no send slot.
+#: padding tax: the `build:exchange` span carries both, and the benchmark's
+#: `exchange_padding` reading is 100 × (moved − payload) / moved over its
+#: window's counter deltas. `pad_rows` counts the rows the caller padded the
+#: input with (validity 0); the count pass skips them and the exchange gives
+#: them no send slot.
 _EXCHANGE_ROWS = _metrics.counter("parallel.exchange.rows")
 _EXCHANGE_PAD_ROWS = _metrics.counter("parallel.exchange.pad_rows")
 _EXCHANGE_BYTES_PAYLOAD = _metrics.counter("parallel.exchange.bytes_payload")
 _EXCHANGE_BYTES_MOVED = _metrics.counter("parallel.exchange.bytes_moved")
 _EXCHANGES = _metrics.counter("parallel.exchange.count")
-
-
-def _dest_of(h1, num_buckets: int, n_dev: int):
-    bucket = (h1 % jnp.uint32(num_buckets)).astype(jnp.int32)
-    return bucket * n_dev // num_buckets, bucket
 
 
 def _valid_counts(dest, valid, n_dev: int):
@@ -86,87 +83,6 @@ def _send_slots(dest, valid, n_dev: int):
     starts = jnp.searchsorted(dest_s, jnp.arange(n_dev + 1))
     slot = jnp.arange(dest.shape[0]) - starts[dest_s]
     return order, dest_s, slot
-
-
-# Program factories are lru_cached so repeated exchanges (every distributed build
-# and every exchanged join in a process) hit jax's compiled-computation cache
-# instead of re-tracing a fresh shard_map closure per call.
-
-
-@lru_cache(maxsize=128)
-def _counts_program(mesh: Mesh, num_buckets: int):
-    n_dev = mesh.devices.size
-
-    def count_fn(h1_local, valid_local):
-        dest, _ = _dest_of(h1_local, num_buckets, n_dev)
-        return _valid_counts(dest, valid_local, n_dev)
-
-    return _observed_jit(
-        jax.shard_map(
-            count_fn,
-            mesh=mesh,
-            in_specs=(P(BUCKET_AXIS), P(BUCKET_AXIS)),
-            out_specs=P(BUCKET_AXIS),
-        ),
-        label="parallel.exchange_counts",
-    )
-
-
-def exchange_counts(mesh: Mesh, h1, num_buckets: int, valid) -> np.ndarray:
-    """Pass 1: [n_dev, n_dev] matrix of valid rows device i sends to device j."""
-    return np.asarray(_counts_program(mesh, num_buckets)(h1, valid))
-
-
-@lru_cache(maxsize=128)
-def _exchange_program(mesh: Mesh, num_buckets: int, cap: int):
-    n_dev = mesh.devices.size
-
-    def fn(h1_local, valid_local, payload_local, keys_local):
-        dest, _ = _dest_of(h1_local, num_buckets, n_dev)
-        order, dest_s, slot = _send_slots(dest, valid_local, n_dev)
-
-        def scatter(col):
-            send = jnp.zeros((n_dev, cap), dtype=col.dtype)
-            send = send.at[dest_s, slot].set(col[order], mode="drop")
-            return jax.lax.all_to_all(
-                send, BUCKET_AXIS, split_axis=0, concat_axis=0, tiled=False
-            )
-
-        # Validity travels as its own lane.
-        valid_recv = scatter(valid_local)
-
-        h1_recv = scatter(h1_local)
-        payload_recv = [scatter(c) for c in payload_local]
-        keys_recv = [scatter(c) for c in keys_local]
-
-        # Local sort: invalid rows last, then by (bucket, sort keys...). The
-        # final iota operand breaks ties by receive position = (sender id,
-        # sender-local order) = ORIGINAL GLOBAL ROW ORDER — the canonical
-        # stable build order every other build path produces.
-        flat_valid = valid_recv.reshape(-1)
-        bucket = (h1_recv.reshape(-1) % jnp.uint32(num_buckets)).astype(jnp.int32)
-        sort_operands = (
-            1 - flat_valid,
-            bucket,
-            *[k.reshape(-1) for k in keys_recv],
-            jnp.arange(flat_valid.shape[0], dtype=jnp.int32),
-        )
-        res = jax.lax.sort(sort_operands, num_keys=2 + len(keys_recv))
-        perm = res[-1]
-        out_bucket = bucket[perm][None]
-        out_valid = flat_valid[perm][None]
-        out_payload = [c.reshape(-1)[perm][None] for c in payload_recv]
-        return out_bucket, out_valid, out_payload
-
-    return _observed_jit(
-        jax.shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=(P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS)),
-            out_specs=(P(BUCKET_AXIS), P(BUCKET_AXIS), P(BUCKET_AXIS)),
-        ),
-        label="parallel.exchange",
-    )
 
 
 def _record_exchange(
@@ -203,30 +119,14 @@ def _record_exchange(
     return payload, moved
 
 
-def _run_exchange(
-    stages: Optional[StageTimings], n_dev: int, cap: int, traffic, program, *args
-):
-    """The exchange program on every device, to its outputs being ready: the
-    index build's ``exchange`` stage. `traffic` is (payload, moved) bytes
-    from `_record_exchange`, or None for an unaccounted call."""
-    with StageTimings.timed_or_not(stages, "exchange") as sp:
-        out = jax.block_until_ready(program(*args))
-        if sp.recording:
-            sp.add_attrs(devices=n_dev, cap=cap)
-            if traffic is not None:
-                sp.add_attrs(bytes_payload=traffic[0], bytes_moved=traffic[1])
-    return out
-
-
 def _exchange_cap(
-    stages: Optional[StageTimings], count, mesh: Mesh, lead, valid, num_buckets: int
+    stages: Optional[StageTimings], mesh: Mesh, bucket, valid, num_buckets: int
 ) -> int:
-    """Pass 1 (`count`: `exchange_counts` or `exchange_counts_coded`) and the
-    per-destination capacity, the largest count of valid rows quantized: the
-    index build's ``exchange_counts`` stage, whose ``shard_rows`` is the real
-    rows on the fullest sending shard."""
+    """Pass 1 and the per-destination capacity, the largest count of valid
+    rows quantized: the index build's ``exchange_counts`` stage, whose
+    ``shard_rows`` is the real rows on the fullest sending shard."""
     with StageTimings.timed_or_not(stages, "exchange_counts") as sp:
-        counts = count(mesh, lead, num_buckets, valid)
+        counts = exchange_counts(mesh, bucket, num_buckets, valid)
         max_count = int(counts.max()) if counts.size else 0
         # Quantize to the mesh row quantum's power-of-two grid so repeated
         # builds of growing data reuse ONE compiled exchange instead of
@@ -238,101 +138,13 @@ def _exchange_cap(
     return cap
 
 
-def exchange_rows(
-    mesh: Mesh,
-    h1,
-    payload: Sequence[jnp.ndarray],
-    sort_keys: Sequence[jnp.ndarray],
-    num_buckets: int,
-    cap: int,
-    in_valid=None,
-    n_valid=None,
-    stages: Optional[StageTimings] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray, List[jnp.ndarray]]:
-    """Pass 2: all-to-all exchange + local in-bucket sort.
-
-    `in_valid` (optional, int32 0/1 per row, sharded like `h1`) marks padding rows
-    added by the caller to make the global row count divisible by the mesh size;
-    they take no send slot, so `cap` need only hold the valid rows.
-    `n_valid` is the real (un-padded) row count for the traffic counters; the
-    table-level callers pass it, and a plain unpadded call infers it from the
-    shape — never from a device sync, so tracing/lowering this function stays
-    legal. `stages` is the index build's, which times this pass as its
-    ``exchange`` stage.
-
-    Returns (bucket_ids [n_dev*cap], valid mask, payload arrays), each sharded over
-    the mesh: device d's block holds its bucket range, valid rows sorted by
-    (bucket, sort_keys...) and grouped before padding."""
-    # The whole call is the ``exchange`` stage for attribution: the pad
-    # ledger tick in _record_exchange and the exchange program's device time
-    # bill the mesh lane, not whichever stage submitted the bucketize.
-    with _stage_ledger.stage_scope("exchange"):
-        n_dev = mesh.devices.size
-        if in_valid is None:
-            in_valid = jnp.ones(h1.shape, dtype=jnp.int32)
-            if n_valid is None:
-                n_valid = int(h1.shape[0])
-        traffic = None
-        if n_valid is not None:
-            traffic = _record_exchange(
-                n_valid, n_dev, cap, [h1, in_valid, *payload, *sort_keys]
-            )
-        return _run_exchange(
-            stages,
-            n_dev,
-            cap,
-            traffic,
-            _exchange_program(mesh, num_buckets, cap),
-            h1,
-            in_valid,
-            list(payload),
-            list(sort_keys),
-        )
-
-
-def distributed_bucketize(
-    mesh: Mesh,
-    h1,
-    payload: Sequence[jnp.ndarray],
-    sort_keys: Sequence[jnp.ndarray],
-    num_buckets: int,
-    in_valid=None,
-    n_valid=None,
-    stages: Optional[StageTimings] = None,
-):
-    """Full two-pass distributed bucketize. Rows arrive sharded over the mesh; the
-    result is (bucket_ids, valid, payload) blocks, one bucket range per device.
-    `stages` (the index build's) times the two passes as its
-    ``exchange_counts`` and ``exchange`` stages."""
-    if in_valid is None:
-        in_valid = jnp.ones(h1.shape, dtype=jnp.int32)
-        if n_valid is None:
-            n_valid = int(h1.shape[0])
-    cap = _exchange_cap(stages, exchange_counts, mesh, h1, in_valid, num_buckets)
-    return exchange_rows(
-        mesh, h1, payload, sort_keys, num_buckets, cap, in_valid, n_valid=n_valid,
-        stages=stages,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Code-space exchange (HYPERSPACE_ENCODED_DEVICE): same two-pass shape, but
-# the wire lanes are narrowed — the caller ships a pre-computed bucket lane in
-# the smallest width num_buckets fits (instead of the uint32 hash), an int8
-# validity lane, an int32 row id when the global row count allows, and
-# dictionary codes narrowed to the dictionary's width. Every sort operand
-# carries the SAME VALUES as the flat path (narrowing is value-preserving and
-# bucket/dest are computed from the identical h1 % num_buckets), so the
-# receive-side permutation — and therefore the index files and join outputs —
-# are byte-identical in both flag states; only `parallel.exchange.bytes_moved`
-# shrinks. Both programs keep their flat twins' observability labels: the
-# compile-per-class contract is about the label's compile COUNT per workload
-# class, and a process runs one staging mode per class.
-# ---------------------------------------------------------------------------
+# Program factories are lru_cached so repeated exchanges (every distributed build
+# and every exchanged join in a process) hit jax's compiled-computation cache
+# instead of re-tracing a fresh shard_map closure per call.
 
 
 @lru_cache(maxsize=128)
-def _counts_coded_program(mesh: Mesh, num_buckets: int):
+def _counts_program(mesh: Mesh, num_buckets: int):
     n_dev = mesh.devices.size
 
     def count_fn(bucket_local, valid_local):
@@ -350,9 +162,9 @@ def _counts_coded_program(mesh: Mesh, num_buckets: int):
     )
 
 
-def exchange_counts_coded(mesh: Mesh, bucket, num_buckets: int, valid) -> np.ndarray:
-    """Pass 1 over a pre-computed (narrow) bucket-id lane."""
-    return np.asarray(_counts_coded_program(mesh, num_buckets)(bucket, valid))
+def exchange_counts(mesh: Mesh, bucket, num_buckets: int, valid) -> np.ndarray:
+    """Pass 1: [n_dev, n_dev] matrix of valid rows device i sends to device j."""
+    return np.asarray(_counts_program(mesh, num_buckets)(bucket, valid))
 
 
 def _pack_wire(send, bits: int):
@@ -373,16 +185,17 @@ def _unpack_wire(words, bits: int, dtype):
 
 
 @lru_cache(maxsize=128)
-def _exchange_coded_program(
+def _exchange_program(
     mesh: Mesh,
     num_buckets: int,
     cap: int,
     sort_from_payload: tuple,
     packed_spec: tuple = (),
 ):
-    """Coded twin of `_exchange_program`: input lanes arrive narrow, and sort
-    keys may be REFERENCED from payload lanes (`sort_from_payload` indexes)
-    instead of shipped twice — the k64 of the exchanged join travels once.
+    """Pass 2: the all_to_all exchange and the local in-bucket sort. Input
+    lanes arrive narrow, and sort keys may be REFERENCED from payload lanes
+    (`sort_from_payload` indexes) instead of shipped twice — the k64 of the
+    exchanged join travels once.
 
     `packed_spec` (static, folded into the program cache key like `cap`) is a
     per-lane (bits, bias) tuple aligned with (bucket, valid, *payload, *keys);
@@ -391,8 +204,13 @@ def _exchange_coded_program(
     AFTER the destination scatter, crosses the all_to_all as uint32 words,
     and unpacks back to the identical [n_dev, cap] matrix on the receive
     side — pad slots scatter as 0, pack as the bias value, and unpack back
-    to 0, so every downstream operand is value-identical to the unpacked
-    program and the receive-side sort permutation cannot move."""
+    to 0, so every downstream operand is value-identical to an unpacked
+    exchange and the receive-side sort permutation cannot move.
+
+    The local sort puts invalid rows last, then orders by (bucket, sort
+    keys...); the final iota operand breaks ties by receive position =
+    (sender id, sender-local order) = ORIGINAL GLOBAL ROW ORDER — the
+    canonical stable build order every other build path produces."""
     n_dev = mesh.devices.size
     for bits, _bias in packed_spec:
         if bits:
@@ -431,9 +249,9 @@ def _exchange_coded_program(
         payload_recv = [scatter(c, _spec(2 + i)) for i, c in enumerate(payload_local)]
         keys_recv = [scatter(c, _spec(2 + n_pay + i)) for i, c in enumerate(keys_local)]
 
-        # Receive-side widening is free (post-wire); the sort operand VALUES
-        # match the flat program's exactly, so the permutation — and with it
-        # the canonical stable build order — is identical.
+        # Receive-side widening is free (post-wire); narrowing kept every
+        # value, so the sort operands order the rows as the full-width
+        # values would.
         flat_valid = valid_recv.reshape(-1).astype(jnp.int32)
         bucket = bucket_recv.reshape(-1).astype(jnp.int32)
         sort_lanes = [payload_recv[i].reshape(-1) for i in sort_from_payload]
@@ -462,7 +280,7 @@ def _exchange_coded_program(
     )
 
 
-def distributed_bucketize_coded(
+def distributed_bucketize(
     mesh: Mesh,
     bucket,
     payload: Sequence[jnp.ndarray],
@@ -474,18 +292,26 @@ def distributed_bucketize_coded(
     packed_spec: Sequence[Tuple[int, int]] = (),
     stages: Optional[StageTimings] = None,
 ):
-    """Two-pass distributed bucketize over NARROW lanes: `bucket` is the
-    pre-computed (h1 % num_buckets) lane in its smallest width, `in_valid` is
-    int8, and `sort_from_payload` names payload lanes that double as sort
-    keys (so they are not shipped twice). `packed_spec` (optional, aligned
-    (bucket, valid, *payload, *sort_keys)) bit-packs the marked lanes across
-    the all_to_all (`HYPERSPACE_PACKED_CODES`). Output contract (and bytes of
-    the output) match `distributed_bucketize`: int32 bucket ids, int32
-    validity, payload lanes in their input dtypes. `stages` as there."""
+    """Full two-pass distributed bucketize. Rows arrive sharded over the
+    mesh: `bucket` is the pre-computed (h1 % num_buckets) lane in its
+    smallest width, `in_valid` the int8 validity lane (0 marks the caller's
+    pad rows, which take no send slot, so the capacity need only hold the
+    `n_valid` real rows), and `sort_from_payload` names payload lanes that
+    double as sort keys (so they are not shipped twice). `packed_spec`
+    (optional, aligned (bucket, valid, *payload, *sort_keys)) bit-packs the
+    marked lanes across the all_to_all (`HYPERSPACE_PACKED_CODES`).
+
+    Returns (bucket_ids, valid, payload) blocks sharded over the mesh, one
+    bucket range per device: int32 bucket ids, int32 validity and the
+    payload lanes in their input dtypes, valid rows sorted by (bucket,
+    sort keys...) and grouped before padding. `stages` (the index build's)
+    times the two passes as its ``exchange_counts`` and ``exchange``
+    stages."""
+    # The whole call is the ``exchange`` stage for attribution: the pad
+    # ledger tick in _record_exchange and the exchange program's device time
+    # bill the mesh lane, not whichever stage submitted the bucketize.
     with _stage_ledger.stage_scope("exchange"):
-        cap = _exchange_cap(
-            stages, exchange_counts_coded, mesh, bucket, in_valid, num_buckets
-        )
+        cap = _exchange_cap(stages, mesh, bucket, in_valid, num_buckets)
         n_dev = mesh.devices.size
         spec = tuple(tuple(s) for s in packed_spec)
         traffic = _record_exchange(
@@ -495,17 +321,17 @@ def distributed_bucketize_coded(
             [bucket, in_valid, *payload, *sort_keys],
             packed_spec=spec if spec else None,
         )
-        return _run_exchange(
-            stages,
-            n_dev,
-            cap,
-            traffic,
-            _exchange_coded_program(mesh, num_buckets, cap, tuple(sort_from_payload), spec),
-            bucket,
-            in_valid,
-            list(payload),
-            list(sort_keys),
-        )
+        program = _exchange_program(mesh, num_buckets, cap, tuple(sort_from_payload), spec)
+        # The ``exchange`` stage ends when the outputs are ready on every device.
+        with StageTimings.timed_or_not(stages, "exchange") as sp:
+            out = jax.block_until_ready(
+                program(bucket, in_valid, list(payload), list(sort_keys))
+            )
+            if sp.recording:
+                sp.add_attrs(
+                    devices=n_dev, cap=cap, bytes_payload=traffic[0], bytes_moved=traffic[1]
+                )
+        return out
 
 
 # ---------------------------------------------------------------------------

@@ -25,25 +25,29 @@ one shape per workload class, not one per table size — the exact failure mode
 that hung the r05 TPU bench for 2400 s inside `ops/hashing.bucket_id`), and
 the exchange/probe capacities are floored at the mesh row quantum. Each
 `parallel.*` program compiles exactly once per process per class, asserted by
-`tests/test_mesh_compile.py` and reported in `bench_detail.mesh`.
+`tests/test_mesh_compile.py`. A mesh build reports each step as a
+`build:<stage>` span (`hash`, `h2d`, `exchange_counts`, `exchange`, `gather`,
+`take`); the benchmark's `exchange_ms` and `mesh_sort_roofline` read the
+exchange's two, and `exchange_padding` the `parallel.exchange.*` counters.
 """
 
 from __future__ import annotations
 
 from functools import lru_cache
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..engine.encoded_device import narrow_codes, record_mesh_wire_stage
 from ..engine.encoding import column_nbytes
 from ..engine.table import Table
 from ..ops.hashing import _SEED1, combined_hash_u32, key64
 from ..telemetry.compile_log import observed_jit as _observed_jit
 from ..telemetry.profiling import StageTimings
-from .distributed import distributed_bucketize, distributed_bucketize_coded
+from .distributed import distributed_bucketize
 from .mesh import BUCKET_AXIS, quantize_cap, quantized_rows, row_sharding
 
 _PAD = np.iinfo(np.int64).max
@@ -63,30 +67,6 @@ def _coded_rowid_dtype(n_pad_total: int):
     does at realistic per-host scales; the int64 fallback keeps the contract
     total)."""
     return np.int32 if n_pad_total <= np.iinfo(np.int32).max else np.int64
-
-
-def _record_coded_stage(n_rows: int, flat_lanes, coded_lanes, packed_spec=None) -> None:
-    """Encoded-vs-flat ledger entry for one exchange's wire lanes: what the
-    flat itemsizes would have staged vs what the narrow lanes stage. Lanes
-    marked in `packed_spec` (aligned with `coded_lanes`) cross bit-packed —
-    their staged cost is true bits-on-the-wire, surfaced in the ledger's
-    `packed` tier."""
-    from ..telemetry import device_observatory as _devobs
-
-    flat = sum(n_rows * int(np.dtype(d).itemsize) for d in flat_lanes)
-    staged = 0
-    packed = 0
-    for i, a in enumerate(coded_lanes):
-        bits = packed_spec[i][0] if packed_spec is not None else 0
-        if bits:
-            lane_bytes = -(-n_rows * bits // 8)  # ceil(n_rows*bits/8)
-            packed += lane_bytes
-        else:
-            lane_bytes = n_rows * int(a.dtype.itemsize)
-        staged += lane_bytes
-    _devobs.record_encoded_stage(
-        "mesh_exchange", flat, staged, packed_bytes=packed if packed else None
-    )
 
 
 def _packed_wire_spec(
@@ -166,24 +146,15 @@ def _wire_rows(n: int, n_dev: int, n_pad_total: int, valid_dtype, rowid_dtype):
     return valid, rowid
 
 
-def _sort_key_arrays(
-    table: Table, columns: Sequence[str], n_dev: int, n_pad_total: int,
-    narrow: bool = False,
-) -> List[np.ndarray]:
-    from ..engine.encoded_device import narrow_codes, narrowable
-
-    out = []
-    for c in columns:
-        col = table.column(c)
-        a = col.data
-        if narrow and narrowable(col):
-            # Code-space wire lane: narrowing preserves code VALUES, so the
-            # receive-side (bucket, keys..., row) sort orders identically.
-            a = narrow_codes(col)
-        if a.dtype == np.bool_:
-            a = a.astype(np.int32)
-        out.append(_even_shards(a, n_dev, n_pad_total))
-    return out
+def _key_lane(col) -> np.ndarray:
+    """A key column's values as the exchange ships them: a string column
+    rides its dictionary codes, narrowed to the dictionary's width. The hash
+    gathers dh_table[codes] and narrowing keeps every code value, so the
+    hashes and the receive-side (bucket, keys..., row) order are those of
+    the full-width codes."""
+    if col.is_string and col.dictionary is not None:
+        return narrow_codes(col)
+    return col.data
 
 
 def _padded_hash_inputs(cols, pad: int):
@@ -192,22 +163,99 @@ def _padded_hash_inputs(cols, pad: int):
     for the real rows, and the program traces ONE shape per pow2 class
     instead of one per exact table size. String columns ride their dictionary
     codes (pad code 0 = a valid in-range index; the pad rows' hashes are
-    sliced off before the wire lanes are laid out), narrowed to the
-    dictionary's width when the encoded-device path is on — the hash
-    gathers dh_table[codes], so the hash values are identical from narrow
-    lanes."""
-    from ..engine.encoded_device import narrow_codes, narrowable
+    sliced off before the wire lanes are laid out)."""
+    return [jnp.asarray(_pad_rows(_key_lane(c), pad)) for c in cols]
 
-    return [
-        jnp.asarray(
-            _pad_rows(narrow_codes(c) if narrowable(c) else c.data, pad)
+
+def _exchange_keys(
+    mesh: Mesh,
+    cols,
+    n: int,
+    num_buckets: int,
+    key64_lane: bool = False,
+    stages: Optional[StageTimings] = None,
+):
+    """Hash `n` rows' key columns, stage the exchange's wire lanes on the mesh
+    and run the exchange (`distributed_bucketize`). Returns its (bucket ids,
+    validity, payload) blocks; payload is (row id,), or (row id, k64) with
+    `key64_lane`.
+
+    Every lane is in the even layout (`_even_shards`) and as narrow as its
+    values allow: the (h1 % num_buckets) bucket lane in the smallest width
+    `num_buckets` fits, int8 validity, int32 row ids when the padded row
+    count fits, string keys as dictionary codes in the dictionary's width.
+    The build ships the key columns as sort lanes; the join (`key64_lane`)
+    ships one 64-bit key lane as payload that doubles as the sort key, so it
+    crosses the interconnect once. `stages` (the index build's) times the
+    hashing as ``hash`` and the lanes built and put on the mesh as ``h2d``."""
+    n_dev = mesh.devices.size
+    n_pad_total = quantized_rows(n, n_dev)
+    pad = n_pad_total - n
+    with StageTimings.timed_or_not(stages, "hash", rows=n, pad_rows=pad):
+        arrs_p = _padded_hash_inputs(cols, pad)
+        h1_np = np.asarray(combined_hash_u32(cols, arrs_p, _SEED1))
+
+    def even(a):
+        return _even_shards(a, n_dev, n_pad_total)
+
+    with StageTimings.timed_or_not(stages, "h2d") as sp:
+        bucket = even(
+            (h1_np[:n] % np.uint32(num_buckets)).astype(_bucket_lane_dtype(num_buckets))
         )
-        for c in cols
-    ]
+        valid, rowid = _wire_rows(
+            n, n_dev, n_pad_total, np.int8, _coded_rowid_dtype(n_pad_total)
+        )
+        if key64_lane:
+            payload = [rowid, even(np.asarray(key64(cols, arrs_p))[:n])]
+            keys = []
+            key_pairs = [(None, payload[1])]
+            flat_keys = [np.int64, np.int64]  # k64 as payload, then as sort key
+            sort_from_payload = (1,)
+        else:
+            payload = [rowid]
+            keys = []
+            for c in cols:
+                a = _key_lane(c)
+                keys.append(even(a.astype(np.int32) if a.dtype == np.bool_ else a))
+            key_pairs = list(zip(cols, keys))
+            flat_keys = [
+                np.int32 if c.data.dtype == np.bool_ else c.data.dtype for c in cols
+            ]
+            sort_from_payload = ()
+        host = [bucket, valid, *payload, *keys]
+        packed_spec = _packed_wire_spec(
+            num_buckets, bucket, n_pad_total, rowid, key_pairs
+        )
+        record_mesh_wire_stage(
+            n_pad_total,
+            [np.uint32, np.int32, np.int64, *flat_keys],
+            host,
+            packed_spec=packed_spec or None,
+        )
+        sh = row_sharding(mesh)
+        lanes = jax.block_until_ready([jax.device_put(jnp.asarray(a), sh) for a in host])
+        if sp.recording:
+            sp.set_attr("bytes", sum(a.nbytes for a in host))
+
+    n_pay = len(payload)
+    return distributed_bucketize(
+        mesh,
+        lanes[0],
+        lanes[2 : 2 + n_pay],
+        lanes[2 + n_pay :],
+        num_buckets,
+        lanes[1],
+        n,
+        sort_from_payload=sort_from_payload,
+        packed_spec=packed_spec,
+        stages=stages,
+    )
 
 
-def _gather_valid_perm(bucket, valid, rowid) -> Tuple[np.ndarray, np.ndarray]:
-    """Host-gather an exchange result into (permutation, bucket ids of valid rows).
+def _gather_valid_perm(
+    bucket, valid, rowid, n: int, num_buckets: int
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Host-gather an exchange result into (permutation, bucket starts).
 
     Device d's block holds its bucket range with valid rows first, sorted by
     (bucket, keys...); blocks are in device order, so the concatenation is globally
@@ -215,7 +263,8 @@ def _gather_valid_perm(bucket, valid, rowid) -> Tuple[np.ndarray, np.ndarray]:
     valid_h = np.asarray(valid).reshape(-1).astype(bool)
     perm = np.asarray(rowid).reshape(-1)[valid_h]
     bucket_v = np.asarray(bucket).reshape(-1)[valid_h]
-    return perm, bucket_v
+    assert len(perm) == n, f"exchange dropped rows: {len(perm)} != {n}"
+    return perm, np.searchsorted(bucket_v, np.arange(num_buckets + 1))
 
 
 def distributed_bucketize_table(
@@ -227,8 +276,8 @@ def distributed_bucketize_table(
 ) -> Tuple[Table, np.ndarray]:
     """Mesh-wide hash-partition + in-bucket sort; drop-in for `bucketize_table`.
 
-    The exchange moves (hash, row id, sort keys) over ICI; the permutation comes
-    back to the host, which materializes the reordered table for the bucketed
+    The exchange moves (bucket id, row id, sort keys) over ICI; the permutation
+    comes back to the host, which materializes the reordered table for the bucketed
     parquet write (index files are host I/O regardless of where the shuffle ran).
     Bucket assignment is identical to the single-device path (h1 % num_buckets over
     the same column hash) AND the within-bucket order is the same canonical
@@ -236,97 +285,17 @@ def distributed_bucketize_table(
     BYTE-IDENTICAL index files — `HYPERSPACE_DISTRIBUTED=0` is an exact
     fallback, pinned by the on/off oracles in tests/test_mesh_compile.py.
 
-    `stages` (the index build's) times each step as a stage: ``hash``, ``h2d``
-    (the wire lanes built and put on the mesh), ``exchange_counts`` and
-    ``exchange`` (`distributed.py`), ``gather`` (the exchange's output copied
-    back into the permutation and bucket starts) and ``take`` (the reordered
-    table)."""
-    n_dev = mesh.devices.size
+    `stages` (the index build's) times each step as a stage: ``hash`` and
+    ``h2d`` (`_exchange_keys`), ``exchange_counts`` and ``exchange``
+    (`distributed.py`), ``gather`` (the exchange's output copied back into
+    the permutation and bucket starts) and ``take`` (the reordered table)."""
     n = table.num_rows
     cols = [table.column(c) for c in bucket_columns]
-
-    n_pad_total = quantized_rows(n, n_dev)
-    pad = n_pad_total - n
-    with StageTimings.timed_or_not(stages, "hash", rows=n, pad_rows=pad):
-        arrs_p = _padded_hash_inputs(cols, pad)
-        h1_np = np.asarray(combined_hash_u32(cols, arrs_p, _SEED1))
-
-    from ..engine.encoded_device import encoded_device_enabled
-
-    coded = encoded_device_enabled()
-    with StageTimings.timed_or_not(stages, "h2d") as sp:
-        # Every lane in the even layout (`_even_shards`): the real rows spread
-        # over the shards, each shard's pads (valid 0) at its end.
-        h1_np = h1_np[:n]
-        if coded:
-            # Code-space wire lanes: the narrow (h1 % num_buckets) lane replaces
-            # the uint32 hash, validity rides int8, row ids int32 when the
-            # padded count fits, and string sort keys travel as narrow codes.
-            # Every lane carries the SAME VALUES as the flat path, so the
-            # exchange permutation — and the index files — are byte-identical;
-            # only `parallel.exchange.bytes_moved` shrinks.
-            lead = _even_shards(
-                (h1_np % np.uint32(num_buckets)).astype(_bucket_lane_dtype(num_buckets)),
-                n_dev,
-                n_pad_total,
-            )
-            valid_p, rowid_p = _wire_rows(
-                n, n_dev, n_pad_total, np.int8, _coded_rowid_dtype(n_pad_total)
-            )
-            keys_p = _sort_key_arrays(
-                table, bucket_columns, n_dev, n_pad_total, narrow=True
-            )
-            flat_keys = [
-                np.int32 if c.data.dtype == np.bool_ else c.data.dtype for c in cols
-            ]
-            packed_spec = _packed_wire_spec(
-                num_buckets, lead, n_pad_total, rowid_p, list(zip(cols, keys_p))
-            )
-            _record_coded_stage(
-                n_pad_total,
-                [np.uint32, np.int32, np.int64, *flat_keys],
-                [lead, valid_p, rowid_p, *keys_p],
-                packed_spec=packed_spec or None,
-            )
-        else:
-            lead = _even_shards(h1_np, n_dev, n_pad_total)
-            valid_p, rowid_p = _wire_rows(n, n_dev, n_pad_total, np.int32, np.int64)
-            keys_p = _sort_key_arrays(table, bucket_columns, n_dev, n_pad_total)
-        host = [lead, valid_p, rowid_p, *keys_p]
-        sh = row_sharding(mesh)
-        lead_d, valid_d, rowid_d, *keys_d = jax.block_until_ready(
-            [jax.device_put(jnp.asarray(a), sh) for a in host]
-        )
-        if sp.recording:
-            sp.set_attr("bytes", sum(a.nbytes for a in host))
-
-    if coded:
-        bucket, out_valid, (rowid_out,) = distributed_bucketize_coded(
-            mesh,
-            lead_d,
-            [rowid_d],
-            keys_d,
-            num_buckets,
-            in_valid=valid_d,
-            n_valid=n,
-            packed_spec=packed_spec,
-            stages=stages,
-        )
-    else:
-        bucket, out_valid, (rowid_out,) = distributed_bucketize(
-            mesh,
-            lead_d,
-            [rowid_d],
-            keys_d,
-            num_buckets,
-            in_valid=valid_d,
-            n_valid=n,
-            stages=stages,
-        )
+    bucket, out_valid, (rowid_out,) = _exchange_keys(
+        mesh, cols, n, num_buckets, stages=stages
+    )
     with StageTimings.timed_or_not(stages, "gather") as sp:
-        perm, bucket_v = _gather_valid_perm(bucket, out_valid, rowid_out)
-        assert len(perm) == n, f"exchange dropped rows: {len(perm)} != {n}"
-        starts = np.searchsorted(bucket_v, np.arange(num_buckets + 1))
+        perm, starts = _gather_valid_perm(bucket, out_valid, rowid_out, n, num_buckets)
         if sp.recording:
             sp.set_attr("bytes", bucket.nbytes + out_valid.nbytes + rowid_out.nbytes)
     with StageTimings.timed_or_not(stages, "take", rows=n, columns=len(table.columns)) as sp:
@@ -358,72 +327,10 @@ def distributed_exchange_table(
     num_partitions = n_dev * partitions_per_device
     n = table.num_rows
     cols = [table.column(c) for c in key_columns]
-
-    n_pad_total = quantized_rows(n, n_dev)
-    pad = n_pad_total - n
-    arrs_p = _padded_hash_inputs(cols, pad)
-    h1_np = np.asarray(combined_hash_u32(cols, arrs_p, _SEED1))[:n]
-    # The wire lanes in the build's even layout (`_even_shards`).
-    k64_p = _even_shards(np.asarray(key64(cols, arrs_p))[:n], n_dev, n_pad_total)
-
-    sh = row_sharding(mesh)
-
-    def put(x):
-        return jax.device_put(jnp.asarray(x), sh)
-
-    from ..engine.encoded_device import encoded_device_enabled
-
-    if encoded_device_enabled():
-        # Code-space exchange: narrow partition lane instead of the uint32
-        # hash, int8 validity, int32 row ids when they fit — and the k64
-        # payload lane DOUBLES as the sort key (`sort_from_payload`), so it
-        # crosses the interconnect once instead of twice.
-        bucket_np = _even_shards(
-            (h1_np % np.uint32(num_partitions)).astype(_bucket_lane_dtype(num_partitions)),
-            n_dev,
-            n_pad_total,
-        )
-        valid_p, rowid_p = _wire_rows(
-            n, n_dev, n_pad_total, np.int8, _coded_rowid_dtype(n_pad_total)
-        )
-        # Spec covers (bucket, valid, rowid); the k64 payload lane appends
-        # unpacked — 64-bit hashes have no narrower wire class.
-        packed_spec = _packed_wire_spec(num_partitions, bucket_np, n_pad_total, rowid_p)
-        if packed_spec:
-            packed_spec = packed_spec + ((0, 0),)
-        _record_coded_stage(
-            n_pad_total,
-            [np.uint32, np.int32, np.int64, np.int64, np.int64],
-            [bucket_np, valid_p, rowid_p, k64_p],
-            packed_spec=packed_spec or None,
-        )
-        bucket, out_valid, (rowid_out, k64_out) = distributed_bucketize_coded(
-            mesh,
-            put(bucket_np),
-            [put(rowid_p), put(k64_p)],
-            [],
-            num_partitions,
-            in_valid=put(valid_p),
-            n_valid=n,
-            sort_from_payload=(1,),
-            packed_spec=packed_spec,
-        )
-    else:
-        valid_p, rowid_p = _wire_rows(n, n_dev, n_pad_total, np.int32, np.int64)
-        bucket, out_valid, (rowid_out, k64_out) = distributed_bucketize(
-            mesh,
-            put(_even_shards(h1_np, n_dev, n_pad_total)),
-            [put(rowid_p), put(k64_p)],
-            [put(k64_p)],
-            num_partitions,
-            in_valid=put(valid_p),
-            n_valid=n,
-        )
-    valid_h = np.asarray(out_valid).reshape(-1).astype(bool)
-    perm = np.asarray(rowid_out).reshape(-1)[valid_h]
-    bucket_v = np.asarray(bucket).reshape(-1)[valid_h]
-    assert len(perm) == n, f"exchange dropped rows: {len(perm)} != {n}"
-    starts = np.searchsorted(bucket_v, np.arange(num_partitions + 1))
+    bucket, out_valid, (rowid_out, k64_out) = _exchange_keys(
+        mesh, cols, n, num_partitions, key64_lane=True
+    )
+    perm, starts = _gather_valid_perm(bucket, out_valid, rowid_out, n, num_partitions)
 
     # Device-resident key blocks for the co-partitioned probe: invalid slots
     # masked to the probe's pad value (sort-last), real keys clipped below it.

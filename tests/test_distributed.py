@@ -24,6 +24,18 @@ def mesh():
     return make_mesh(8)
 
 
+def _bucket_lanes(keys, num_buckets):
+    """The exchange's (bucket, validity) lanes for unpadded int64 keys: the
+    (h1 % num_buckets) bucket lane in its narrow dtype, all-valid int8."""
+    from hyperspace_tpu.engine.table import Column
+    from hyperspace_tpu.ops.hashing import _SEED1, column_hash_u32
+    from hyperspace_tpu.parallel.table_ops import _bucket_lane_dtype
+
+    h1 = np.asarray(column_hash_u32(Column.from_values(keys), jnp.asarray(keys), _SEED1))
+    bucket = (h1 % np.uint32(num_buckets)).astype(_bucket_lane_dtype(num_buckets))
+    return jnp.asarray(bucket), jnp.ones(len(keys), dtype=jnp.int8)
+
+
 class TestDistributedBucketize:
     def test_exchange_preserves_rows_and_bucket_ownership(self, mesh):
         n_dev = 8
@@ -33,14 +45,10 @@ class TestDistributedBucketize:
         keys = rng.randint(0, 10_000, size=n).astype(np.int64)
         payload = np.arange(n, dtype=np.int64)
 
-        from hyperspace_tpu.engine.table import Column
-        from hyperspace_tpu.ops.hashing import _SEED1, column_hash_u32
-
-        kcol = Column.from_values(keys)
-        h1 = column_hash_u32(kcol, jnp.asarray(keys), _SEED1)
-
+        lead, in_valid = _bucket_lanes(keys, num_buckets)
         bucket, valid, (pay_out, keys_out) = distributed_bucketize(
-            mesh, h1, [jnp.asarray(payload), jnp.asarray(keys)], [jnp.asarray(keys)], num_buckets
+            mesh, lead, [jnp.asarray(payload), jnp.asarray(keys)], [jnp.asarray(keys)],
+            num_buckets, in_valid, n,
         )
         # Outputs are [n_dev, n_dev*cap]: one padded block row per device.
         bucket = np.asarray(bucket)
@@ -71,7 +79,6 @@ class TestDistributedBucketize:
         """Same hash function ⇒ distributed bucket assignment agrees with the
         single-device build path."""
         from hyperspace_tpu.engine.table import Column, Table
-        from hyperspace_tpu.ops.hashing import _SEED1, column_hash_u32
         from hyperspace_tpu.ops.partition import bucketize_table
 
         n = 512
@@ -81,10 +88,9 @@ class TestDistributedBucketize:
         sorted_t, starts = bucketize_table(t, ["k"], num_buckets)
         single_sizes = np.diff(starts)
 
-        kcol = Column.from_values(keys)
-        h1 = column_hash_u32(kcol, jnp.asarray(keys), _SEED1)
+        lead, in_valid = _bucket_lanes(keys, num_buckets)
         bucket, valid, _ = distributed_bucketize(
-            mesh, h1, [jnp.asarray(keys)], [jnp.asarray(keys)], num_buckets
+            mesh, lead, [jnp.asarray(keys)], [jnp.asarray(keys)], num_buckets, in_valid, n
         )
         bucket = np.asarray(bucket)[np.asarray(valid).astype(bool)]
         dist_sizes = np.bincount(bucket, minlength=num_buckets)
@@ -124,14 +130,15 @@ class TestDistributedJoin:
 
     def test_build_exchange_does_use_all_to_all(self, mesh):
         """Sanity check on the inverse: the build exchange genuinely communicates."""
-        from hyperspace_tpu.parallel.distributed import exchange_rows
+        from hyperspace_tpu.parallel.distributed import _exchange_program
 
         n = 256
-        h1 = jnp.asarray(np.random.RandomState(2).randint(0, 2**31, n), dtype=jnp.uint32)
+        keys = np.random.RandomState(2).randint(0, 2**31, n).astype(np.int64)
+        lead, in_valid = _bucket_lanes(keys, 16)
         pay = jnp.arange(n, dtype=jnp.int64)
-        lowered = jax.jit(
-            lambda h, p: exchange_rows(mesh, h, [p], [p], 16, 64)
-        ).lower(h1, pay)
+        lowered = jax.jit(_exchange_program(mesh, 16, 64, ())).lower(
+            lead, in_valid, [pay], [pay]
+        )
         hlo = lowered.compile().as_text()
         assert "all-to-all" in hlo
 
@@ -143,12 +150,8 @@ class TestPadRowsTakeNoSlot:
 
     N_DEV, NUM_BUCKETS, N = 8, 32, 600  # 75 real rows a shard, 53 pads each
 
-    @pytest.mark.parametrize(
-        "path", [{"HYPERSPACE_ENCODED_DEVICE": "0"},
-                 {"HYPERSPACE_ENCODED_DEVICE": "1", "HYPERSPACE_PACKED_CODES": "1"}],
-        ids=["flat", "packed"],
-    )
-    def test_pads_neither_size_the_cap_nor_arrive(self, mesh, monkeypatch, path):
+    @pytest.mark.parametrize("packed", ["0", "1"], ids=["packed_off", "packed"])
+    def test_pads_neither_size_the_cap_nor_arrive(self, mesh, monkeypatch, packed):
         from hyperspace_tpu.engine.table import Column, Table
         from hyperspace_tpu.ops.hashing import _SEED1, column_hash_u32
         from hyperspace_tpu.ops.partition import bucketize_table
@@ -160,23 +163,21 @@ class TestPadRowsTakeNoSlot:
         # A small quantum lets the capacity tell 600 real rows from their pads
         # (32: the packed 1-bit validity lane needs whole words per destination).
         monkeypatch.setenv(ENV_ROW_QUANTUM, "32")
-        for k, v in path.items():
-            monkeypatch.setenv(k, v)
+        monkeypatch.setenv("HYPERSPACE_PACKED_CODES", packed)
         n_dev, nb, n = self.N_DEV, self.NUM_BUCKETS, self.N
         keys = np.random.RandomState(5).randint(1, 10_000, n).astype(np.int64)
         t = Table({"k": Column.from_values(keys), "rid": Column.from_values(np.arange(n))})
 
         # Mark every pad row's id -1 on its way in, so an arriving pad shows.
-        name = "distributed_bucketize_coded" if len(path) > 1 else "distributed_bucketize"
-        real, seen = getattr(table_ops, name), {}
+        real, seen = table_ops.distributed_bucketize, {}
 
-        def spy(mesh_, lead, payload, keys_, nb_, in_valid, **kw):
+        def spy(mesh_, lead, payload, keys_, nb_, in_valid, n_valid, **kw):
             payload = [jnp.where(in_valid != 0, payload[0], -1)]
             seen["spec"] = kw.get("packed_spec")
-            seen["out"] = real(mesh_, lead, payload, keys_, nb_, in_valid=in_valid, **kw)
+            seen["out"] = real(mesh_, lead, payload, keys_, nb_, in_valid, n_valid, **kw)
             return seen["out"]
 
-        monkeypatch.setattr(table_ops, name, spy)
+        monkeypatch.setattr(table_ops, "distributed_bucketize", spy)
         with tracing.capture() as cap, tracing.query_span("build:create_index"):
             with StageTimings("build", "mesh") as st:
                 out, starts = table_ops.distributed_bucketize_table(
@@ -184,7 +185,7 @@ class TestPadRowsTakeNoSlot:
                 )
                 st.summary()
         (counts_sp,) = cap.trace.find("build:exchange_counts")
-        assert bool(seen["spec"]) == (name == "distributed_bucketize_coded")
+        assert bool(seen["spec"]) == (packed == "1")
 
         # The count matrix from the same hashes, in numpy: shard d sends
         # real rows [d*q, (d+1)*q); every pad (key 0) hashes to one bucket.

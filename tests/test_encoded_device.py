@@ -284,35 +284,33 @@ class TestMeshCodedExchange:
         assert rows_on == rows_off and len(rows_on) > 0
 
     def test_exchange_bytes_moved_shrinks_2x(self, tmp_path, monkeypatch):
-        """The coded exchange's wire lanes (narrow bucket + int8 validity +
-        int32 row id + int8 codes) move ≥2× fewer bytes than the flat lanes
-        (uint32 hash + int32 validity + int64 row id + int32 codes) for the
-        SAME build."""
+        """The exchange's wire lanes (narrow bucket + int8 validity + int32 row
+        id + int8 codes) move ≥2× fewer bytes than full-width lanes (uint32
+        hash + int32 validity + int64 row id + int32 codes) would at the SAME
+        build's capacity."""
+        import jax
+
+        from hyperspace_tpu.telemetry import tracing
+
         monkeypatch.setenv("HYPERSPACE_DISTRIBUTED", "1")
+        monkeypatch.setenv(ENV, "1")
         s = _session(tmp_path)
         base = str(tmp_path)
         _write_str_pair(s, base, 3000, card=100, seed=9)
         hs = Hyperspace(s)
         f = s.read.parquet(os.path.join(base, "fact"))
 
-        def moved_during(build):
-            before = metrics.counter("parallel.exchange.bytes_moved").value
-            build()
-            return metrics.counter("parallel.exchange.bytes_moved").value - before
-
-        monkeypatch.setenv(ENV, "1")
         _clear_caches()
-        moved_on = moved_during(
-            lambda: hs.create_index(f, IndexConfig("mcIdx", ["sk"], ["val"]))
-        )
-        monkeypatch.setenv(ENV, "0")
-        _clear_caches()
-        moved_off = moved_during(
-            lambda: hs.create_index(f, IndexConfig("mfIdx", ["sk"], ["val"]))
-        )
+        before = metrics.counter("parallel.exchange.bytes_moved").value
+        with tracing.capture() as cap:
+            hs.create_index(f, IndexConfig("mcIdx", ["sk"], ["val"]))
+        moved = metrics.counter("parallel.exchange.bytes_moved").value - before
         monkeypatch.delenv(ENV, raising=False)
-        assert moved_on > 0 and moved_off > 0
-        assert moved_off / moved_on >= 2.0, (moved_off, moved_on)
+        (counts_sp,) = cap.trace.find("build:exchange_counts")
+        n_dev = len(jax.devices())
+        full_width = n_dev * n_dev * counts_sp.attrs["cap"] * (4 + 4 + 8 + 4)
+        assert moved > 0
+        assert full_width / moved >= 2.0, (full_width, moved)
 
     def test_no_per_cardinality_compile_classes(self, tmp_path, monkeypatch):
         """Two dictionary cardinalities in the SAME width class (both int8)

@@ -211,9 +211,13 @@ def test_mesh_exchange_bills_exchange_stage():
     rng = np.random.RandomState(5)
     keys = rng.randint(0, 1000, size=512).astype(np.int64)
     kcol = Column.from_values(keys)
-    h1 = column_hash_u32(kcol, jnp.asarray(keys), _SEED1)
+    h1 = np.asarray(column_hash_u32(kcol, jnp.asarray(keys), _SEED1))
+    bucket = jnp.asarray((h1 % np.uint32(32)).astype(np.int8))
+    valid = jnp.ones(len(keys), dtype=jnp.int8)
     with resilience.query_scope("q-mesh"):
-        distributed_bucketize(mesh, h1, [jnp.asarray(keys)], [jnp.asarray(keys)], 32)
+        distributed_bucketize(
+            mesh, bucket, [jnp.asarray(keys)], [jnp.asarray(keys)], 32, valid, len(keys)
+        )
         walls = stage_ledger.query_stage_walls()
     assert walls is not None and walls.get("exchange", 0) > 0
 
