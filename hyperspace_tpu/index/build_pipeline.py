@@ -44,10 +44,11 @@ two to each other).
 
 The ordering this contract fixes is the engine's ONE canonical build order —
 stable (bucket, keys...) with ties broken by original row id — which the
-MESH build (`parallel/table_ops.distributed_bucketize_table`, taken instead
-of this pipeline when a multi-device mesh claims the source) also produces:
-all three build strategies (serial, pipelined, mesh) emit byte-identical
-index files, pinned by `tests/test_build_pipeline.py` and
+MESH build (`parallel/table_ops.distributed_bucketize_perm`, taken instead
+of this pipeline's decode and sort when a multi-device mesh claims the
+source) also produces, and writes through this module's writer
+(`write_sorted`): all three build strategies (serial, pipelined, mesh) emit
+byte-identical index files, pinned by `tests/test_build_pipeline.py` and
 `tests/test_mesh_compile.py` respectively. Any change to the sort tie order
 here breaks BOTH contracts at once.
 
@@ -76,7 +77,7 @@ from concurrent.futures import (
     wait,
 )
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -760,17 +761,18 @@ def _decode_and_finish(
     )
 
 
-def _sort_write_summarize(
+def write_sorted(
     chunks: List[Table],
-    bucket_ids: List[Optional[np.ndarray]],
-    staged_device,
-    key_names: List[str],
-    num_buckets: int,
+    sort: Callable[[], Tuple[np.ndarray, np.ndarray]],
     index_data_path: str,
     cfg: PipelineConfig,
     stages: StageTimings,
-    n_files: int,
-) -> dict:
+) -> np.ndarray:
+    """THE build's sort-and-write tail, shared by the pipeline and the mesh
+    build: `sort()` gives (perm, starts) over the chunk concatenation while
+    the writer assembles every output column beside it (the sort touches only
+    the key columns), then the range writer gathers and writes the bucket
+    files. Returns `perm`."""
     os.makedirs(index_data_path, exist_ok=True)
     writer = _BucketWriter(chunks, index_data_path, stages)
     prep_err: List[BaseException] = []
@@ -784,15 +786,35 @@ def _sort_write_summarize(
     # Arrow-array assembly (all columns) overlaps the sort (key columns only).
     prep = threading.Thread(target=prep_guarded, daemon=True)
     prep.start()
-    perm, starts = _sort_pipeline(
-        chunks, bucket_ids, staged_device, key_names, num_buckets, stages
-    )
+    perm, starts = sort()
     prep.join()
     if prep_err:
         raise prep_err[0]
 
     writer.run(perm, starts, cfg.writers)
+    return perm
 
+
+def _sort_write_summarize(
+    chunks: List[Table],
+    bucket_ids: List[Optional[np.ndarray]],
+    staged_device,
+    key_names: List[str],
+    num_buckets: int,
+    index_data_path: str,
+    cfg: PipelineConfig,
+    stages: StageTimings,
+    n_files: int,
+) -> dict:
+    perm = write_sorted(
+        chunks,
+        lambda: _sort_pipeline(
+            chunks, bucket_ids, staged_device, key_names, num_buckets, stages
+        ),
+        index_data_path,
+        cfg,
+        stages,
+    )
     summary = stages.summary(
         rows=int(perm.shape[0]),
         files=n_files,

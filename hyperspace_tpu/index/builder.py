@@ -109,7 +109,8 @@ class CoveringIndexBuilder(IndexerBuilder):
     def write(self, df: DataFrame, index_config: IndexConfig, index_data_path: str) -> None:
         """The bucketed build. Routed three ways:
 
-        - mesh build (distributed all_to_all) when a device mesh applies;
+        - mesh build (distributed all_to_all) when a device mesh applies,
+          written through the pipeline's range writer;
         - the staged PIPELINE (`index/build_pipeline.py`) by default — decode,
           transfer, fused bucketize+sort and bucket writes overlap;
         - the pre-pipeline SERIAL chain under
@@ -176,21 +177,31 @@ class CoveringIndexBuilder(IndexerBuilder):
                 table = self._prepare_index_table(df, index_config)
             mesh = self._session.mesh_for(table.num_rows)
             if mesh is not None:
-                stages.mode = "mesh"
-            with stages.timed("sort", rows=table.num_rows):
-                if mesh is not None:
-                    # Cluster-wide build (the reference's repartition+bucketed-
-                    # write runs on the whole Spark cluster,
-                    # `CreateActionBase.scala:119-140`): rows ride an
-                    # all_to_all over the mesh; identical hash → identical
-                    # index files.
-                    from ..parallel.table_ops import distributed_bucketize_table
+                # Cluster-wide build (the reference's repartition+bucketed-
+                # write runs on the whole Spark cluster,
+                # `CreateActionBase.scala:119-140`): rows ride an all_to_all
+                # over the mesh; identical hash → identical index files. The
+                # permutation comes back alone, and the pipeline's range
+                # writer gathers the bucket files through it, its column
+                # assembly overlapping the exchange.
+                from ..parallel.table_ops import distributed_bucketize_perm
+                from .build_pipeline import write_sorted
 
-                    sorted_table, starts = distributed_bucketize_table(
-                        mesh, table, indexed, num_buckets, stages
-                    )
-                else:
-                    sorted_table, starts = bucketize_table(table, indexed, num_buckets)
+                stages.mode = "mesh"
+
+                def sort():
+                    with stages.timed("sort", rows=table.num_rows):
+                        return distributed_bucketize_perm(
+                            mesh, table, indexed, num_buckets, stages
+                        )
+
+                write_sorted([table], sort, index_data_path, cfg, stages)
+                record_build_stages(
+                    stages.summary(rows=table.num_rows, writers=cfg.writers)
+                )
+                return
+            with stages.timed("sort", rows=table.num_rows):
+                sorted_table, starts = bucketize_table(table, indexed, num_buckets)
             os.makedirs(index_data_path, exist_ok=True)
             import numpy as np
             from concurrent.futures import ThreadPoolExecutor
@@ -214,15 +225,6 @@ class CoveringIndexBuilder(IndexerBuilder):
             # the bucket files concurrently keeps the build from serializing on
             # host I/O (SURVEY §7 — the executors of the reference's bucketed
             # write ran cluster-wide for the same reason).
-            # Per-bucket tasks for BOTH paths: the pool load-balances small
-            # parquet encodes regardless of which device owned a bucket. The
-            # mesh layout's per-shard file ownership (device d's exchange block
-            # IS its contiguous bucket range [d·B/n, (d+1)·B/n)) matters on a
-            # MULTI-HOST mesh, where each host would map only its own devices'
-            # bucket range here — on one host, coarser shard-sized tasks would
-            # only serialize a skewed shard's writes behind one worker. File
-            # names and bytes are identical across the mesh and single-device
-            # paths either way.
             with stages.timed("write"):
                 with ThreadPoolExecutor(max_workers=cfg.writers) as pool:
                     list(pool.map(write_bucket, range(num_buckets)))

@@ -14,6 +14,7 @@ from .distributed import (  # noqa: F401
 )
 from .table_ops import (  # noqa: F401
     distributed_bucketed_join_pairs,
+    distributed_bucketize_perm,
     distributed_bucketize_table,
     distributed_exchange_table,
 )

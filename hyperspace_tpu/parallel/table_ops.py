@@ -3,13 +3,14 @@ mesh kernels in `distributed.py`.
 
 These are the entry points the REAL build and query paths call (not just tests):
 
-- `distributed_bucketize_table` — the index build's shuffle. The TPU-native
+- `distributed_bucketize_perm` — the index build's shuffle. The TPU-native
   analogue of the reference's cluster-wide `repartition(numBuckets, cols)` +
   bucketed write (`CreateActionBase.scala:119-140`): rows leave the host as
   row-sharded blocks, ride a two-pass `lax.all_to_all` to their bucket's device,
-  and come back grouped by bucket and sorted within bucket. Same contract as the
-  single-device `ops.partition.bucketize_table` (identical hash AND identical
-  stable tie order → byte-identical index files).
+  and come back grouped by bucket and sorted within bucket, as a permutation.
+  Same order as the single-device `ops.partition.bucketize_table` (identical
+  hash AND identical stable tie order → byte-identical index files);
+  `distributed_bucketize_table` applies it to the table.
 - `distributed_exchange_table` — the general join's ShuffleExchange. Both sides
   exchanged with the same key hash are co-partitioned, so the merge join after it
   needs no further communication.
@@ -26,8 +27,8 @@ that hung the r05 TPU bench for 2400 s inside `ops/hashing.bucket_id`), and
 the exchange/probe capacities are floored at the mesh row quantum. Each
 `parallel.*` program compiles exactly once per process per class, asserted by
 `tests/test_mesh_compile.py`. A mesh build reports each step as a
-`build:<stage>` span (`hash`, `h2d`, `exchange_counts`, `exchange`, `gather`,
-`take`); the benchmark's `exchange_ms` and `mesh_sort_roofline` read the
+`build:<stage>` span (`hash`, `h2d`, `exchange_counts`, `exchange`,
+`gather`); the benchmark's `exchange_ms` and `mesh_sort_roofline` read the
 exchange's two, and `exchange_padding` the `parallel.exchange.*` counters.
 """
 
@@ -42,7 +43,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.encoded_device import narrow_codes, record_mesh_wire_stage
-from ..engine.encoding import column_nbytes
 from ..engine.table import Table
 from ..ops.hashing import _SEED1, combined_hash_u32, key64
 from ..telemetry.compile_log import observed_jit as _observed_jit
@@ -267,28 +267,31 @@ def _gather_valid_perm(
     return perm, np.searchsorted(bucket_v, np.arange(num_buckets + 1))
 
 
-def distributed_bucketize_table(
+def distributed_bucketize_perm(
     mesh: Mesh,
     table: Table,
     bucket_columns: Sequence[str],
     num_buckets: int,
     stages: Optional[StageTimings] = None,
-) -> Tuple[Table, np.ndarray]:
-    """Mesh-wide hash-partition + in-bucket sort; drop-in for `bucketize_table`.
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Mesh-wide hash-partition + in-bucket sort of `table`'s rows: the
+    (permutation, bucket starts) of the canonical build order, the
+    permutation in source-row terms.
 
-    The exchange moves (bucket id, row id, sort keys) over ICI; the permutation
-    comes back to the host, which materializes the reordered table for the bucketed
-    parquet write (index files are host I/O regardless of where the shuffle ran).
-    Bucket assignment is identical to the single-device path (h1 % num_buckets over
-    the same column hash) AND the within-bucket order is the same canonical
-    stable (bucket, keys..., original row) order, so the two paths produce
-    BYTE-IDENTICAL index files — `HYPERSPACE_DISTRIBUTED=0` is an exact
-    fallback, pinned by the on/off oracles in tests/test_mesh_compile.py.
+    The exchange moves (bucket id, row id, sort keys) over ICI; only the
+    permutation comes back to the host. Bucket assignment is identical to the
+    single-device path (h1 % num_buckets over the same column hash) AND the
+    within-bucket order is the same canonical stable (bucket, keys...,
+    original row) order, so rows written in this order give BYTE-IDENTICAL
+    index files — `HYPERSPACE_DISTRIBUTED=0` is an exact fallback, pinned by
+    the on/off oracles in tests/test_mesh_compile.py. The index build writes
+    through this order without reordering the table first
+    (`index/build_pipeline.write_sorted`).
 
     `stages` (the index build's) times each step as a stage: ``hash`` and
     ``h2d`` (`_exchange_keys`), ``exchange_counts`` and ``exchange``
-    (`distributed.py`), ``gather`` (the exchange's output copied back into
-    the permutation and bucket starts) and ``take`` (the reordered table)."""
+    (`distributed.py`), and ``gather`` (the exchange's output copied back into
+    the permutation and bucket starts)."""
     n = table.num_rows
     cols = [table.column(c) for c in bucket_columns]
     bucket, out_valid, (rowid_out,) = _exchange_keys(
@@ -298,11 +301,22 @@ def distributed_bucketize_table(
         perm, starts = _gather_valid_perm(bucket, out_valid, rowid_out, n, num_buckets)
         if sp.recording:
             sp.set_attr("bytes", bucket.nbytes + out_valid.nbytes + rowid_out.nbytes)
-    with StageTimings.timed_or_not(stages, "take", rows=n, columns=len(table.columns)) as sp:
-        out = table.take(perm)
-        if sp.recording:
-            sp.set_attr("bytes", sum(column_nbytes(c) for c in out.columns.values()))
-    return out, starts
+    return perm, starts
+
+
+def distributed_bucketize_table(
+    mesh: Mesh,
+    table: Table,
+    bucket_columns: Sequence[str],
+    num_buckets: int,
+    stages: Optional[StageTimings] = None,
+) -> Tuple[Table, np.ndarray]:
+    """`distributed_bucketize_perm` with the table reordered on the host; a
+    drop-in for `ops.partition.bucketize_table`."""
+    perm, starts = distributed_bucketize_perm(
+        mesh, table, bucket_columns, num_buckets, stages
+    )
+    return table.take(perm), starts
 
 
 def distributed_exchange_table(

@@ -240,15 +240,23 @@ class TestChaosOracle:
             with pytest.raises(RetryBudgetExceededError, match="retry budget"):
                 s.read.parquet(src).collect()
 
-    def test_build_identical_under_faults(self, tmp_path, monkeypatch):
+    @pytest.mark.parametrize("route", ["pipelined", "mesh"])
+    def test_build_identical_under_faults(self, tmp_path, monkeypatch, route):
         """The chaos contract covers the BUILD too: an index built under
         injected transient decode/write faults is byte-identical to a clean
-        build."""
-        from hyperspace_tpu import Hyperspace, IndexConfig
-        from hyperspace_tpu.hyperspace import enable_hyperspace
+        build, on the pipeline and on the mesh (8 devices here), which both
+        write through the range writer."""
+        from hyperspace_tpu import Hyperspace, IndexConfig, IndexConstants
+        from hyperspace_tpu.telemetry.profiling import last_build_stages
 
         monkeypatch.setenv("HYPERSPACE_BUILD_DECODE_THREADS", "2")
+        # At rate 0.3 the default 2 retries exhaust for one write in 37, and a
+        # build makes several; the chaos contract raises the bound like the
+        # CI leg does (exhaustion: test_exhausted_retries_fail_classified).
+        monkeypatch.setenv("HYPERSPACE_IO_RETRIES", "6")
         s, src = _session(tmp_path)
+        if route == "mesh":
+            s.conf.set(IndexConstants.DISTRIBUTED_MIN_ROWS, 0)
         s.conf.set("hyperspace.system.path", str(tmp_path / "idx_clean"))
         s.conf.set("hyperspace.index.num.buckets", 4)
         _clear_caches()
@@ -265,6 +273,9 @@ class TestChaosOracle:
             Hyperspace(s).create_index(
                 s.read.parquet(src), IndexConfig("idx", ["k"], ["v"])
             )
+        assert last_build_stages()["mode"].startswith(
+            "mesh" if route == "mesh" else "pipelined"
+        )
         chaos_dir = str(tmp_path / "idx_chaos" / "idx" / "v__=0")
         chaos = {
             f: open(os.path.join(chaos_dir, f), "rb").read()

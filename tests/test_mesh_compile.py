@@ -150,20 +150,45 @@ class TestDistributedFlagContract:
         monkeypatch.setenv("HYPERSPACE_DISTRIBUTED", "1")
         assert s.mesh_for(10) is not None
 
-    def test_build_outputs_byte_identical(self, tmp_path, monkeypatch):
+    @pytest.mark.parametrize(
+        "writers, lineage", [("1", False), ("3", False), ("8", False), ("3", True)]
+    )
+    def test_build_outputs_byte_identical(self, tmp_path, monkeypatch, writers, lineage):
+        """The mesh build's files are the single-device build's bytes whatever
+        the number of the range writer's gather ranges, and with lineage on
+        (`_data_file_name`, a string column, per source file)."""
+        from hyperspace_tpu.telemetry.profiling import last_build_stages
+
         monkeypatch.setenv("HYPERSPACE_DISTRIBUTED", "1")
+        monkeypatch.setenv("HYPERSPACE_BUILD_WRITERS", writers)
         s = _session(tmp_path)
+        s.conf.set(IndexConstants.INDEX_LINEAGE_ENABLED, lineage)
         base = str(tmp_path)
         _write_pair(s, base, 3000)  # duplicate keys: ties exercise the order
+        if lineage:  # a second source file: two lineage values
+            _write_pair(s, base, 1000, seed=4, suffix="2")
+            os.replace(
+                os.path.join(base, "dept2", "part-00000.parquet"),
+                os.path.join(base, "dept", "part-00001.parquet"),
+            )
         hs = Hyperspace(s)
         df = s.read.parquet(os.path.join(base, "dept"))
         hs.create_index(df, IndexConfig("meshIdx", ["k"], ["name"]))
+        mesh = last_build_stages()
+        assert mesh["mode"] == "mesh"
+        assert 1 <= mesh["stage_counts"]["take"] <= int(writers)
         monkeypatch.setenv("HYPERSPACE_DISTRIBUTED", "0")
         hs.create_index(df, IndexConfig("localIdx", ["k"], ["name"]))
         hm = _dir_hashes(os.path.join(base, "indexes", "meshIdx", "v__=0"))
         hl = _dir_hashes(os.path.join(base, "indexes", "localIdx", "v__=0"))
         assert list(hm) == list(hl)
         assert hm == {f.replace("local", "mesh"): h for f, h in hl.items()}
+        if lineage:
+            import pyarrow.parquet as pq
+
+            part = os.path.join(base, "indexes", "meshIdx", "v__=0", next(iter(hm)))
+            names = pq.read_schema(part).names
+            assert IndexConstants.DATA_FILE_NAME_COLUMN in names
 
     @pytest.mark.parametrize("n", [3001, 5])
     def test_uneven_shard_build_byte_identical(self, tmp_path, monkeypatch, n):

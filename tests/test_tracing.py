@@ -512,14 +512,17 @@ def test_failing_build_closes_stage_spans_with_error(session, tmp_path, monkeypa
     assert any(sp.status == "error" for sp in cap.trace.find("build:write"))
 
 
-MESH_STAGES = ("hash", "h2d", "exchange_counts", "exchange", "gather", "take")
+MESH_STAGES = ("hash", "h2d", "exchange_counts", "exchange", "gather")
 
 
 def test_mesh_build_records_exchange_spans(session, tmp_path, monkeypatch):
     """A build on the mesh (every visible device; 8 here) times each step of
     its bucketize as a stage inside `build:sort`, once per build, with the
-    counts that step saw; the exchange's bytes are the counters' deltas."""
+    counts that step saw; the exchange's bytes are the counters' deltas. The
+    pipeline's range writer then gathers the rows after the sort, one
+    `build:take` per gather range."""
     session.conf.set(IndexConstants.DISTRIBUTED_MIN_ROWS, 0)
+    monkeypatch.setenv("HYPERSPACE_BUILD_WRITERS", "3")
     src = _write_build_source(session, tmp_path)
     before = metrics.snapshot()["counters"]
     with tracing.capture() as cap:
@@ -558,10 +561,36 @@ def test_mesh_build_records_exchange_spans(session, tmp_path, monkeypatch):
     assert delta["parallel.exchange.count"] == 1
     assert exchange["bytes_payload"] == delta["parallel.exchange.bytes_payload"] > 0
     assert exchange["bytes_moved"] == delta["parallel.exchange.bytes_moved"]
-    assert spans["take"]["rows"] == SRC_ROWS and spans["take"]["columns"] == 3
-    assert spans["take"]["bytes"] == SRC_ROWS * 3 * 8
-    # The steps nest in the sort: the ratio counts their seconds once.
-    assert stages.attrs["overlap_ratio"] <= 1.05
+
+    # The reorder is the range writer's: one take per gather range, after the
+    # sort and inside the pipeline, over every column (k, v, f); the ranges'
+    # rows cover the source once, their buckets the files written.
+    files = [
+        f
+        for _root, _dirs, fs in os.walk(os.path.join(str(tmp_path), "indexes", "idx"))
+        for f in fs
+        if f.endswith(".parquet")
+    ]
+    takes = trace.find("build:take")
+    writes = trace.find("build:write")
+    assert 1 < len(takes) <= 3 and stages.attrs["stage_counts"]["take"] == len(takes)
+    end = stages.start_s + stages.duration_s
+    for sp in takes:
+        assert sp.parent_id == stages.span_id and sp.status == "ok"
+        assert sort_end <= sp.start_s and sp.start_s + sp.duration_s <= end + 1e-3
+        assert sp.attrs["columns"] == 3
+    assert sum(sp.attrs["rows"] for sp in takes) == SRC_ROWS
+    assert sum(sp.attrs["buckets"] for sp in takes) == len(files) == len(writes) > 1
+    assert sum(sp.attrs["rows"] for sp in writes) == SRC_ROWS
+    # The exchange's steps nest in the sort: the busy time the ratio counts
+    # holds their seconds once, beside the writer's concurrent brackets.
+    nested = sum(stages.attrs[f"{st}_s"] for st in MESH_STAGES)
+    busy = sum(
+        v for k, v in stages.attrs.items() if k.endswith("_s") and k != "wall_s"
+    ) - nested
+    assert stages.attrs["overlap_ratio"] == pytest.approx(
+        busy / stages.attrs["wall_s"], abs=0.01  # the summary's rounding
+    )
 
 
 @pytest.mark.parametrize("route", ["pipelined", "serial"])
